@@ -94,6 +94,9 @@ def main(argv=None) -> int:
     p.add_argument("--quick", action="store_true")
     p.add_argument("--scale", type=float, default=None)
     args = p.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     scale = args.scale or (0.005 if args.quick else 0.02)
 

@@ -10,10 +10,12 @@ selects any differentiable registry implementation — ``blocked`` (XLA),
 transpose-SpMM/SDDMM duality (DESIGN.md §9) through the same kernels.
 
   PYTHONPATH=src python examples/gnn_train.py [--graph GitHub] [--epochs 60]
-  PYTHONPATH=src python examples/gnn_train.py --steps 2 --impl pallas_tuned
-      # CI smoke: one small config, asserts finite decreasing loss
+  PYTHONPATH=src python examples/gnn_train.py --steps 2 --impl pallas_tuned \
+      --scale 0.002
+      # smoke: one config at --scale, asserts finite decreasing loss
   XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \
-      python examples/gnn_train.py --steps 2 --impl pallas_sharded --mesh 4,2
+      python examples/gnn_train.py --steps 2 --impl pallas_sharded --mesh 4,2 \
+      --scale 0.002
       # multi-device: row segments over the 4-way "data" axis, feature
       # columns over the 2-way "model" axis (DESIGN.md §12)
 """
@@ -28,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.core import from_coo
 from repro.core.autodiff import ad_plan
+from repro.launch.cache import enable_compile_cache
 from repro.models.gnn import GNNConfig, init_agnn, init_gcn, make_train_step
 from repro.sparse.graphs import make_dataset
 
@@ -79,8 +82,8 @@ def main():
                     help="registry impl: blocked | pallas | pallas_balanced "
                          "| pallas_tuned | pallas_sharded")
     ap.add_argument("--steps", type=int, default=None,
-                    help="smoke mode: run STEPS steps of one small config "
-                         "and assert a finite loss decrease (CI gate)")
+                    help="smoke mode: run STEPS steps of one config at "
+                         "--scale and assert a finite loss decrease")
     ap.add_argument("--mesh", default=None, metavar="DATA,MODEL",
                     help="device grid for --impl pallas_sharded, e.g. 4,2 "
                          "(row segments over 'data', heads/columns over "
@@ -92,6 +95,7 @@ def main():
                          "path end to end, fp32 masters in the optimizer "
                          "(DESIGN.md §13)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = None
     if args.mesh is not None:
@@ -100,11 +104,10 @@ def main():
         mesh = mesh_from_arg(args.mesh)
 
     if args.steps is not None:
-        # CI smoke: tiny graph, one (model, V=8) config, hard asserts.
-        scale = min(args.scale, 0.002)
+        # Smoke: one (model, V=8) config at --scale, hard asserts.
         model = args.model if args.model != "both" else "gcn"
         dtype = jnp.float32 if args.dtype == "f32" else jnp.bfloat16
-        g = make_dataset(args.graph, scale=scale)
+        g = make_dataset(args.graph, scale=args.scale)
         x_np, labels, train_mask = make_task(g)
         losses, acc, dt = train_one(
             g, x_np, labels, train_mask, model=model, v=8,

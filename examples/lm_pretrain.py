@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_reduced
 from repro.data.synthetic import SyntheticLMData
+from repro.launch.cache import enable_compile_cache
 from repro.train.checkpoint import CheckpointManager
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_step import (
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--ckpt-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch)
     ts = TrainStepConfig(opt=AdamWConfig(lr=1e-3, warmup_steps=10,

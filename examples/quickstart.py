@@ -17,7 +17,10 @@ from repro.core import (
     with_values, zeros_in_nonzero_vectors,
 )
 from repro.core.softmax import sparse_softmax
+from repro.launch.cache import enable_compile_cache
 from repro.sparse.graphs import make_dataset
+
+enable_compile_cache()
 
 # 1. a scaled replica of the paper's GitHub graph ---------------------------
 g = make_dataset("GitHub", scale=0.02)

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from repro.core import dispatch as sparse_dispatch
 from repro.core import from_coo
 from repro.core.autodiff import ad_plan
+from repro.launch.cache import enable_compile_cache
 from repro.models.layers import sparse_attention
 
 
@@ -103,6 +104,7 @@ def main():
                          "'model'); force host devices on CPU via "
                          "XLA_FLAGS=--xla_force_host_platform_device_count=8")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = None
     if args.mesh is not None:

@@ -34,7 +34,7 @@ All wrappers accept a leading batch dim on the dense operands and/or the
 bound values (per-head sparse attention).  The Pallas paths execute the
 **native batched grids** — ``(H, N/N_BLK, W)`` SpMM, ``(H, NB, F/F_BLK)``
 SDDMM — one kernel launch for any head count, forward and both backward
-duality ops, with the scalar-prefetch metadata shared across heads (the
+duality ops, with the sparse metadata shared across heads (the
 per-slice one-grid-per-head loop they used to run is gone).  XLA impls
 flagged ``batched`` in the registry are ``jax.vmap``-ed; anything else
 falls back to an unrolled per-slice loop.
@@ -130,14 +130,18 @@ class ADPlan:
 
         Pure gather: sources are exclusively mask-true ``fwd`` entries and
         padding targets are zeroed, so junk in masked-off input positions
-        never leaks into the transpose-SpMM.
+        never leaks into the transpose-SpMM.  ``perm`` is in bounds by
+        construction, so the gather clamps instead of filling: the TPU
+        compiler takes minutes over a program with several fill-mode
+        gathers of this size, and seconds with clamping ones.
         """
         perm = self.perm.reshape(-1)
         if vals.ndim == 3:
-            flat = jnp.take(vals.reshape(vals.shape[0], -1), perm, axis=1)
+            flat = jnp.take(vals.reshape(vals.shape[0], -1), perm, axis=1,
+                            mode="clip")
             return (flat.reshape((vals.shape[0],) + self.bwd.vals.shape)
                     * self.bwd.mask)
-        flat = jnp.take(vals.reshape(-1), perm, axis=0)
+        flat = jnp.take(vals.reshape(-1), perm, axis=0, mode="clip")
         return flat.reshape(self.bwd.vals.shape) * self.bwd.mask
 
     def tree_flatten(self):
@@ -686,7 +690,11 @@ def _attention_ad_bwd(impl, interpret, res, g):
     # FlashAttention-style recompute backward: re-derive scores/probs via
     # the staged differentiable composition — its own backward is the
     # dispatched transpose-SpMM / SDDMM duality on the batched grids — so
-    # nothing from the forward megakernel needs to be residual.
+    # nothing from the forward megakernel needs to be residual.  The
+    # barrier ties the recompute to the cotangent: otherwise XLA may
+    # schedule every layer's recompute early and keep all their
+    # score-sized buffers live at once.
+    q, k, v, scale, g = jax.lax.optimization_barrier((q, k, v, scale, g))
     _, vjp = jax.vjp(
         lambda q_, k_, v_, s_: _staged_attention(impl, interpret, plan,
                                                  q_, k_, v_, s_),

@@ -110,13 +110,12 @@ class OpImpl:
 _REGISTRY: Dict[Tuple[str, str], OpImpl] = {}
 
 # Modules that register implementations at import time.  ``get`` imports
-# them lazily so the registry is fully populated regardless of entry point
-# (kernels are optional at core-import time, mirroring the old local
-# imports in core/spmm.py).
+# them lazily so the registry is fully populated regardless of entry point.
+# A provider that fails to import raises: a broken kernels package must
+# not leave a registry of XLA impls only.
 _PROVIDERS = ("repro.core.spmm", "repro.core.sddmm", "repro.kernels.ops",
               "repro.distributed.sparse_shard",
               "repro.distributed.sparse_shard_overlap")
-_provider_errors: Dict[str, str] = {}
 _loaded = False
 _lock = threading.Lock()
 
@@ -136,14 +135,7 @@ def _ensure_loaded() -> None:
         if _loaded:
             return
         for mod in _PROVIDERS:
-            # Best-effort: the kernels package stays optional (an
-            # environment without jax.experimental.pallas must still run
-            # the XLA impls).  A failed provider surfaces in the miss
-            # message of any impl it would have registered.
-            try:
-                importlib.import_module(mod)
-            except Exception as e:  # noqa: BLE001 — reported on lookup miss
-                _provider_errors[mod] = f"{type(e).__name__}: {e}"
+            importlib.import_module(mod)
         _loaded = True
 
 
@@ -152,12 +144,8 @@ def get(op: str, impl: str) -> OpImpl:
     _ensure_loaded()
     entry = _REGISTRY.get((op, impl))
     if entry is None:
-        msg = (f"unknown impl {impl!r} for op {op!r}; "
-               f"available: {', '.join(impls(op)) or '(none)'}")
-        if _provider_errors:
-            msg += "".join(f"\n  (provider {m} failed to import: {err})"
-                           for m, err in _provider_errors.items())
-        raise ValueError(msg)
+        raise ValueError(f"unknown impl {impl!r} for op {op!r}; "
+                         f"available: {', '.join(impls(op)) or '(none)'}")
     return entry
 
 

@@ -25,7 +25,7 @@ A *blocked* view (:class:`BlockedMEBCRS`) pads each window's vector count to
 a multiple of ``K_BLK`` for the grouped window-GEMM (XLA and Pallas paths).
 Padding lives only in the blocked view; the canonical format stays
 padding-free, exactly like the paper (the kernel reconstructs the residue
-arithmetically — here via the ``block_win`` scalar-prefetch metadata).
+arithmetically — here via the ``block_win`` metadata).
 """
 
 from __future__ import annotations
@@ -146,7 +146,7 @@ class BlockedMEBCRS:
     k_blk: int
     # Optional per-K-block dequantization scales (NB,) fp32: set (alongside
     # int8 ``vals``) by :func:`repro.core.quantize.quantize_format`; the
-    # Pallas SpMM kernels scalar-prefetch them and dequantize in-VMEM
+    # Pallas SpMM kernels read them per block and dequantize in-VMEM
     # (DESIGN.md §13).  ``None`` on every unquantized format.
     scales: Optional[jax.Array] = None
 
@@ -457,8 +457,8 @@ def block_format(fmt: MEBCRS, k_blk: int = 8,
     This is where the paper's "last TC block residue" lives: padding columns
     get value 0 / mask False / column 0, so their MMA contribution vanishes
     (same arithmetic-elimination trick as the paper's modulo residue test,
-    but resolved at format-translation time so the kernel's scalar prefetch
-    stays branch-free).  ``check`` audits the input format and the blocked
+    but resolved at format-translation time so the kernel's metadata
+    reads stay branch-free).  ``check`` audits the input format and the blocked
     view (``None`` → ambient level, DESIGN.md §15).
     """
     from . import validate as _validate

@@ -9,7 +9,7 @@ share code:
   * per-K-block value scales on :class:`~repro.core.format.BlockedMEBCRS`
     (the tentpole of the mixed-precision kernel path): each K-block's
     ``(K_BLK, V)`` value tile stores int8 with one fp32 scale, and the
-    kernels dequantize in-VMEM via the scalar-prefetched scale — the
+    kernels dequantize in-VMEM via the block's scale — the
     dequantization commutes with the contraction
     (``dot(s·q, b) = s·dot(q, b)``), so the MXU runs on narrow data and a
     single fp32 multiply per block restores the magnitude.
@@ -157,7 +157,7 @@ def quantize_block_values(vals: jax.Array, k_blk: int, scales=None):
     Each K-block owns ``k_blk`` consecutive vectors → one quantization
     group of ``k_blk * V`` elements.  Returns ``(q (NNZP, V) int8,
     scales (NB,) fp32)`` with ``NB = NNZP / k_blk`` — the scale array the
-    kernels scalar-prefetch.  Zero-padding vectors inside a K-block keep
+    kernels read per block.  Zero-padding vectors inside a K-block keep
     quantizing to exact 0, preserving ME-BCRS's branch-free residue
     handling at int8.  An explicit ``scales`` (scalar or ``(NB,)``) runs
     the saturating fixed-scale path of :func:`quantize_blocked`.

@@ -129,13 +129,11 @@ def collective_matmul(x: jax.Array, w: jax.Array, mesh: Mesh,
     if n == 1:
         return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
 
-    from jax.experimental.shard_map import shard_map
-
     x_spec = P(*([None] * (x.ndim - 1)), contract_axis)
     w_spec = P(None, out_axis)
     y_spec = P(*([None] * (x.ndim - 1)), out_axis)
 
     body = functools.partial(ring_allgather_matmul, axis_name=contract_axis,
                              axis_size=n)
-    return shard_map(body, mesh=mesh, in_specs=(x_spec, w_spec),
-                     out_specs=y_spec, check_rep=False)(x, w)
+    return jax.shard_map(body, mesh=mesh, in_specs=(x_spec, w_spec),
+                         out_specs=y_spec, check_vma=False)(x, w)

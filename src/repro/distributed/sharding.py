@@ -263,7 +263,7 @@ def sparse_format_shardings(fmt_tree: Any, mesh: Mesh) -> Any:
     The pattern metadata (cols / win_ptr / mask / transpose perm) is tiny
     next to the dense operands — §6's footprint math puts ME-BCRS at
     ``4(W+NNZV) + 2·NNZV·V`` bytes, and the autodiff plan at ~2× that
-    (DESIGN.md §9) — and the fused kernels scalar-prefetch it whole, so
+    (DESIGN.md §9) — and the fused kernels read it from HBM by index, so
     every device keeps the full pattern **replicated** and parallelism
     comes from sharding the dense operands (:func:`sparse_operand_pspec`).
     This mirrors how the GNN baselines shard: graph replicated, feature

@@ -47,7 +47,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import dispatch as _dispatch
@@ -89,8 +88,7 @@ class ShardedSchedule:
       blk_id   (D, NBL)    int32  local scheduled K-blocks (global ids),
                                   padded with a repeat of the device's
                                   first block (harmless double store) —
-                                  the block-indirect SDDMM grid
-      blk_win  (D, NBL)    int32  owning window of each local block
+                                  the device's SDDMM block range
       row_own  (D, M)      bool   output rows this device produces (≥ 1
                                   local segment of the row's window);
                                   non-owned rows are zeroed NaN-safe
@@ -115,8 +113,7 @@ class ShardedSchedule:
                                 windows — the compact ring buffer's
                                 row map; pad entries are ``m`` (their
                                 buffer rows are zero-masked)
-      bblk_id  (D, NB, NBLB)    per-batch block-indirect SDDMM grid
-      bblk_win (D, NB, NBLB)    owning window of each batch block
+      bblk_id  (D, NB, NBLB)    per-batch SDDMM block range
       bval_idx (D, NB, RV) int32 global value rows of the batch's blocks
                                 (pad ``nnzp``, zero-masked)
 
@@ -128,7 +125,6 @@ class ShardedSchedule:
     seg_win: jax.Array
     seg_meta: jax.Array
     blk_id: jax.Array
-    blk_win: jax.Array
     row_own: jax.Array
     blk_own: jax.Array
     num_devices: int
@@ -140,27 +136,26 @@ class ShardedSchedule:
     bseg_meta: Optional[jax.Array] = None
     brow_idx: Optional[jax.Array] = None
     bblk_id: Optional[jax.Array] = None
-    bblk_win: Optional[jax.Array] = None
     bval_idx: Optional[jax.Array] = None
     n_batches: int = 1
 
     def tree_flatten(self):
-        leaves = (self.seg_win, self.seg_meta, self.blk_id, self.blk_win,
-                  self.row_own, self.blk_own, self.bseg_win, self.bseg_meta,
-                  self.brow_idx, self.bblk_id, self.bblk_win, self.bval_idx)
+        leaves = (self.seg_win, self.seg_meta, self.blk_id, self.row_own,
+                  self.blk_own, self.bseg_win, self.bseg_meta, self.brow_idx,
+                  self.bblk_id, self.bval_idx)
         aux = (self.num_devices, self.num_windows, self.split_blk,
                self.window_split, self.num_blocks, self.n_batches)
         return leaves, aux
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        (sw, sm, bi, bw, ro, bo, bsw, bsm, bri, bbi, bbw, bvi) = leaves
+        (sw, sm, bi, ro, bo, bsw, bsm, bri, bbi, bvi) = leaves
         (d, w, sb, ws, nb, nbat) = aux
-        return cls(seg_win=sw, seg_meta=sm, blk_id=bi, blk_win=bw,
-                   row_own=ro, blk_own=bo, num_devices=d, num_windows=w,
-                   split_blk=sb, window_split=ws, num_blocks=nb,
-                   bseg_win=bsw, bseg_meta=bsm, brow_idx=bri, bblk_id=bbi,
-                   bblk_win=bbw, bval_idx=bvi, n_batches=nbat)
+        return cls(seg_win=sw, seg_meta=sm, blk_id=bi, row_own=ro,
+                   blk_own=bo, num_devices=d, num_windows=w, split_blk=sb,
+                   window_split=ws, num_blocks=nb, bseg_win=bsw,
+                   bseg_meta=bsm, brow_idx=bri, bblk_id=bbi, bval_idx=bvi,
+                   n_batches=nbat)
 
 
 # Fixed per-grid-cell issue overhead of the §11 cost model (bytes-
@@ -339,27 +334,20 @@ def partition_schedule(blocked: BlockedMEBCRS,
             bat_ranges[dev][b] = (int(bcuts[b]), int(bcuts[b + 1]))
 
     nbl = max((hi - lo for lo, hi in blk_ranges), default=0)
-    blk_win_g = np.asarray(schedule.blk_win)
 
     def block_grid(shape, ranges):
         bid = np.zeros(shape, np.int32)
-        bwin = np.zeros(shape, np.int32)
         if shape[-1] == 0:                  # no scheduled blocks at all
-            return bid, bwin
+            return bid
         flat_id = bid.reshape(-1, shape[-1])
-        flat_win = bwin.reshape(-1, shape[-1])
         for i, (lo, hi) in enumerate(ranges):
             n_loc = hi - lo
-            pad_id = lo if n_loc else 0
-            flat_id[i, :] = pad_id               # pad: recompute own block
-            if blk_win_g.size:
-                flat_win[i, :] = blk_win_g[pad_id]
+            flat_id[i, :] = lo if n_loc else 0   # pad: recompute own block
             if n_loc:
                 flat_id[i, :n_loc] = np.arange(lo, hi, dtype=np.int32)
-                flat_win[i, :n_loc] = blk_win_g[lo:hi]
-        return bid, bwin
+        return bid
 
-    bid, bwin = block_grid((d, nbl), blk_ranges)
+    bid = block_grid((d, nbl), blk_ranges)
 
     # ---- segment-batch arrays ------------------------------------------
     bat_counts = np.asarray([[hi - lo for lo, hi in row] for row in bat_ranges],
@@ -395,7 +383,7 @@ def partition_schedule(blocked: BlockedMEBCRS,
     for i, rows in enumerate(row_lists):
         flat_bri[i, :rows.size] = rows
     nblb = max((hi - lo for lo, hi in bat_blk_ranges), default=0) or 1
-    bbi, bbw = block_grid((d, nb, nblb), bat_blk_ranges)
+    bbi = block_grid((d, nb, nblb), bat_blk_ranges)
     rv_max = max((hi - lo for lo, hi in bat_blk_ranges), default=0) * k_blk or 1
     bvi = np.full((d, nb, rv_max), nnzp, np.int32)    # pad → zero-masked
     flat_bvi = bvi.reshape(d * nb, rv_max)
@@ -405,13 +393,12 @@ def partition_schedule(blocked: BlockedMEBCRS,
 
     return _validate.validate_sharded(ShardedSchedule(
         seg_win=jnp.asarray(sw), seg_meta=jnp.asarray(sm),
-        blk_id=jnp.asarray(bid), blk_win=jnp.asarray(bwin),
-        row_own=jnp.asarray(row_own), blk_own=jnp.asarray(blk_own),
+        blk_id=jnp.asarray(bid), row_own=jnp.asarray(row_own), blk_own=jnp.asarray(blk_own),
         num_devices=d, num_windows=w, split_blk=schedule.split_blk,
         window_split=window_split, num_blocks=schedule.num_blocks,
         bseg_win=jnp.asarray(bsw), bseg_meta=jnp.asarray(bsm),
         brow_idx=jnp.asarray(bri), bblk_id=jnp.asarray(bbi),
-        bblk_win=jnp.asarray(bbw), bval_idx=jnp.asarray(bvi),
+        bval_idx=jnp.asarray(bvi),
         n_batches=nb), blocked=blocked, check=level)
 
 
@@ -577,7 +564,8 @@ def spmm_sharded(fmt, b: jax.Array, *, mesh: Optional[Mesh] = None,
     the operands before the shard_map, ``"int8"`` quantizes the sparse
     values per K-block (scales replicate — a few bytes per block).
     """
-    from repro.kernels.spmm_pallas import _apply_precision, _balanced_spmm_call
+    from repro.kernels.layout import schedule_steps
+    from repro.kernels.spmm_pallas import _apply_precision, _spmm_call
 
     blocked = fmt if isinstance(fmt, BlockedMEBCRS) else block_format(fmt, k_blk)
     mesh = _resolve_mesh(mesh)
@@ -594,7 +582,6 @@ def spmm_sharded(fmt, b: jax.Array, *, mesh: Optional[Mesh] = None,
     m, _ = blocked.shape
     n = b.shape[-1]
     w = part.num_windows
-    v = blocked.vector_size
     model_ax, tp = _model_axis(mesh)
     if model_ax and (vb or bb) and h % tp == 0:
         mode = "heads"
@@ -605,19 +592,13 @@ def spmm_sharded(fmt, b: jax.Array, *, mesh: Optional[Mesh] = None,
 
     def local(sw, sm, own, vals_l, b_l):
         sw, sm, own = sw[0], sm[0], own[0]
-        vals3 = vals_l if vb else vals_l[None]
         b3 = b_l if bb else b_l[None]
-        n_loc = b3.shape[-1]
-        nb_eff = min(n_blk, max(n_loc, 1))
-        n_pad = -(-n_loc // nb_eff) * nb_eff
-        if n_pad != n_loc:
-            b3 = jnp.pad(b3, ((0, 0), (0, 0), (0, n_pad - n_loc)))
-        out = _balanced_spmm_call(
-            sw, sm, blocked.cols, scales, vals3, b3, num_windows=w + 1, v=v,
-            k_blk=blocked.k_blk, n_blk=nb_eff, h=vals3.shape[0] if vb
-            else (b3.shape[0] if bb else 1), vals_batched=vb, b_batched=bb,
-            interpret=interpret, quantized=quantized)
-        out = out[:, :m, :n_loc]
+        out = _spmm_call(
+            schedule_steps(sw, sm), blocked.cols, scales,
+            vals_l if vb else vals_l[None], b3, num_windows=w + 1,
+            k_blk=blocked.k_blk, n_blk=n_blk, interpret=interpret,
+            quantized=quantized)
+        out = out[:, :m, :b3.shape[-1]].astype(b3.dtype)
         out = jnp.where(own[None, :, None], out, 0.0)   # NaN-safe zero fill
         out = jax.lax.psum(out, "data")
         return out if (vb or bb) else out[0]
@@ -629,9 +610,10 @@ def spmm_sharded(fmt, b: jax.Array, *, mesh: Optional[Mesh] = None,
         out_spec = P(model_ax) if mode == "heads" else P()
     else:
         out_spec = P(None, model_ax) if mode == "cols" else P()
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P("data"), P("data"), P("data"), v_spec, b_spec),
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("data"), P("data"), P("data"), v_spec,
+                                 b_spec),
+                       out_specs=out_spec, check_vma=False)
     return fn(part.seg_win, part.seg_meta, part.row_own, vals, b)
 
 
@@ -653,7 +635,8 @@ def sddmm_sharded(fmt, q: jax.Array, k: jax.Array, *,
     the partial products (TP-style).  Degrades to replication when the
     dim does not divide.
     """
-    from repro.kernels.sddmm_pallas import _balanced_sddmm_call, _cast_precision
+    from repro.kernels.layout import LANES
+    from repro.kernels.sddmm_pallas import _cast_precision, _sddmm, chunk_range
 
     q, k = _cast_precision(precision, q, k)
     blocked = fmt if isinstance(fmt, BlockedMEBCRS) else block_format(fmt, k_blk)
@@ -668,12 +651,12 @@ def sddmm_sharded(fmt, q: jax.Array, k: jax.Array, *,
     qb, kb = q.ndim == 3, k.ndim == 3
     h = q.shape[0] if qb else (k.shape[0] if kb else 1)
     v = blocked.vector_size
-    w = blocked.num_windows
     nb = blocked.num_blocks
     f = q.shape[-1]
     if part.num_blocks == 0:                     # all-empty pattern
         out = jnp.zeros((h, nb * blocked.k_blk, v), q.dtype)
         return out if (qb or kb) else out[0]
+    num_chunks = chunk_range(part.blk_id.shape[-1], blocked.k_blk)
     model_ax, tp = _model_axis(mesh)
     if model_ax and (qb or kb) and h % tp == 0:
         mode = "heads"
@@ -683,22 +666,14 @@ def sddmm_sharded(fmt, q: jax.Array, k: jax.Array, *,
         mode, model_ax = "none", None
     psum_axes = ("data", model_ax) if mode == "feat" else ("data",)
 
-    def local(bid, bwin, own, q_l, k_l):
-        bid, bwin, own = bid[0], bwin[0], own[0]
-        q3 = q_l if qb else q_l[None]
-        k3 = k_l if kb else k_l[None]
-        f_loc = q3.shape[-1]
-        fb_eff = min(f_blk, max(f_loc, 1))
-        f_pad = -(-f_loc // fb_eff) * fb_eff
-        qpad = jnp.zeros((q3.shape[0], w * v, f_pad), q.dtype
-                         ).at[:, : q3.shape[1], :f_loc].set(q3)
-        if f_pad != f_loc:
-            k3 = jnp.pad(k3, ((0, 0), (0, 0), (0, f_pad - f_loc)))
-        out = _balanced_sddmm_call(
-            bid, bwin, blocked.cols, qpad, k3, blocked.mask, v=v,
-            k_blk=blocked.k_blk, f_blk=fb_eff, h=q3.shape[0] if qb
-            else (k3.shape[0] if kb else 1), q_batched=qb, k_batched=kb,
-            nb=nb, interpret=interpret)
+    def local(bid, own, q_l, k_l):
+        bid, own = bid[0], own[0]
+        # The device's blocks are the contiguous range starting at bid[0]:
+        # launch over the 128-vector chunks that cover it.
+        out = _sddmm(blocked, q_l, k_l, f_blk=f_blk, interpret=interpret,
+                     precision=None, chunk0=bid[:1] * blocked.k_blk // LANES,
+                     num_chunks=num_chunks)
+        out = out if (qb or kb) else out[None]
         out = jnp.where(own[None, :, None], out, 0.0)
         out = jax.lax.psum(out, psum_axes)
         return out if (qb or kb) else out[0]
@@ -708,10 +683,10 @@ def sddmm_sharded(fmt, q: jax.Array, k: jax.Array, *,
     k_spec = (P(model_ax) if (mode == "heads" and kb)
               else (P(None, model_ax) if mode == "feat" else P()))
     out_spec = P(model_ax) if mode == "heads" else P()
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P("data"), P("data"), P("data"), q_spec, k_spec),
-                   out_specs=out_spec, check_rep=False)
-    return fn(part.blk_id, part.blk_win, part.blk_own, q, k)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("data"), P("data"), q_spec, k_spec),
+                       out_specs=out_spec, check_vma=False)
+    return fn(part.blk_id, part.blk_own, q, k)
 
 
 def attention_sharded(fmt, q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -735,7 +710,8 @@ def attention_sharded(fmt, q: jax.Array, k: jax.Array, v: jax.Array, *,
     """
     import math
 
-    from repro.kernels.attention_pallas import _balanced_attn_call
+    from repro.kernels.attention_pallas import _attn_call
+    from repro.kernels.layout import schedule_steps
     from repro.kernels.sddmm_pallas import _cast_precision
 
     q, k, v = _cast_precision(precision, q, k, v)
@@ -757,7 +733,6 @@ def attention_sharded(fmt, q: jax.Array, k: jax.Array, v: jax.Array, *,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    maskf = blocked.mask.astype(jnp.float32)
     model_ax, tp = _model_axis(mesh)
     mode = "heads" if (model_ax and batched and h % tp == 0) else "none"
     if mode == "none":
@@ -770,13 +745,10 @@ def attention_sharded(fmt, q: jax.Array, k: jax.Array, v: jax.Array, *,
         v3 = v_l if vb else v_l[None]
         qpad = jnp.zeros((q3.shape[0], (w + 1) * vsz, q.shape[-1]), q.dtype
                          ).at[:, : q3.shape[1], :].set(q3)
-        out = _balanced_attn_call(
-            sw, sm, blocked.cols, qpad, k3, v3, maskf, num_windows=w + 1,
-            v=vsz, k_blk=blocked.k_blk,
-            h=next((x.shape[0] for x, f in ((q3, qb), (k3, kb), (v3, vb))
-                    if f), 1),
-            q_batched=qb, k_batched=kb, v_batched=vb, interpret=interpret)
-        out = out[:, :m, :]
+        out = _attn_call(
+            schedule_steps(sw, sm), blocked.cols, blocked.mask, qpad, k3, v3,
+            num_windows=w + 1, k_blk=blocked.k_blk, interpret=interpret)
+        out = out[:, :m, :v3.shape[-1]].astype(v3.dtype)
         out = jnp.where(own[None, :, None], out, 0.0)
         out = jax.lax.psum(out, "data")
         return out if batched else out[0]
@@ -785,10 +757,10 @@ def attention_sharded(fmt, q: jax.Array, k: jax.Array, v: jax.Array, *,
         return P(model_ax) if (mode == "heads" and is_b) else P()
 
     out_spec = (P(model_ax) if mode == "heads" else P()) if batched else P()
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P("data"), P("data"), P("data"), spec(qb),
-                             spec(kb), spec(vb)),
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("data"), P("data"), P("data"), spec(qb),
+                                 spec(kb), spec(vb)),
+                       out_specs=out_spec, check_vma=False)
     return fn(part.seg_win, part.seg_meta, part.row_own, qs, k, v)
 
 

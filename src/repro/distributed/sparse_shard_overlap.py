@@ -38,7 +38,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import dispatch as _dispatch
@@ -100,7 +99,8 @@ def spmm_sharded_overlap(fmt, b: jax.Array, *, mesh: Optional[Mesh] = None,
     ``n_batches`` picks the pipeline depth when ``part`` is not supplied
     (else the partition's own ``n_batches`` wins).
     """
-    from repro.kernels.spmm_pallas import _apply_precision, _balanced_spmm_call
+    from repro.kernels.layout import schedule_steps
+    from repro.kernels.spmm_pallas import _apply_precision, _spmm_call
 
     blocked = fmt if isinstance(fmt, BlockedMEBCRS) else block_format(fmt, k_blk)
     mesh = _resolve_mesh(mesh)
@@ -119,7 +119,6 @@ def spmm_sharded_overlap(fmt, b: jax.Array, *, mesh: Optional[Mesh] = None,
     m, _ = blocked.shape
     n = b.shape[-1]
     w = part.num_windows
-    v = blocked.vector_size
     ndev = mesh.shape["data"]
     model_ax, tp = _model_axis(mesh)
     if model_ax and (vb or bb) and h % tp == 0:
@@ -134,18 +133,14 @@ def spmm_sharded_overlap(fmt, b: jax.Array, *, mesh: Optional[Mesh] = None,
         vals3 = vals_l if vb else vals_l[None]
         b3 = b_l if bb else b_l[None]
         n_loc = b3.shape[-1]
-        nb_eff = min(n_blk, max(n_loc, 1))
-        n_pad = -(-n_loc // nb_eff) * nb_eff
-        if n_pad != n_loc:
-            b3 = jnp.pad(b3, ((0, 0), (0, 0), (0, n_pad - n_loc)))
-        hh = vals3.shape[0] if vb else (b3.shape[0] if bb else 1)
+        hh = vals3.shape[0] if vb else b3.shape[0]
 
         def compute(t):
-            out = _balanced_spmm_call(
-                bsw[t], bsm[t], blocked.cols, scales, vals3, b3,
-                num_windows=w + 1, v=v, k_blk=blocked.k_blk, n_blk=nb_eff,
-                h=hh, vals_batched=vb, b_batched=bb, interpret=interpret,
-                quantized=quantized)[:, :m, :n_loc]
+            out = _spmm_call(
+                schedule_steps(bsw[t], bsm[t]), blocked.cols, scales, vals3,
+                b3, num_windows=w + 1, k_blk=blocked.k_blk, n_blk=n_blk,
+                interpret=interpret, quantized=quantized)
+            out = out[:, :m, :n_loc].astype(b3.dtype)
             return _gather_rows(out, bri[t], m), bri[t]
 
         acc = jnp.zeros((hh, m, n_loc), b3.dtype)
@@ -161,9 +156,10 @@ def spmm_sharded_overlap(fmt, b: jax.Array, *, mesh: Optional[Mesh] = None,
         out_spec = P(model_ax) if mode == "heads" else P()
     else:
         out_spec = P(None, model_ax) if mode == "cols" else P()
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P("data"), P("data"), P("data"), v_spec, b_spec),
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("data"), P("data"), P("data"), v_spec,
+                                 b_spec),
+                       out_specs=out_spec, check_vma=False)
     return fn(part.bseg_win, part.bseg_meta, part.brow_idx, vals, b)
 
 
@@ -182,7 +178,8 @@ def sddmm_sharded_overlap(fmt, q: jax.Array, k: jax.Array, *,
     the "feat" TP mode still ``psum``s the partial products over
     ``"model"`` after the data-axis ring.
     """
-    from repro.kernels.sddmm_pallas import _balanced_sddmm_call, _cast_precision
+    from repro.kernels.layout import LANES
+    from repro.kernels.sddmm_pallas import _cast_precision, _sddmm, chunk_range
 
     q, k = _cast_precision(precision, q, k)
     blocked = fmt if isinstance(fmt, BlockedMEBCRS) else block_format(fmt, k_blk)
@@ -199,7 +196,6 @@ def sddmm_sharded_overlap(fmt, q: jax.Array, k: jax.Array, *,
     qb, kb = q.ndim == 3, k.ndim == 3
     h = q.shape[0] if qb else (k.shape[0] if kb else 1)
     v = blocked.vector_size
-    w = blocked.num_windows
     nb = blocked.num_blocks
     f = q.shape[-1]
     nnzp = nb * blocked.k_blk
@@ -214,28 +210,22 @@ def sddmm_sharded_overlap(fmt, q: jax.Array, k: jax.Array, *,
         mode = "feat"
     else:
         mode, model_ax = "none", None
+    num_chunks = chunk_range(part.bblk_id.shape[-1], blocked.k_blk)
 
-    def local(bbi, bbw, bvi, q_l, k_l):
-        bbi, bbw, bvi = bbi[0], bbw[0], bvi[0]
-        q3 = q_l if qb else q_l[None]
-        k3 = k_l if kb else k_l[None]
-        f_loc = q3.shape[-1]
-        fb_eff = min(f_blk, max(f_loc, 1))
-        f_pad = -(-f_loc // fb_eff) * fb_eff
-        qpad = jnp.zeros((q3.shape[0], w * v, f_pad), q.dtype
-                         ).at[:, : q3.shape[1], :f_loc].set(q3)
-        if f_pad != f_loc:
-            k3 = jnp.pad(k3, ((0, 0), (0, 0), (0, f_pad - f_loc)))
-        hh = q3.shape[0] if qb else (k3.shape[0] if kb else 1)
+    def local(bbi, bvi, q_l, k_l):
+        bbi, bvi = bbi[0], bvi[0]
+        hh = q_l.shape[0] if qb else (k_l.shape[0] if kb else 1)
 
         def compute(t):
-            out = _balanced_sddmm_call(
-                bbi[t], bbw[t], blocked.cols, qpad, k3, blocked.mask, v=v,
-                k_blk=blocked.k_blk, f_blk=fb_eff, h=hh, q_batched=qb,
-                k_batched=kb, nb=nb, interpret=interpret)
+            # batch t's blocks are the contiguous range starting at bbi[t, 0]
+            out = _sddmm(blocked, q_l, k_l, f_blk=f_blk, interpret=interpret,
+                         precision=None,
+                         chunk0=bbi[t, :1] * blocked.k_blk // LANES,
+                         num_chunks=num_chunks)
+            out = out if (qb or kb) else out[None]
             return _gather_rows(out, bvi[t], nnzp), bvi[t]
 
-        acc = jnp.zeros((hh, nnzp, v), q3.dtype)
+        acc = jnp.zeros((hh, nnzp, v), q_l.dtype)
         out = ring_scatter_pipeline(compute, _scatter_rows, acc,
                                     axis_name="data", axis_size=ndev,
                                     n_batches=nbat)
@@ -248,10 +238,10 @@ def sddmm_sharded_overlap(fmt, q: jax.Array, k: jax.Array, *,
     k_spec = (P(model_ax) if (mode == "heads" and kb)
               else (P(None, model_ax) if mode == "feat" else P()))
     out_spec = P(model_ax) if mode == "heads" else P()
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P("data"), P("data"), P("data"), q_spec, k_spec),
-                   out_specs=out_spec, check_rep=False)
-    return fn(part.bblk_id, part.bblk_win, part.bval_idx, q, k)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("data"), P("data"), q_spec, k_spec),
+                       out_specs=out_spec, check_vma=False)
+    return fn(part.bblk_id, part.bval_idx, q, k)
 
 
 def attention_sharded_overlap(fmt, q: jax.Array, k: jax.Array, v: jax.Array,
@@ -272,7 +262,8 @@ def attention_sharded_overlap(fmt, q: jax.Array, k: jax.Array, v: jax.Array,
     """
     import math
 
-    from repro.kernels.attention_pallas import _balanced_attn_call
+    from repro.kernels.attention_pallas import _attn_call
+    from repro.kernels.layout import schedule_steps
     from repro.kernels.sddmm_pallas import _cast_precision
 
     q, k, v = _cast_precision(precision, q, k, v)
@@ -297,7 +288,6 @@ def attention_sharded_overlap(fmt, q: jax.Array, k: jax.Array, v: jax.Array,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    maskf = blocked.mask.astype(jnp.float32)
     model_ax, tp = _model_axis(mesh)
     mode = "heads" if (model_ax and batched and h % tp == 0) else "none"
     if mode == "none":
@@ -314,11 +304,11 @@ def attention_sharded_overlap(fmt, q: jax.Array, k: jax.Array, v: jax.Array,
                    if f), 1)
 
         def compute(t):
-            out = _balanced_attn_call(
-                bsw[t], bsm[t], blocked.cols, qpad, k3, v3, maskf,
-                num_windows=w + 1, v=vsz, k_blk=blocked.k_blk, h=hh,
-                q_batched=qb, k_batched=kb, v_batched=vb,
-                interpret=interpret)[:, :m, :]
+            out = _attn_call(
+                schedule_steps(bsw[t], bsm[t]), blocked.cols, blocked.mask,
+                qpad, k3, v3, num_windows=w + 1, k_blk=blocked.k_blk,
+                interpret=interpret)
+            out = out[:, :m, :v3.shape[-1]].astype(v3.dtype)
             return _gather_rows(out, bri[t], m), bri[t]
 
         acc = jnp.zeros((hh, m, v3.shape[-1]), v3.dtype)
@@ -331,10 +321,10 @@ def attention_sharded_overlap(fmt, q: jax.Array, k: jax.Array, v: jax.Array,
         return P(model_ax) if (mode == "heads" and is_b) else P()
 
     out_spec = (P(model_ax) if mode == "heads" else P()) if batched else P()
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P("data"), P("data"), P("data"), spec(qb),
-                             spec(kb), spec(vb)),
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("data"), P("data"), P("data"), spec(qb),
+                                 spec(kb), spec(vb)),
+                       out_specs=out_spec, check_vma=False)
     return fn(part.bseg_win, part.bseg_meta, part.brow_idx, qs, k, v)
 
 

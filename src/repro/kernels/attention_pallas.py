@@ -10,7 +10,7 @@ blocks sees every score of its V rows and can finish their softmax without
 any cross-cell communication.
 
 Per K-block the cell DMAs the sampled K rows *and* the matching V rows
-(same scalar-prefetched column ids, one descriptor batch, double-buffered),
+(same column ids, one descriptor batch, double-buffered),
 computes the (K_BLK, V) score tile on the MXU, folds it into running
 per-row (max, sum) statistics, and accumulates the rescaled probability
 tile against the V rows into a VMEM-resident (V, DV) accumulator:
@@ -43,6 +43,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .layout import (CACHE_ROWS, LANES, SmemRows, block_tile, chunks_per_block,
+                     dot, lane_tile, pad_cols, schedule_steps, smem_cache,
+                     steps_table, sublanes, table, vectors_on_lanes,
+                     window_steps)
 from .sddmm_pallas import _cast_precision
 
 __all__ = [
@@ -55,130 +59,179 @@ __all__ = [
 _NEG = float(jnp.finfo(jnp.float32).min)  # same sentinel as sparse_softmax
 
 
-def _fused_attn_kernel(win_ptr_ref, cols_ref, q_ref, k_hbm, v_hbm, maskf_hbm,
-                       o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, mask_buf,
-                       sems, *, k_blk: int, k_batched: bool, v_batched: bool):
+def _attn_kernel(steps_hbm, cols_hbm, mask_hbm, q_hbm, k_hbm, v_hbm, o_hbm,
+                 acc_ref, m_ref, l_ref, q_buf, k_buf, v_buf, mask_buf,
+                 steps_buf, steps_base, cols_buf, cols_base, sems, *,
+                 k_blk: int, v: int, kv_rows: int, num_windows: int,
+                 q_batched: bool, k_batched: bool, v_batched: bool):
     h = pl.program_id(0)
-    w = pl.program_id(1)
-    kh = h if k_batched else 0      # static: shared operands read slice 0
-    vh = h if v_batched else 0
-    lo = win_ptr_ref[w]
-    hi = win_ptr_ref[w + 1]
+    s = pl.program_id(1)
+    # Heads are stacked on rows; shared operands read slice 0 (static).
+    qrow = h * num_windows * v if q_batched else 0
+    krow = h * kv_rows if k_batched else 0
+    vrow = h * kv_rows if v_batched else 0
+    steps = SmemRows(steps_hbm, steps_buf, steps_base, sems.at[0, 3])
+    cols = SmemRows(cols_hbm, cols_buf, cols_base, sems.at[1, 3])
 
-    def block_copies(blk, slot):
-        """DMA descriptors for K-block ``blk``: the (K_BLK, V) mask tile
-        plus K_BLK K-row and V-row slices at the block's column ids."""
+    @pl.when((h == 0) & (s == 0))
+    def _reset():
+        steps.reset()
+        cols.reset()
+
+    steps.ensure(8 * s, 8 * s + 4)
+    lo = steps[8 * s]
+    hi = lo + steps[8 * s + 1]
+    first = steps[8 * s + 2]
+    last = steps[8 * s + 3]
+    win = steps[8 * s + 4]
+    width = mask_buf.shape[-1]
+
+    def copies(blk, slot, for_wait=False):
+        """DMA descriptors for K-block ``blk``: the block's mask chunk plus
+        K_BLK K-row and V-row slices at the block's column ids."""
         base = blk * k_blk
-        cps = [pltpu.make_async_copy(
-            maskf_hbm.at[pl.ds(base, k_blk), :],
-            mask_buf.at[slot],
-            sems.at[slot, 0],
-        )]
+        chunk = 0 if for_wait else (base // LANES) * LANES
+        cps = [pltpu.make_async_copy(mask_hbm.at[:, pl.ds(chunk, width)],
+                                     mask_buf.at[slot], sems.at[slot, 0])]
         for r in range(k_blk):
-            c = cols_ref[base + r]
+            c = 0 if for_wait else cols[base + r]
             cps.append(pltpu.make_async_copy(
-                k_hbm.at[kh, pl.ds(c, 1), :],
-                k_buf.at[slot, pl.ds(r, 1)],
-                sems.at[slot, 1],
-            ))
+                k_hbm.at[pl.ds(krow + c, 1), :],
+                k_buf.at[slot, pl.ds(r, 1)], sems.at[slot, 1]))
             cps.append(pltpu.make_async_copy(
-                v_hbm.at[vh, pl.ds(c, 1), :],
-                v_buf.at[slot, pl.ds(r, 1)],
-                sems.at[slot, 2],
-            ))
+                v_hbm.at[pl.ds(vrow + c, 1), :],
+                v_buf.at[slot, pl.ds(r, 1)], sems.at[slot, 2]))
         return cps
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    m_ref[...] = jnp.full_like(m_ref, _NEG)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    qwin = q_ref[0].astype(jnp.float32)                      # (V, D) scaled Q
+    def start(blk, slot):
+        cols.ensure(blk * k_blk, blk * k_blk + k_blk - 1)
+        for cp in copies(blk, slot):
+            cp.start()
+
+    @pl.when(first == 1)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    cp = pltpu.make_async_copy(q_hbm.at[pl.ds(qrow + win * v, v), :], q_buf,
+                               sems.at[0, 4])
+    cp.start()
+    cp.wait()
+    qwin = q_buf[...]                                        # (V, D) scaled Q
 
     @pl.when(lo < hi)
     def _warmup():
-        for cp in block_copies(lo, 0):
-            cp.start()
+        start(lo, 0)
 
     def body(blk, carry):
         slot = jax.lax.rem(blk - lo, 2)
 
         @pl.when(blk + 1 < hi)
         def _prefetch_next():
-            for cp in block_copies(blk + 1, 1 - slot):
-                cp.start()
+            start(blk + 1, 1 - slot)
 
-        for cp in block_copies(blk, slot):
+        for cp in copies(blk, slot, for_wait=True):
             cp.wait()
 
-        maskf = mask_buf[slot]                               # (K_BLK, V) f32
-        s = jax.lax.dot_general(                             # (K_BLK, V)
-            k_buf[slot].astype(jnp.float32), qwin,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        s = jnp.where(maskf > 0, s, _NEG)
+        maskf = block_tile(mask_buf[slot], blk * k_blk, k_blk)  # (V, K_BLK)
+        sc = dot(qwin, k_buf[slot, pl.ds(0, k_blk), :], ((1,), (1,)))
+        sc = jnp.where(maskf > 0, sc, _NEG)
         m_new = jnp.maximum(m_ref[...],
-                            jnp.max(s, axis=0, keepdims=True))   # (1, V)
-        alpha = jnp.exp(m_ref[...] - m_new)                      # (1, V)
-        p = jnp.exp(s - m_new) * maskf                           # (K_BLK, V)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha.T + jax.lax.dot_general(
-            p, v_buf[slot].astype(jnp.float32),
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                        # (V, DV)
+                            jnp.max(sc, axis=1, keepdims=True))  # (V, 1)
+        alpha = jnp.exp(m_ref[...] - m_new)                      # (V, 1)
+        p = jnp.exp(sc - m_new) * maskf                          # (V, K_BLK)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + dot(
+            p, v_buf[slot, pl.ds(0, k_blk), :])                  # (V, DV)
         m_ref[...] = m_new
         return carry
 
     jax.lax.fori_loop(lo, hi, body, 0)
-    # Fused epilogue: normalize and cast in-kernel.  Empty windows / fully
-    # masked rows keep l = 0 → output 0, matching sparse_softmax ∘ SpMM.
-    denom = jnp.maximum(l_ref[...], 1e-20)                       # (1, V)
-    o_ref[...] = (acc_ref[...] / denom.T).astype(o_ref.dtype)[None]
+
+    @pl.when(last == 1)
+    def _epilogue():
+        # Normalize in-kernel.  Empty windows / fully masked rows keep
+        # l = 0 → output 0, matching sparse_softmax ∘ SpMM.
+        acc_ref[...] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
+        cp = pltpu.make_async_copy(
+            acc_ref, o_hbm.at[pl.ds((h * num_windows + win) * v, v), :],
+            sems.at[1, 4])
+        cp.start()
+        cp.wait()
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("num_windows", "v", "k_blk", "h", "q_batched",
-                     "k_batched", "v_batched", "interpret"),
-)
-def _fused_attn_call(win_ptr, cols, q3, k3, v3, maskf, *, num_windows, v,
-                     k_blk, h, q_batched, k_batched, v_batched, interpret):
-    d = q3.shape[-1]
-    dv = v3.shape[-1]
-    grid = (h, num_windows)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, v, d),
-                lambda hh, w, wp, c: ((hh if q_batched else 0), w, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # K stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # V stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # mask (f32) stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1, v, dv), lambda hh, w, wp, c: (hh, w, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((v, dv), jnp.float32),        # output accumulator
-            pltpu.VMEM((1, v), jnp.float32),         # running row max
-            pltpu.VMEM((1, v), jnp.float32),         # running row sum
-            pltpu.VMEM((2, k_blk, d), k3.dtype),     # K-rows double-buffer
-            pltpu.VMEM((2, k_blk, dv), v3.dtype),    # V-rows double-buffer
-            pltpu.VMEM((2, k_blk, v), jnp.float32),  # mask double-buffer
-            pltpu.SemaphoreType.DMA((2, 3)),
-        ],
-    )
+    jax.jit, static_argnames=("num_windows", "k_blk", "interpret"))
+def _attn_call(steps, cols, mask, q3, k3, v3, *, num_windows, k_blk,
+               interpret):
+    """Launch :func:`_attn_kernel` over ``steps`` (``(S, 5)``, see
+    :func:`layout.window_steps`).  ``q3`` ``(1 | H, num_windows·V, D)``
+    (scaled, window rows padded); ``k3``/``v3`` ``(1 | H, Mc, D | DV)``.
+    Returns fp32 ``(H, num_windows·V, DV_pad)``; rows of windows no step
+    stores are left unwritten."""
+    _, v = mask.shape
+    hq, _, d = q3.shape
+    hk, kv_rows, _ = k3.shape
+    hv, _, dv = v3.shape
+    h = max(hq, hk, hv)
+    q3, k3 = (pad_cols(x.astype(jnp.float32), lane_tile(d, d))
+              for x in (q3, k3))
+    v3 = pad_cols(v3.astype(jnp.float32), lane_tile(dv, dv))
+    width = chunks_per_block(k_blk) * LANES
+    cols_rows = max(CACHE_ROWS, width // LANES + 1)
     kernel = functools.partial(
-        _fused_attn_kernel, k_blk=k_blk, k_batched=k_batched,
-        v_batched=v_batched)
-    out_shape = jax.ShapeDtypeStruct((h, num_windows * v, dv), v3.dtype)
+        _attn_kernel, k_blk=k_blk, v=v, kv_rows=kv_rows,
+        num_windows=num_windows, q_batched=hq > 1, k_batched=hk > 1,
+        v_batched=hv > 1)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    dp, dvp = q3.shape[-1], v3.shape[-1]
     return pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
+        grid=(h, steps.shape[0]),
+        in_specs=[any_spec] * 6,
+        out_specs=any_spec,
+        out_shape=jax.ShapeDtypeStruct((h * num_windows * v, dvp),
+                                       jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((v, dvp), jnp.float32),          # output accumulator
+            pltpu.VMEM((v, 1), jnp.float32),            # running row max
+            pltpu.VMEM((v, 1), jnp.float32),            # running row sum
+            pltpu.VMEM((v, dp), jnp.float32),           # the window's Q
+            pltpu.VMEM((2, sublanes(k_blk), dp), jnp.float32),   # K rows
+            pltpu.VMEM((2, sublanes(k_blk), dvp), jnp.float32),  # V rows
+            pltpu.VMEM((2, v, width), jnp.float32),     # mask chunks
+            *smem_cache(),                              # step table
+            *smem_cache(rows=cols_rows),                # cols
+            pltpu.SemaphoreType.DMA((2, 5)),
+        ],
         interpret=interpret,
-    )(win_ptr, cols, q3, k3, v3, maskf)
+    )(steps_table(steps), table(cols, cols_rows),
+      vectors_on_lanes(mask, k_blk), q3.reshape(-1, dp),
+      k3.reshape(-1, dp), v3.reshape(-1, dvp)).reshape(h, num_windows * v,
+                                                       dvp)
+
+
+def _attention(blocked, q, k, v, steps, *, scale, precision, interpret,
+               num_windows=None):
+    """Precision policy, scale folding and launch: ``(M, DV)`` or
+    ``(H, M, DV)`` in ``v``'s (narrowed) dtype."""
+    q, k, v = _cast_precision(precision, q, k, v)
+    vsz = blocked.vector_size
+    w = num_windows or blocked.num_windows
+    m, _ = blocked.shape
+    qb, kb, vb = q.ndim == 3, k.ndim == 3, v.ndim == 3
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    q3 = qs if qb else qs[None]
+    qpad = jnp.zeros((q3.shape[0], w * vsz, q.shape[-1]), q.dtype
+                     ).at[:, : q3.shape[1], :].set(q3)
+    out = _attn_call(steps, blocked.cols, blocked.mask, qpad,
+                     k if kb else k[None], v if vb else v[None],
+                     num_windows=w, k_blk=blocked.k_blk, interpret=interpret)
+    out = out[:, :m, : v.shape[-1]].astype(v.dtype)
+    return out if (qb or kb or vb) else out[0]
 
 
 def attention_pallas(blocked, q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -191,186 +244,24 @@ def attention_pallas(blocked, q: jax.Array, k: jax.Array, v: jax.Array, *,
     **one** ``(H, W)`` grid launch.  ``scale`` defaults to ``1/sqrt(D)``
     and may be a traced scalar (it is folded into Q before the kernel —
     the scores themselves never exist outside VMEM).  Returns ``(M, DV)``
-    or ``(H, M, DV)`` in ``v`` dtype.  ``precision`` ("fp32"/"bf16") casts
-    Q/K/V before the launch; the online-softmax statistics and the output
-    accumulator stay fp32 in VMEM either way (DESIGN.md §13).
+    or ``(H, M, DV)`` in ``v`` dtype.  ``precision`` ("fp32"/"bf16")
+    rounds Q/K/V before the launch; the online-softmax statistics and the
+    output accumulator stay fp32 in VMEM either way (DESIGN.md §13).
     """
-    q, k, v = _cast_precision(precision, q, k, v)
-    vsz = blocked.vector_size
-    w = blocked.num_windows
-    m, _ = blocked.shape
-    qb, kb, vb = q.ndim == 3, k.ndim == 3, v.ndim == 3
-    batched = qb or kb or vb
-    h = next((x.shape[0] for x, f in ((q, qb), (k, kb), (v, vb)) if f), 1)
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
-
-    q3 = qs if qb else qs[None]
-    k3 = k if kb else k[None]
-    v3 = v if vb else v[None]
-    qpad = jnp.zeros((q3.shape[0], w * vsz, q.shape[-1]), q.dtype
-                     ).at[:, : q3.shape[1], :].set(q3)
-    maskf = blocked.mask.astype(jnp.float32)
-
-    out = _fused_attn_call(
-        blocked.win_ptr, blocked.cols, qpad, k3, v3, maskf,
-        num_windows=w, v=vsz, k_blk=blocked.k_blk, h=h,
-        q_batched=qb, k_batched=kb, v_batched=vb, interpret=interpret,
-    )
-    out = out[:, :m, :]
-    return out if batched else out[0]
+    return _attention(blocked, q, k, v, window_steps(blocked.win_ptr),
+                      scale=scale, precision=precision, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
-# Block-parallel load-balanced megakernel (DESIGN.md §11).  Grid (H, NS)
-# over uniform schedule segments instead of (H, W) over ragged windows: a
-# hub window's online softmax is split across several cells, each walking
-# at most ``split_blk`` K-blocks.  The running statistics (row max ``m``,
-# row sum ``l``) and the (V, DV) accumulator live in VMEM scratch, which
-# persists across the sequential grid — so carrying them across the split
-# segments of one window is a straight extension of the row-segment
-# rescale the fused kernel already does per block: init on ``seg_first``,
-# the identical per-block update in the identical ascending order
-# (bitwise-equal fp32), normalize + store on ``seg_last``.  Empty windows
-# are zero-length segments whose epilogue stores zeros (l stays 0),
-# matching sparse_softmax ∘ SpMM semantics in-kernel.
+# Load-balanced grid (DESIGN.md §11): the same kernel over a Schedule's
+# uniform segments instead of ragged windows — a hub window's online
+# softmax is split across several steps, each walking at most
+# ``split_blk`` K-blocks.  The running statistics (row max ``m``, row sum
+# ``l``) and the (V, DV) accumulator live in VMEM scratch, which persists
+# across the sequential grid, so carrying them across the split segments
+# of one window is the same per-block rescale in the same ascending order
+# (bitwise-equal fp32): init on ``first``, normalize + store on ``last``.
 # ---------------------------------------------------------------------------
-
-
-def _balanced_attn_kernel(seg_win_ref, seg_meta_ref, cols_ref, q_ref, k_hbm,
-                          v_hbm, maskf_hbm, o_ref, acc_ref, m_ref, l_ref,
-                          k_buf, v_buf, mask_buf, sems, *, k_blk: int,
-                          k_batched: bool, v_batched: bool):
-    h = pl.program_id(0)
-    s = pl.program_id(1)
-    kh = h if k_batched else 0      # static: shared operands read slice 0
-    vh = h if v_batched else 0
-    lo = seg_meta_ref[s, 0]
-    hi = lo + seg_meta_ref[s, 1]
-    seg_first = seg_meta_ref[s, 2]
-    seg_last = seg_meta_ref[s, 3]
-
-    def block_copies(blk, slot):
-        base = blk * k_blk
-        cps = [pltpu.make_async_copy(
-            maskf_hbm.at[pl.ds(base, k_blk), :],
-            mask_buf.at[slot],
-            sems.at[slot, 0],
-        )]
-        for r in range(k_blk):
-            c = cols_ref[base + r]
-            cps.append(pltpu.make_async_copy(
-                k_hbm.at[kh, pl.ds(c, 1), :],
-                k_buf.at[slot, pl.ds(r, 1)],
-                sems.at[slot, 1],
-            ))
-            cps.append(pltpu.make_async_copy(
-                v_hbm.at[vh, pl.ds(c, 1), :],
-                v_buf.at[slot, pl.ds(r, 1)],
-                sems.at[slot, 2],
-            ))
-        return cps
-
-    @pl.when(seg_first == 1)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    qwin = q_ref[0].astype(jnp.float32)                      # (V, D) scaled Q
-
-    @pl.when(lo < hi)
-    def _warmup():
-        for cp in block_copies(lo, 0):
-            cp.start()
-
-    def body(blk, carry):
-        slot = jax.lax.rem(blk - lo, 2)
-
-        @pl.when(blk + 1 < hi)
-        def _prefetch_next():
-            for cp in block_copies(blk + 1, 1 - slot):
-                cp.start()
-
-        for cp in block_copies(blk, slot):
-            cp.wait()
-
-        maskf = mask_buf[slot]                               # (K_BLK, V) f32
-        sc = jax.lax.dot_general(                            # (K_BLK, V)
-            k_buf[slot].astype(jnp.float32), qwin,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        sc = jnp.where(maskf > 0, sc, _NEG)
-        m_new = jnp.maximum(m_ref[...],
-                            jnp.max(sc, axis=0, keepdims=True))  # (1, V)
-        alpha = jnp.exp(m_ref[...] - m_new)                      # (1, V)
-        p = jnp.exp(sc - m_new) * maskf                          # (K_BLK, V)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha.T + jax.lax.dot_general(
-            p, v_buf[slot].astype(jnp.float32),
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                        # (V, DV)
-        m_ref[...] = m_new
-        return carry
-
-    jax.lax.fori_loop(lo, hi, body, 0)
-
-    @pl.when(seg_last == 1)
-    def _epilogue():
-        denom = jnp.maximum(l_ref[...], 1e-20)                   # (1, V)
-        o_ref[...] = (acc_ref[...] / denom.T).astype(o_ref.dtype)[None]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_windows", "v", "k_blk", "h", "q_batched",
-                     "k_batched", "v_batched", "interpret"),
-)
-def _balanced_attn_call(seg_win, seg_meta, cols, q3, k3, v3, maskf, *,
-                        num_windows, v, k_blk, h, q_batched, k_batched,
-                        v_batched, interpret):
-    d = q3.shape[-1]
-    dv = v3.shape[-1]
-    ns = seg_win.shape[0]
-    grid = (h, ns)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, v, d),
-                lambda hh, s, sw, sm, c: (
-                    (hh if q_batched else 0), sw[s], 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # K stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # V stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # mask (f32) stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1, v, dv),
-                               lambda hh, s, sw, sm, c: (hh, sw[s], 0)),
-        scratch_shapes=[
-            pltpu.VMEM((v, dv), jnp.float32),        # output accumulator
-            pltpu.VMEM((1, v), jnp.float32),         # running row max
-            pltpu.VMEM((1, v), jnp.float32),         # running row sum
-            pltpu.VMEM((2, k_blk, d), k3.dtype),     # K-rows double-buffer
-            pltpu.VMEM((2, k_blk, dv), v3.dtype),    # V-rows double-buffer
-            pltpu.VMEM((2, k_blk, v), jnp.float32),  # mask double-buffer
-            pltpu.SemaphoreType.DMA((2, 3)),
-        ],
-    )
-    kernel = functools.partial(
-        _balanced_attn_kernel, k_blk=k_blk, k_batched=k_batched,
-        v_batched=v_batched)
-    out_shape = jax.ShapeDtypeStruct((h, num_windows * v, dv), v3.dtype)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(seg_win, seg_meta, cols, q3, k3, v3, maskf)
 
 
 def attention_pallas_balanced(blocked, q: jax.Array, k: jax.Array,
@@ -389,31 +280,9 @@ def attention_pallas_balanced(blocked, q: jax.Array, k: jax.Array,
     """
     if schedule is None:
         schedule = blocked.schedule(split_blk)
-    q, k, v = _cast_precision(precision, q, k, v)
-    vsz = blocked.vector_size
-    w = blocked.num_windows
-    m, _ = blocked.shape
-    qb, kb, vb = q.ndim == 3, k.ndim == 3, v.ndim == 3
-    batched = qb or kb or vb
-    h = next((x.shape[0] for x, f in ((q, qb), (k, kb), (v, vb)) if f), 1)
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
-
-    q3 = qs if qb else qs[None]
-    k3 = k if kb else k[None]
-    v3 = v if vb else v[None]
-    qpad = jnp.zeros((q3.shape[0], w * vsz, q.shape[-1]), q.dtype
-                     ).at[:, : q3.shape[1], :].set(q3)
-    maskf = blocked.mask.astype(jnp.float32)
-
-    out = _balanced_attn_call(
-        schedule.seg_win, schedule.seg_meta, blocked.cols, qpad, k3, v3,
-        maskf, num_windows=w, v=vsz, k_blk=blocked.k_blk, h=h,
-        q_batched=qb, k_batched=kb, v_batched=vb, interpret=interpret,
-    )
-    out = out[:, :m, :]
-    return out if batched else out[0]
+    return _attention(blocked, q, k, v,
+                      schedule_steps(schedule.seg_win, schedule.seg_meta),
+                      scale=scale, precision=precision, interpret=interpret)
 
 
 def attention_pallas_staged(blocked, q: jax.Array, k: jax.Array,

@@ -3,9 +3,10 @@
 ``interpret`` semantics (shared by every wrapper and by the ``core``
 dispatch layer, which threads ``interpret=None`` straight through):
 
-  * ``None`` (default) — auto-detect: compile to Mosaic when the default
-    JAX backend is a TPU, otherwise fall back to interpret mode, which
-    executes the kernel bodies in Python for correctness validation.
+  * ``None`` (default) — compile to Mosaic when the default JAX backend
+    is a TPU; interpret mode (the kernel bodies run as plain JAX, for
+    correctness validation) when it is the CPU.  Any other backend raises:
+    the kernels are never silently interpreted next to an accelerator.
   * ``True`` / ``False`` — force interpret / compiled mode explicitly.
 
 The ``*_tuned`` wrappers consult the :mod:`repro.kernels.autotune`
@@ -62,12 +63,16 @@ __all__ = [
 ]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _resolve_interpret(interpret: bool | None) -> bool:
-    return (not _on_tpu()) if interpret is None else interpret
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels compile for TPU only and interpret on the "
+            f"CPU; backend {backend!r} is neither (pass interpret=True to "
+            f"run them in interpret mode anyway)")
+    return backend == "cpu"
 
 
 def spmm(blocked, b_dense, *, n_blk: int = 128, interpret: bool | None = None,

@@ -8,14 +8,15 @@ sub-blocks; on TPU the block layouts coincide by construction.
 
 Gather-free (DESIGN.md §3): K stays in HBM (``memory_space=ANY``) and the
 kernel DMAs the K_BLK rows each sparse block samples — at the feature tile
-currently being contracted — into a double-buffered VMEM scratch, driven by
-the scalar-prefetched ``cols``.  This removes the ``(NB·K_BLK, F)`` staged
+currently being contracted — into a double-buffered VMEM scratch, at the
+column ids read through an SMEM cache of ``cols``.  This removes the ``(NB·K_BLK, F)`` staged
 gather the previous pipeline materialized in HBM.  The sparsity mask and
 the cast to the input dtype are fused into the final-feature-tile epilogue.
 
-Grid ``(NB, F / F_BLK)`` with the feature dimension innermost: the fp32
-accumulator for sparse block ``b`` stays resident in VMEM scratch while the
-QKᵀ contraction walks the feature tiles.
+Grid ``(H, chunks, F / F_BLK)`` over chunks of 128 consecutive vectors
+(the lane-dense output tile, :mod:`.layout`), the feature dimension
+innermost: the chunk's fp32 accumulator stays resident in VMEM scratch
+while the QKᵀ contraction walks the feature tiles.
 """
 
 from __future__ import annotations
@@ -26,6 +27,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .layout import (CACHE_ROWS, LANES, SmemRows, dot, lane_tile, pad_cols,
+                     smem_cache, table, vectors_on_lanes)
 
 __all__ = [
     "sddmm_pallas",
@@ -46,89 +50,189 @@ def _cast_precision(precision, *operands):
     return cast_precision(precision, *operands)
 
 
-def _fused_sddmm_kernel(block_win_ref, cols_ref, q_ref, k_hbm, mask_ref,
-                        o_ref, acc_ref, k_buf, sems, *,
-                        k_blk: int, f_blk: int, nf: int):
-    b = pl.program_id(0)
-    fi = pl.program_id(1)
-    base = b * k_blk
+def _sddmm_kernel(chunk0_ref, cols_hbm, wp_hbm, bw_hbm, q_hbm, k_hbm,
+                  mask_ref, o_ref, acc_ref, k_buf, q_buf, cols_buf, cols_base,
+                  wp_buf, wp_base, bw_buf, bw_base, sems, *, k_blk: int,
+                  nf: int, v: int, nnzp: int, num_windows: int, k_rows: int,
+                  q_batched: bool, k_batched: bool):
+    h = pl.program_id(0)
+    c = pl.program_id(1)
+    fi = pl.program_id(2)
+    qh = h if q_batched else 0      # static: shared operands read slice 0
+    kh = h if k_batched else 0
+    t0 = (chunk0_ref[0] + c) * LANES
+    cols = SmemRows(cols_hbm, cols_buf, cols_base, sems.at[2])
+    win_ptr = SmemRows(wp_hbm, wp_buf, wp_base, sems.at[3])
+    block_win = SmemRows(bw_hbm, bw_buf, bw_base, sems.at[3])
 
-    def row_copies(tile_fi, slot):
-        """K_BLK single-row DMA descriptors of K's feature tile ``tile_fi``
-        at the block's scalar-prefetched column ids."""
-        return [
-            pltpu.make_async_copy(
-                k_hbm.at[pl.ds(cols_ref[base + r], 1),
-                         pl.ds(tile_fi * f_blk, f_blk)],
-                k_buf.at[slot, pl.ds(r, 1)],
-                sems.at[slot],
-            )
-            for r in range(k_blk)
-        ]
+    @pl.when((h == 0) & (c == 0) & (fi == 0))
+    def _reset():
+        for cache in (cols, win_ptr, block_win):
+            cache.reset()
+
+    cols.ensure(t0, t0 + LANES - 1)
+
+    def row_copy(r, tile_fi, slot, for_wait=False):
+        """Single K-row DMA: the chunk's vector ``r`` at feature tile
+        ``tile_fi`` (waits need only destination and semaphore)."""
+        row = 0 if for_wait else (kh * nf + tile_fi) * k_rows + cols[t0 + r]
+        return pltpu.make_async_copy(k_hbm.at[pl.ds(row, 1), :],
+                                     k_buf.at[slot, pl.ds(r, 1)],
+                                     sems.at[slot])
+
+    def start_rows(tile_fi, slot):
+        def body(r, carry):
+            row_copy(r, tile_fi, slot).start()
+            return carry
+        jax.lax.fori_loop(0, LANES, body, 0)
 
     @pl.when(fi == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        for cp in row_copies(0, 0):
-            cp.start()
+        start_rows(0, 0)
 
     slot = jax.lax.rem(fi, 2)
 
     @pl.when(fi + 1 < nf)
     def _prefetch_next():
-        for cp in row_copies(fi + 1, 1 - slot):
+        start_rows(fi + 1, 1 - slot)
+
+    def wait_body(r, carry):
+        row_copy(r, 0, slot, for_wait=True).wait()
+        return carry
+
+    jax.lax.fori_loop(0, LANES, wait_body, 0)
+
+    # The chunk's vectors belong to the windows [w_lo, w_hi] (blocks of a
+    # window are contiguous): contract each window's Q tile against all
+    # 128 sampled K rows and keep the lanes that window owns.
+    t_first = jnp.minimum(t0, nnzp - 1)
+    t_last = jnp.minimum(t0 + LANES - 1, nnzp - 1)
+    block_win.ensure(t_first // k_blk, t_last // k_blk)
+    w_lo = block_win[t_first // k_blk]
+    w_hi = block_win[t_last // k_blk]
+    lane_t = t0 + jax.lax.broadcasted_iota(jnp.int32, (v, LANES), 1)
+    krows = k_buf[slot]
+
+    def window(w, carry):
+        win_ptr.ensure(w, w + 1)
+        lo_t = win_ptr[w] * k_blk
+        hi_t = win_ptr[w + 1] * k_blk
+
+        @pl.when(lo_t < hi_t)
+        def _contract():
+            row = ((qh * nf + fi) * num_windows + w) * v
+            cp = pltpu.make_async_copy(q_hbm.at[pl.ds(row, v), :], q_buf,
+                                       sems.at[4])
             cp.start()
+            cp.wait()
+            # (V, 128) = Q window tile (V, F_BLK) @ K rows (128, F_BLK)ᵀ
+            scores = dot(q_buf[...], krows, ((1,), (1,)))
+            owned = (lane_t >= lo_t) & (lane_t < hi_t)
+            acc_ref[...] += jnp.where(owned, scores, 0.0)
 
-    for cp in row_copies(fi, slot):
-        cp.wait()
+        return carry
 
-    # (K_BLK, V) += krows (K_BLK, F_BLK) @ qᵀ (F_BLK, V)
-    acc_ref[...] += jax.lax.dot_general(
-        k_buf[slot].astype(jnp.float32),
-        q_ref[...].astype(jnp.float32),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    jax.lax.fori_loop(w_lo, w_hi + 1, window, 0)
 
     @pl.when(fi == nf - 1)
     def _epilogue():
-        # Fused epilogue: sample at the sparsity pattern and cast in-kernel.
-        o_ref[...] = (acc_ref[...] * mask_ref[...].astype(jnp.float32)
-                      ).astype(o_ref.dtype)
+        # Fused epilogue: sample at the sparsity pattern in-kernel.
+        o_ref[...] = (acc_ref[...] * mask_ref[...])[None]
 
 
-@functools.partial(jax.jit, static_argnames=("v", "k_blk", "f_blk", "interpret"))
-def _fused_sddmm_call(block_win, cols, qpad, k_dense, mask, *, v, k_blk,
-                      f_blk, interpret):
-    nb = block_win.shape[0]
-    f_pad = qpad.shape[1]
-    nf = f_pad // f_blk
-    grid = (nb, nf)
+@functools.partial(
+    jax.jit, static_argnames=("k_blk", "f_blk", "num_chunks", "interpret"))
+def _sddmm_call(chunk0, cols, win_ptr, block_win, mask, q3, k3, *, k_blk,
+                f_blk, num_chunks, interpret):
+    """Launch :func:`_sddmm_kernel` over ``num_chunks`` chunks of 128
+    vectors starting at chunk ``chunk0`` (a traced ``(1,)`` int32).
 
+    ``mask`` ``(NNZP, V)``; ``q3`` ``(1 | H, W·V, F)`` (window rows padded);
+    ``k3`` ``(1 | H, Mc, F)``.  Returns fp32 ``(H, V, num_chunks·128)``:
+    the sampled scores with the vector index on lanes.
+    """
+    nnzp, v = mask.shape
+    hq, qrows, _ = q3.shape
+    hk, k_rows, _ = k3.shape
+    h = max(hq, hk)
+    f_blk = lane_tile(f_blk, q3.shape[-1])
+    q3 = pad_cols(q3.astype(jnp.float32), f_blk)
+    k3 = pad_cols(k3.astype(jnp.float32), f_blk)
+    nf = q3.shape[-1] // f_blk
+    lanes = (-(-nnzp // LANES) + num_chunks) * LANES
+    mask_t = pad_cols(vectors_on_lanes(mask, 1), lanes)
+    # Column tiles stacked on rows, so every DMA copies whole rows.
+    q_tiles = q3.reshape(hq, qrows, nf, f_blk).swapaxes(1, 2)
+    k_tiles = k3.reshape(hk, k_rows, nf, f_blk).swapaxes(1, 2)
+    kernel = functools.partial(
+        _sddmm_kernel, k_blk=k_blk, nf=nf, v=v, nnzp=nnzp,
+        num_windows=qrows // v, k_rows=k_rows, q_batched=hq > 1,
+        k_batched=hk > 1)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((v, f_blk), lambda b, fi, bw, c: (bw[b], fi)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # K stays in HBM
-            pl.BlockSpec((k_blk, v), lambda b, fi, bw, c: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((k_blk, v), lambda b, fi, bw, c: (b, 0)),
+        num_scalar_prefetch=1,
+        grid=(h, num_chunks, nf),
+        in_specs=[any_spec] * 5 + [
+            pl.BlockSpec((v, LANES), lambda hh, c, fi, c0: (0, c0[0] + c))],
+        out_specs=pl.BlockSpec((1, v, LANES),
+                               lambda hh, c, fi, c0: (hh, 0, c)),
         scratch_shapes=[
-            pltpu.VMEM((k_blk, v), jnp.float32),           # fp32 accumulator
-            pltpu.VMEM((2, k_blk, f_blk), k_dense.dtype),  # K-rows buffer
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((v, LANES), jnp.float32),         # fp32 accumulator
+            pltpu.VMEM((2, LANES, f_blk), jnp.float32),  # sampled K rows
+            pltpu.VMEM((v, f_blk), jnp.float32),         # one window of Q
+            *smem_cache(), *smem_cache(), *smem_cache(),  # cols, win_ptr, block_win
+            pltpu.SemaphoreType.DMA((5,)),
         ],
     )
-    out_shape = jax.ShapeDtypeStruct((nb * k_blk, v), qpad.dtype)
-    kernel = functools.partial(
-        _fused_sddmm_kernel, k_blk=k_blk, f_blk=f_blk, nf=nf)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct((h, v, num_chunks * LANES),
+                                       jnp.float32),
         interpret=interpret,
-    )(block_win, cols, qpad, k_dense, mask)
+    )(chunk0, table(cols, num_chunks + CACHE_ROWS), table(win_ptr),
+      table(block_win), q_tiles.reshape(-1, f_blk),
+      k_tiles.reshape(-1, f_blk), mask_t)
+
+
+def chunk_range(num_blocks: int, k_blk: int) -> int:
+    """Chunks of 128 vectors that cover ``num_blocks`` contiguous K-blocks
+    wherever the first one starts."""
+    return -(-(LANES - 1 + num_blocks * k_blk) // LANES)
+
+
+def _sddmm(blocked, q, k, *, f_blk, interpret, precision, chunk0=None,
+           num_chunks=None):
+    """Precision policy + launch, back in the blocked value layout:
+    ``(NNZP, V)`` or ``(H, NNZP, V)`` in ``q``'s (narrowed) dtype.
+
+    ``chunk0``/``num_chunks`` restrict the launch to a chunk range (a
+    device's share of the blocks); vectors outside it come back zero.
+    """
+    q, k = _cast_precision(precision, q, k)
+    qb, kb = q.ndim == 3, k.ndim == 3
+    v = blocked.vector_size
+    nnzp = blocked.cols.shape[0]
+    q3 = q if qb else q[None]
+    k3 = k if kb else k[None]
+    qpad = jnp.zeros((q3.shape[0], blocked.num_windows * v, q.shape[-1]),
+                     q.dtype).at[:, : q3.shape[1], :].set(q3)
+    ranged = num_chunks is not None
+    if not ranged:
+        chunk0, num_chunks = jnp.zeros((1,), jnp.int32), -(-nnzp // LANES)
+    out = _sddmm_call(chunk0, blocked.cols, blocked.win_ptr,
+                      blocked.block_win, blocked.mask, qpad, k3,
+                      k_blk=blocked.k_blk, f_blk=f_blk,
+                      num_chunks=num_chunks, interpret=interpret)
+    out = jnp.swapaxes(out, 1, 2)                        # (H, C·128, V)
+    if ranged:
+        full = jnp.zeros((out.shape[0], nnzp + num_chunks * LANES, v),
+                         out.dtype)
+        out = jax.lax.dynamic_update_slice(full, out,
+                                           (0, chunk0[0] * LANES, 0))
+    out = out[:, :nnzp].astype(q.dtype)
+    return out if (qb or kb) else out[0]
 
 
 def sddmm_pallas(blocked, q: jax.Array, k: jax.Array, *, f_blk: int = 128,
@@ -136,271 +240,32 @@ def sddmm_pallas(blocked, q: jax.Array, k: jax.Array, *, f_blk: int = 128,
                  precision: str | None = None) -> jax.Array:
     """Gather-free SDDMM over a :class:`BlockedMEBCRS` pattern.
 
-    Returns blocked-layout values ``(NB * K_BLK, V)`` in ``q`` dtype,
-    directly consumable by :func:`repro.core.sddmm.with_values` + SpMM.
-    K's sampled rows are DMA'd in-kernel; no staged gather of K remains.
-    ``precision`` ("fp32"/"bf16") casts Q and K before the launch so they
-    DMA narrow; accumulation stays fp32 in-kernel.
+    Returns blocked-layout values ``(NNZP, V)`` in ``q`` dtype, directly
+    consumable by :func:`repro.core.sddmm.with_values` + SpMM.  The grid
+    runs over chunks of 128 consecutive vectors: each chunk DMAs its
+    sampled K rows in-kernel (no staged gather of K) and contracts them
+    against the Q tile of every window it touches.  ``precision``
+    ("fp32"/"bf16") rounds Q and K before the launch; accumulation stays
+    fp32 in-kernel.  Either operand may carry a leading head dim (see
+    :func:`sddmm_pallas_batched`).
     """
-    q, k = _cast_precision(precision, q, k)
-    v = blocked.vector_size
-    w = blocked.num_windows
-    f = q.shape[1]
-    f_blk = min(f_blk, max(f, 1))
-    f_pad = -(-f // f_blk) * f_blk
-
-    qpad = jnp.zeros((w * v, f_pad), q.dtype).at[: q.shape[0], :f].set(q)
-    k_padded = k if f_pad == f else jnp.pad(k, ((0, 0), (0, f_pad - f)))
-
-    return _fused_sddmm_call(
-        blocked.block_win, blocked.cols, qpad, k_padded, blocked.mask,
-        v=v, k_blk=blocked.k_blk, f_blk=f_blk, interpret=interpret,
-    )
-
-
-# ---------------------------------------------------------------------------
-# Batched (head-major) variant: grid (H, NB, F / F_BLK).  One launch for
-# any number of heads, scalar-prefetch metadata shared across the grid.
-# Q and/or K may carry a leading per-head dim; shared operands are passed
-# as a single (1, ...) slice (no H-fold HBM broadcast).  Per-(h, b, fi)
-# cell the arithmetic matches :func:`_fused_sddmm_kernel` exactly, so the
-# batched launch is bitwise-equal to the per-slice loop it replaces.
-# ---------------------------------------------------------------------------
-
-
-def _batched_sddmm_kernel(block_win_ref, cols_ref, q_ref, k_hbm, mask_ref,
-                          o_ref, acc_ref, k_buf, sems, *,
-                          k_blk: int, f_blk: int, nf: int, k_batched: bool):
-    h = pl.program_id(0)
-    b = pl.program_id(1)
-    fi = pl.program_id(2)
-    kh = h if k_batched else 0      # static: shared K reads slice 0
-    base = b * k_blk
-
-    def row_copies(tile_fi, slot):
-        return [
-            pltpu.make_async_copy(
-                k_hbm.at[kh, pl.ds(cols_ref[base + r], 1),
-                         pl.ds(tile_fi * f_blk, f_blk)],
-                k_buf.at[slot, pl.ds(r, 1)],
-                sems.at[slot],
-            )
-            for r in range(k_blk)
-        ]
-
-    @pl.when(fi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        for cp in row_copies(0, 0):
-            cp.start()
-
-    slot = jax.lax.rem(fi, 2)
-
-    @pl.when(fi + 1 < nf)
-    def _prefetch_next():
-        for cp in row_copies(fi + 1, 1 - slot):
-            cp.start()
-
-    for cp in row_copies(fi, slot):
-        cp.wait()
-
-    acc_ref[...] += jax.lax.dot_general(
-        k_buf[slot].astype(jnp.float32),
-        q_ref[0].astype(jnp.float32),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(fi == nf - 1)
-    def _epilogue():
-        o_ref[...] = (acc_ref[...] * mask_ref[...].astype(jnp.float32)
-                      ).astype(o_ref.dtype)[None]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("v", "k_blk", "f_blk", "h", "q_batched", "k_batched",
-                     "interpret"),
-)
-def _batched_sddmm_call(block_win, cols, q3, k3, mask, *, v, k_blk, f_blk,
-                        h, q_batched, k_batched, interpret):
-    nb = block_win.shape[0]
-    f_pad = q3.shape[-1]
-    nf = f_pad // f_blk
-    grid = (h, nb, nf)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, v, f_blk),
-                lambda hh, b, fi, bw, c: ((hh if q_batched else 0), bw[b], fi)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # K stays in HBM
-            pl.BlockSpec((k_blk, v), lambda hh, b, fi, bw, c: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, k_blk, v),
-                               lambda hh, b, fi, bw, c: (hh, b, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((k_blk, v), jnp.float32),           # fp32 accumulator
-            pltpu.VMEM((2, k_blk, f_blk), k3.dtype),       # K-rows buffer
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    out_shape = jax.ShapeDtypeStruct((h, nb * k_blk, v), q3.dtype)
-    kernel = functools.partial(
-        _batched_sddmm_kernel, k_blk=k_blk, f_blk=f_blk, nf=nf,
-        k_batched=k_batched)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(block_win, cols, q3, k3, mask)
+    return _sddmm(blocked, q, k, f_blk=f_blk, interpret=interpret,
+                  precision=precision)
 
 
 def sddmm_pallas_batched(blocked, q: jax.Array, k: jax.Array, *,
                          f_blk: int = 128,
                          interpret: bool = True,
                          precision: str | None = None) -> jax.Array:
-    """Batched gather-free SDDMM: one ``(H, NB, F/F_BLK)`` grid for H heads.
+    """Batched gather-free SDDMM: one ``(H, chunks, F/F_BLK)`` grid for H
+    heads.
 
     ``q``/``k`` may be ``(M, F)``/``(Mc, F)`` shared or carry a leading
-    per-head dim.  At least one operand batched returns ``(H, NNZP, V)``;
-    neither batched falls through to the single-head :func:`sddmm_pallas`.
+    per-head dim.  At least one operand batched returns ``(H, NNZP, V)``.
     Bitwise-equal to stacking H per-slice launches.
     """
-    qb, kb = q.ndim == 3, k.ndim == 3
-    if not (qb or kb):
-        return sddmm_pallas(blocked, q, k, f_blk=f_blk, interpret=interpret,
-                            precision=precision)
-    q, k = _cast_precision(precision, q, k)
-    h = q.shape[0] if qb else k.shape[0]
-    v = blocked.vector_size
-    w = blocked.num_windows
-    f = q.shape[-1]
-    f_blk = min(f_blk, max(f, 1))
-    f_pad = -(-f // f_blk) * f_blk
-
-    q3 = q if qb else q[None]
-    k3 = k if kb else k[None]
-    qpad = jnp.zeros((q3.shape[0], w * v, f_pad), q.dtype
-                     ).at[:, : q3.shape[1], :f].set(q3)
-    if f_pad != f:
-        k3 = jnp.pad(k3, ((0, 0), (0, 0), (0, f_pad - f)))
-
-    return _batched_sddmm_call(
-        blocked.block_win, blocked.cols, qpad, k3, blocked.mask,
-        v=v, k_blk=blocked.k_blk, f_blk=f_blk, h=h,
-        q_batched=qb, k_batched=kb, interpret=interpret,
-    )
-
-
-# ---------------------------------------------------------------------------
-# Block-parallel scheduled variant (DESIGN.md §11).  SDDMM's natural grid is
-# *already* block-parallel — every K-block is one uniform unit of work
-# (K_BLK sampled rows × the feature tiles), so there is no ragged inner
-# loop to split.  What the schedule adds is the block indirection: the grid
-# runs over the Schedule's ``blk_id`` list — scheduled blocks only, in
-# schedule order — so the degenerate all-empty matrix (zero scheduled
-# blocks) returns zeros without launching or relying on the dummy block,
-# and any future block reordering the scheduler emits is honored.  Grid
-# ``(H, NSB, F/F_BLK)``; per-cell arithmetic identical to the batched
-# kernel, hence bitwise-equal outputs.
-# ---------------------------------------------------------------------------
-
-
-def _balanced_sddmm_kernel(blk_id_ref, blk_win_ref, cols_ref, q_ref, k_hbm,
-                           mask_ref, o_ref, acc_ref, k_buf, sems, *,
-                           k_blk: int, f_blk: int, nf: int, k_batched: bool):
-    h = pl.program_id(0)
-    s = pl.program_id(1)
-    fi = pl.program_id(2)
-    kh = h if k_batched else 0      # static: shared K reads slice 0
-    base = blk_id_ref[s] * k_blk
-
-    def row_copies(tile_fi, slot):
-        return [
-            pltpu.make_async_copy(
-                k_hbm.at[kh, pl.ds(cols_ref[base + r], 1),
-                         pl.ds(tile_fi * f_blk, f_blk)],
-                k_buf.at[slot, pl.ds(r, 1)],
-                sems.at[slot],
-            )
-            for r in range(k_blk)
-        ]
-
-    @pl.when(fi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        for cp in row_copies(0, 0):
-            cp.start()
-
-    slot = jax.lax.rem(fi, 2)
-
-    @pl.when(fi + 1 < nf)
-    def _prefetch_next():
-        for cp in row_copies(fi + 1, 1 - slot):
-            cp.start()
-
-    for cp in row_copies(fi, slot):
-        cp.wait()
-
-    acc_ref[...] += jax.lax.dot_general(
-        k_buf[slot].astype(jnp.float32),
-        q_ref[0].astype(jnp.float32),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(fi == nf - 1)
-    def _epilogue():
-        o_ref[...] = (acc_ref[...] * mask_ref[...].astype(jnp.float32)
-                      ).astype(o_ref.dtype)[None]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("v", "k_blk", "f_blk", "h", "q_batched", "k_batched",
-                     "nb", "interpret"),
-)
-def _balanced_sddmm_call(blk_id, blk_win, cols, q3, k3, mask, *, v, k_blk,
-                         f_blk, h, q_batched, k_batched, nb, interpret):
-    nsb = blk_id.shape[0]
-    f_pad = q3.shape[-1]
-    nf = f_pad // f_blk
-    grid = (h, nsb, nf)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, v, f_blk),
-                lambda hh, s, fi, bid, bw, c: (
-                    (hh if q_batched else 0), bw[s], fi)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # K stays in HBM
-            pl.BlockSpec((k_blk, v),
-                         lambda hh, s, fi, bid, bw, c: (bid[s], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, k_blk, v),
-                               lambda hh, s, fi, bid, bw, c: (hh, bid[s], 0)),
-        scratch_shapes=[
-            pltpu.VMEM((k_blk, v), jnp.float32),           # fp32 accumulator
-            pltpu.VMEM((2, k_blk, f_blk), k3.dtype),       # K-rows buffer
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    out_shape = jax.ShapeDtypeStruct((h, nb * k_blk, v), q3.dtype)
-    kernel = functools.partial(
-        _balanced_sddmm_kernel, k_blk=k_blk, f_blk=f_blk, nf=nf,
-        k_batched=k_batched)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(blk_id, blk_win, cols, q3, k3, mask)
+    return sddmm_pallas(blocked, q, k, f_blk=f_blk, interpret=interpret,
+                        precision=precision)
 
 
 def sddmm_pallas_balanced(blocked, q: jax.Array, k: jax.Array, *,
@@ -410,43 +275,24 @@ def sddmm_pallas_balanced(blocked, q: jax.Array, k: jax.Array, *,
                           precision: str | None = None) -> jax.Array:
     """Schedule-driven SDDMM over a :class:`BlockedMEBCRS` pattern.
 
-    ``schedule`` is the precomputed :class:`~repro.core.format.Schedule`
-    (built from ``blocked`` with ``split_blk`` when omitted — host-side).
-    Runs the grid over the schedule's block list: an all-empty matrix has
-    zero scheduled blocks and returns zeros without a kernel launch.
-    Batching follows :func:`sddmm_pallas_batched` (unbatched in →
-    unbatched out); outputs are bitwise-equal to the window-parallel
-    kernels.
+    SDDMM is block-parallel by nature: the chunk grid gives every step the
+    same 128 sampled rows whatever the window skew, so the schedule only
+    decides the degenerate case — an all-empty matrix has zero scheduled
+    blocks and returns zeros without a kernel launch.  ``schedule`` is
+    built from ``blocked`` with ``split_blk`` when omitted (host-side).
+    Batching follows :func:`sddmm_pallas_batched`; outputs are
+    bitwise-equal to the window-parallel path.
     """
     if schedule is None:
         schedule = blocked.schedule(split_blk)
-    q, k = _cast_precision(precision, q, k)
-    qb, kb = q.ndim == 3, k.ndim == 3
-    h = q.shape[0] if qb else (k.shape[0] if kb else 1)
-    v = blocked.vector_size
-    w = blocked.num_windows
-    nb = blocked.num_blocks
     if schedule.num_blocks == 0:
-        shape = (h, nb * blocked.k_blk, v)
-        out = jnp.zeros(shape, q.dtype)
+        q, k = _cast_precision(precision, q, k)
+        qb, kb = q.ndim == 3, k.ndim == 3
+        h = q.shape[0] if qb else (k.shape[0] if kb else 1)
+        out = jnp.zeros((h,) + blocked.mask.shape, q.dtype)
         return out if (qb or kb) else out[0]
-    f = q.shape[-1]
-    f_blk = min(f_blk, max(f, 1))
-    f_pad = -(-f // f_blk) * f_blk
-
-    q3 = q if qb else q[None]
-    k3 = k if kb else k[None]
-    qpad = jnp.zeros((q3.shape[0], w * v, f_pad), q.dtype
-                     ).at[:, : q3.shape[1], :f].set(q3)
-    if f_pad != f:
-        k3 = jnp.pad(k3, ((0, 0), (0, 0), (0, f_pad - f)))
-
-    out = _balanced_sddmm_call(
-        schedule.blk_id, schedule.blk_win, blocked.cols, qpad, k3,
-        blocked.mask, v=v, k_blk=blocked.k_blk, f_blk=f_blk, h=h,
-        q_batched=qb, k_batched=kb, nb=nb, interpret=interpret,
-    )
-    return out if (qb or kb) else out[0]
+    return sddmm_pallas(blocked, q, k, f_blk=f_blk, interpret=interpret,
+                        precision=precision)
 
 
 def sddmm_hbm_bytes(blocked, f: int, *, f_blk: int = 128,

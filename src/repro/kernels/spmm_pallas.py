@@ -3,14 +3,16 @@
 This is the TPU realization of FlashSparse's swap-and-transpose SpMM
 (paper §3.3), adapted per DESIGN.md §2–§3:
 
-  * The sparse operand arrives **vector-major** (``vals (K_BLK, V)`` = Aᵀ),
-    so the window size V = 8 sits on the minor dimension of the MXU
-    contraction — the granularity the paper obtains by swapping MMA
-    operands falls out of the storage layout here.
+  * The sparse operand is stored **vector-major** (``vals (NNZP, V)`` =
+    Aᵀ) and handed to the kernel with the vector index on lanes
+    (:mod:`.layout`), so a K-block is a ``(V=8, K_BLK)`` tile and the
+    window size V = 8 is the output tile's row count — the granularity
+    the paper obtains by swapping MMA operands falls out of the layout.
   * **Gather-free** (DESIGN.md §3): the dense operand B stays in HBM
     (``memory_space=ANY``) and the kernel DMAs exactly the K_BLK dense rows
     each K-block needs into a double-buffered VMEM scratch
-    (``pltpu.make_async_copy`` driven by the scalar-prefetched ``cols``).
+    (``pltpu.make_async_copy`` at the column ids read through an SMEM
+    cache of ``cols``).
     Every dense row slice is a full-lane contiguous HBM→VMEM copy — the TPU
     analogue of the paper's coalesced thread mapping (§3.3, Fig. 7) — and B
     is read **once** per output column tile, with no ``(NB·K_BLK, N)``
@@ -18,7 +20,7 @@ This is the TPU realization of FlashSparse's swap-and-transpose SpMM
     :func:`spmm_pallas_staged` (baseline for the Fig. 12-style traffic
     model, :func:`spmm_hbm_bytes`).
   * The grid runs over **output windows** with an inner loop over that
-    window's K-blocks (the scalar-prefetched ``win_ptr`` ranges), so every
+    window's K-blocks (the ``win_ptr`` ranges, as a step table), so every
     output tile is initialized exactly once, empty windows are written zero
     in-kernel, and the fp32 accumulator is cast to the output dtype in the
     epilogue — no ``_zero_unvisited`` / ``astype`` post-passes.
@@ -38,12 +40,18 @@ schedule segments — same DMAs, same ascending-block fp32 accumulation
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .layout import (CACHE_ROWS, LANES, SmemRows, block_tile, chunks_per_block,
+                     dot, lane_tile, pad_cols, schedule_steps, smem_cache,
+                     steps_table, sublanes, table, vectors_on_lanes,
+                     window_steps)
 
 __all__ = [
     "spmm_pallas",
@@ -76,7 +84,7 @@ def _apply_precision(blocked, b_dense, precision):
         format is already quantized; the dense operand rides at bf16.
 
     ``scales`` is always a concrete ``(NB,)`` fp32 array (ones when not
-    quantized) so every kernel shares one scalar-prefetch signature; the
+    quantized) so every kernel shares one operand signature; the
     static ``quantized`` flag gates the per-block multiply, keeping the
     unquantized path's arithmetic untouched (bitwise-identical).
     """
@@ -103,72 +111,107 @@ def _apply_precision(blocked, b_dense, precision):
 
 
 # ---------------------------------------------------------------------------
-# Fused gather-free kernel (default path)
+# The SpMM kernel.  Grid (H, N / N_BLK, S) over *steps*: step ``s`` owns the
+# contiguous K-block range ``[lo, lo + len)`` of output window ``win`` and
+# is the ``first`` and/or ``last`` step of that window.  The window-parallel
+# grid has one step per window (:func:`layout.window_steps`); the
+# load-balanced grid runs a Schedule's segments (DESIGN.md §11), so a hub
+# window's work is spread over many steps instead of serializing one.
+# Steps of one window are adjacent and ascend over its blocks, so the fp32
+# accumulator (VMEM scratch, persistent across the sequential grid) sees
+# the same additions in the same order on either grid: the window-parallel,
+# batched and balanced paths are bitwise-equal by construction.
+#
+# Per K-block the step DMAs the block's (V, 128·c) value chunk and its
+# K_BLK dense rows of B at the column ids read through the SMEM cache —
+# double-buffered, block ``blk + 1`` in flight while ``blk`` contracts on
+# the MXU.  Empty windows are zero-length steps: no DMA, just the zero
+# store.  Heads share the metadata; a shared operand is a (1, ...) slice
+# every head reads, never an H-fold broadcast in HBM.
 # ---------------------------------------------------------------------------
 
 
-def _fused_spmm_kernel(win_ptr_ref, cols_ref, scales_ref, vals_hbm, b_hbm,
-                       o_ref, acc_ref, vals_buf, b_buf, sems, *,
-                       k_blk: int, n_blk: int, double_buffer: bool,
-                       quantized: bool):
-    j = pl.program_id(0)
-    w = pl.program_id(1)
-    lo = win_ptr_ref[w]
-    hi = win_ptr_ref[w + 1]
+def _spmm_kernel(steps_hbm, cols_hbm, scales_hbm, vals_hbm, b_hbm, o_hbm,
+                 acc_ref, vals_buf, b_buf, steps_buf, steps_base, cols_buf,
+                 cols_base, sc_buf, sc_base, sems, *, k_blk: int, n_blk: int,
+                 v: int, k_rows: int, num_windows: int, vals_batched: bool,
+                 b_batched: bool,
+                 quantized: bool, double_buffer: bool):
+    h = pl.program_id(0)
+    j = pl.program_id(1)
+    s = pl.program_id(2)
+    # Operands are 2-D with heads (and B's column tiles) stacked on rows,
+    # so every dense-row DMA copies a whole row; a shared operand is one
+    # slice every head reads (static choice).
+    vrow = h * v if vals_batched else 0
+    brow = ((h if b_batched else 0) * pl.num_programs(1) + j) * k_rows
+    steps = SmemRows(steps_hbm, steps_buf, steps_base, sems.at[0, 2])
+    cols = SmemRows(cols_hbm, cols_buf, cols_base, sems.at[1, 2])
+    scales = SmemRows(scales_hbm, sc_buf, sc_base, sems.at[0, 3])
 
-    def block_copies(blk, slot):
-        """DMA descriptors for K-block ``blk`` into scratch slot ``slot``:
-        one (K_BLK, V) vals tile plus K_BLK single dense-row slices of B at
-        the scalar-prefetched column ids (contiguous full-lane copies)."""
+    @pl.when((h == 0) & (j == 0) & (s == 0))
+    def _reset():
+        for cache in (steps, cols, scales):
+            cache.reset()
+
+    steps.ensure(8 * s, 8 * s + 4)
+    lo = steps[8 * s]
+    hi = lo + steps[8 * s + 1]
+    first = steps[8 * s + 2]
+    last = steps[8 * s + 3]
+    win = steps[8 * s + 4]
+    width = vals_buf.shape[-1]
+
+    def copies(blk, slot, for_wait=False):
+        """DMA descriptors of K-block ``blk`` into slot ``slot``: the value
+        chunk plus K_BLK single dense rows of B.  Waits need only the
+        destination and semaphore, so their sources are left at 0."""
         base = blk * k_blk
-        vals_cp = pltpu.make_async_copy(
-            vals_hbm.at[pl.ds(base, k_blk), :],
-            vals_buf.at[slot],
-            sems.at[slot, 0],
-        )
-        row_cps = [
-            pltpu.make_async_copy(
-                b_hbm.at[pl.ds(cols_ref[base + r], 1),
-                         pl.ds(j * n_blk, n_blk)],
-                b_buf.at[slot, pl.ds(r, 1)],
-                sems.at[slot, 1],
-            )
-            for r in range(k_blk)
-        ]
-        return [vals_cp] + row_cps
+        chunk = 0 if for_wait else (base // LANES) * LANES
+        cps = [pltpu.make_async_copy(
+            vals_hbm.at[pl.ds(vrow, v), pl.ds(chunk, width)],
+            vals_buf.at[slot], sems.at[slot, 0])]
+        for r in range(k_blk):
+            row = 0 if for_wait else brow + cols[base + r]
+            cps.append(pltpu.make_async_copy(
+                b_hbm.at[pl.ds(row, 1), :],
+                b_buf.at[slot, pl.ds(r, 1)], sems.at[slot, 1]))
+        return cps
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    def start(blk, slot):
+        cols.ensure(blk * k_blk, blk * k_blk + k_blk - 1)
+        for cp in copies(blk, slot):
+            cp.start()
 
     def accumulate(blk, slot):
-        # contraction over the K_BLK vector index: (V, N_BLK) += valsᵀ @ brows
-        contrib = jax.lax.dot_general(
-            vals_buf[slot].astype(jnp.float32),
-            b_buf[slot].astype(jnp.float32),
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        # (V, N_BLK) += vals tile (V, K_BLK) @ dense rows (K_BLK, N_BLK)
+        contrib = dot(block_tile(vals_buf[slot], blk * k_blk, k_blk),
+                      b_buf[slot, pl.ds(0, k_blk), :])
         if quantized:
             # In-VMEM dequantization: the per-block scale commutes with the
-            # contraction, so one fp32 multiply restores the magnitude of a
-            # whole int8 K-block tile (DESIGN.md §13).
-            contrib = contrib * scales_ref[blk]
+            # contraction, so one fp32 multiply restores a whole int8
+            # K-block tile (DESIGN.md §13).
+            scales.ensure(blk, blk)
+            contrib = contrib * scales[blk]
         acc_ref[...] += contrib
+
+    @pl.when(first == 1)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     if double_buffer:
         @pl.when(lo < hi)
         def _warmup():
-            for cp in block_copies(lo, 0):
-                cp.start()
+            start(lo, 0)
 
         def body(blk, carry):
             slot = jax.lax.rem(blk - lo, 2)
 
             @pl.when(blk + 1 < hi)
             def _prefetch_next():
-                for cp in block_copies(blk + 1, 1 - slot):
-                    cp.start()
+                start(blk + 1, 1 - slot)
 
-            for cp in block_copies(blk, slot):
+            for cp in copies(blk, slot, for_wait=True):
                 cp.wait()
             accumulate(blk, slot)
             return carry
@@ -178,94 +221,111 @@ def _fused_spmm_kernel(win_ptr_ref, cols_ref, scales_ref, vals_hbm, b_hbm,
         # DMA and compute — the structural analogue of the strided-access
         # penalty the paper's direct thread mapping suffers (Fig. 15).
         def body(blk, carry):
-            for cp in block_copies(blk, 0):
+            cols.ensure(blk * k_blk, blk * k_blk + k_blk - 1)
+            for cp in copies(blk, 0):
                 cp.start()
                 cp.wait()
             accumulate(blk, 0)
             return carry
 
     jax.lax.fori_loop(lo, hi, body, 0)
-    # Fused epilogue: exactly-once init above means empty windows (lo == hi)
-    # fall through to a zero store; cast to the output dtype in-kernel.
-    o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+    @pl.when(last == 1)
+    def _store():
+        cp = pltpu.make_async_copy(
+            acc_ref,
+            o_hbm.at[pl.ds((h * num_windows + win) * v, v),
+                     pl.ds(j * n_blk, n_blk)],
+            sems.at[1, 3])
+        cp.start()
+        cp.wait()
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_windows", "v", "k_blk", "n_blk", "interpret",
-                     "double_buffer", "quantized"),
+    static_argnames=("num_windows", "k_blk", "n_blk", "interpret",
+                     "quantized", "double_buffer"),
 )
-def _fused_spmm_call(win_ptr, cols, scales, vals, b_dense, *, num_windows, v,
-                     k_blk, n_blk, interpret, double_buffer,
-                     quantized=False):
-    n_pad = b_dense.shape[1]
-    grid = (n_pad // n_blk, num_windows)
+def _spmm_call(steps, cols, scales, vals3, b3, *, num_windows, k_blk, n_blk,
+               interpret, quantized=False, double_buffer=True):
+    """Launch :func:`_spmm_kernel`.
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # vals stay in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # B stays in HBM
-        ],
-        out_specs=pl.BlockSpec((v, n_blk), lambda j, w, wp, c, sc: (w, j)),
-        scratch_shapes=[
-            pltpu.VMEM((v, n_blk), jnp.float32),          # fp32 accumulator
-            pltpu.VMEM((2, k_blk, v), vals.dtype),        # vals double-buffer
-            pltpu.VMEM((2, k_blk, n_blk), b_dense.dtype),  # B-rows buffer
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
+    ``steps`` ``(S, 5)`` int32 ``[lo, len, first, last, win]``
+    (:func:`layout.window_steps` / :func:`layout.schedule_steps`);
+    ``cols`` ``(NNZP,)``; ``scales`` ``(NB,)`` (read only when
+    ``quantized``); ``vals3`` ``(1 | H, NNZP, V)``; ``b3``
+    ``(1 | H, K, N)``.  Returns fp32 ``(H, num_windows·V, N_pad)``; rows of
+    windows no step stores are left unwritten.
+    """
+    hv, _, v = vals3.shape
+    hb, k_rows, _ = b3.shape
+    h = max(hv, hb)
+    n_blk = lane_tile(n_blk, b3.shape[-1])
+    b3 = pad_cols(b3.astype(jnp.float32), n_blk)
+    n_pad = b3.shape[-1]
+    b_tiles = b3.reshape(hb, k_rows, n_pad // n_blk, n_blk).swapaxes(1, 2)
+    vals_t = vectors_on_lanes(vals3, k_blk)
+    width = chunks_per_block(k_blk) * LANES
+    ns = steps.shape[0]
+    cols_rows = max(CACHE_ROWS, width // LANES + 1)
     kernel = functools.partial(
-        _fused_spmm_kernel, k_blk=k_blk, n_blk=n_blk,
-        double_buffer=double_buffer, quantized=quantized,
-    )
-    out_shape = jax.ShapeDtypeStruct((num_windows * v, n_pad), b_dense.dtype)
+        _spmm_kernel, k_blk=k_blk, n_blk=n_blk, v=v, k_rows=k_rows,
+        num_windows=num_windows, vals_batched=hv > 1,
+        b_batched=hb > 1, quantized=quantized, double_buffer=double_buffer)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
+        grid=(h, n_pad // n_blk, ns),
+        in_specs=[any_spec] * 5,
+        out_specs=any_spec,
+        out_shape=jax.ShapeDtypeStruct((h * num_windows * v, n_pad),
+                                       jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((v, n_blk), jnp.float32),           # fp32 accumulator
+            pltpu.VMEM((2, v, width), jnp.float32),        # value chunks
+            pltpu.VMEM((2, sublanes(k_blk), n_blk), jnp.float32),  # B rows
+            *smem_cache(),                                 # step table
+            *smem_cache(rows=cols_rows),                    # cols
+            *smem_cache(jnp.float32),                      # block scales
+            pltpu.SemaphoreType.DMA((2, 4)),
+        ],
         interpret=interpret,
-    )(win_ptr, cols, scales, vals, b_dense)
+    )(steps_table(steps), table(cols, cols_rows), table(scales),
+      vals_t.reshape(hv * v, -1), b_tiles.reshape(-1, n_blk)
+      ).reshape(h, num_windows * v, -1)
 
 
-def _pad_cols(b_dense: jax.Array, n_blk: int):
-    n = b_dense.shape[1]
-    n_blk = min(n_blk, max(n, 1))
-    n_pad = -(-n // n_blk) * n_blk
-    if n_pad != n:
-        b_dense = jnp.pad(b_dense, ((0, 0), (0, n_pad - n)))
-    return b_dense, n_blk
-
-
-def _spmm_fused(blocked, b_dense: jax.Array, n_blk: int, interpret: bool,
-                double_buffer: bool, precision=None) -> jax.Array:
-    m, _ = blocked.shape
-    n = b_dense.shape[1]
+def _spmm(blocked, b_dense, steps, *, n_blk, interpret, precision,
+          num_windows=None, double_buffer=True):
+    """Precision policy + launch + unpad: ``(M, N)`` or ``(H, M, N)`` in
+    the (narrowed) dense operand's dtype."""
     vals, scales, quantized, b_dense = _apply_precision(
         blocked, b_dense, precision)
-    b_padded, n_blk = _pad_cols(b_dense, n_blk)
-    out = _fused_spmm_call(
-        blocked.win_ptr, blocked.cols, scales, vals, b_padded,
-        num_windows=blocked.num_windows, v=blocked.vector_size,
-        k_blk=blocked.k_blk, n_blk=n_blk, interpret=interpret,
-        double_buffer=double_buffer, quantized=quantized,
-    )
-    return out[:m, :n]
+    vb, bb = vals.ndim == 3, b_dense.ndim == 3
+    out = _spmm_call(
+        steps, blocked.cols, scales, vals if vb else vals[None],
+        b_dense if bb else b_dense[None],
+        num_windows=num_windows or blocked.num_windows, k_blk=blocked.k_blk,
+        n_blk=n_blk, interpret=interpret, quantized=quantized,
+        double_buffer=double_buffer)
+    out = out[:, :blocked.shape[0], :b_dense.shape[-1]].astype(b_dense.dtype)
+    return out if (vb or bb) else out[0]
 
 
 def spmm_pallas(blocked, b_dense: jax.Array, *, n_blk: int = 128,
                 interpret: bool = True, precision: str | None = None
                 ) -> jax.Array:
-    """Gather-free SpMM over a :class:`BlockedMEBCRS`. Returns (M, N) in
-    ``b`` dtype.  Dense rows are DMA'd HBM→VMEM inside the kernel
-    (double-buffered); no staging buffer is materialized.  ``precision``
-    selects the mixed-precision path (DESIGN.md §13): ``"bf16"`` narrows
-    the DMA'd operands with fp32 in-kernel accumulation; ``"int8"``
-    additionally quantizes the values per K-block, dequantizing in-VMEM
-    via the scalar-prefetched scales."""
-    return _spmm_fused(blocked, b_dense, n_blk, interpret, double_buffer=True,
-                       precision=precision)
+    """Gather-free SpMM over a :class:`BlockedMEBCRS`, one grid step per
+    output window.  Returns ``(M, N)`` in ``b`` dtype.  Dense rows are
+    DMA'd HBM→VMEM inside the kernel (double-buffered); no staging buffer
+    is materialized.  ``precision`` selects the mixed-precision path
+    (DESIGN.md §13): ``"bf16"`` rounds the operands to bf16 with fp32
+    accumulation; ``"int8"`` additionally quantizes the values per
+    K-block, dequantizing in VMEM with the per-block scales.  Either
+    operand may carry a leading head dim (see
+    :func:`spmm_pallas_batched`)."""
+    return _spmm(blocked, b_dense, window_steps(blocked.win_ptr),
+                 n_blk=n_blk, interpret=interpret, precision=precision)
 
 
 def spmm_pallas_noncoalesced(blocked, b_dense: jax.Array, *, n_blk: int = 128,
@@ -274,123 +334,9 @@ def spmm_pallas_noncoalesced(blocked, b_dense: jax.Array, *, n_blk: int = 128,
     """Ablation variant (paper Fig. 15): serialized per-row DMA with no
     double buffering.  Bitwise-identical results to :func:`spmm_pallas`
     (same accumulation order); only the copy scheduling differs."""
-    return _spmm_fused(blocked, b_dense, n_blk, interpret,
-                       double_buffer=False, precision=precision)
-
-
-# ---------------------------------------------------------------------------
-# Batched (head-major) variant: grid (H, N / N_BLK, W).  One launch covers
-# any number of heads; the scalar-prefetched win_ptr / cols metadata is
-# shared across the whole grid (it describes the pattern, not the values),
-# so H heads cost zero extra metadata traffic.  Either operand may be
-# per-head (leading H dim) or shared (2-D) — shared operands are passed as
-# a single (1, ...) array and every head's grid cells DMA from slice 0, no
-# H-fold broadcast is ever materialized in HBM.  Per-(h, j, w) cell the
-# arithmetic is identical to :func:`_fused_spmm_kernel`, so the batched
-# launch is bitwise-equal to the per-slice loop it replaces.
-# ---------------------------------------------------------------------------
-
-
-def _batched_spmm_kernel(win_ptr_ref, cols_ref, scales_ref, vals_hbm, b_hbm,
-                         o_ref, acc_ref, vals_buf, b_buf, sems, *,
-                         k_blk: int, n_blk: int, vals_batched: bool,
-                         b_batched: bool, quantized: bool):
-    h = pl.program_id(0)
-    j = pl.program_id(1)
-    w = pl.program_id(2)
-    vh = h if vals_batched else 0   # static: shared operands read slice 0
-    bh = h if b_batched else 0
-    lo = win_ptr_ref[w]
-    hi = win_ptr_ref[w + 1]
-
-    def block_copies(blk, slot):
-        base = blk * k_blk
-        vals_cp = pltpu.make_async_copy(
-            vals_hbm.at[vh, pl.ds(base, k_blk), :],
-            vals_buf.at[slot],
-            sems.at[slot, 0],
-        )
-        row_cps = [
-            pltpu.make_async_copy(
-                b_hbm.at[bh, pl.ds(cols_ref[base + r], 1),
-                         pl.ds(j * n_blk, n_blk)],
-                b_buf.at[slot, pl.ds(r, 1)],
-                sems.at[slot, 1],
-            )
-            for r in range(k_blk)
-        ]
-        return [vals_cp] + row_cps
-
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(lo < hi)
-    def _warmup():
-        for cp in block_copies(lo, 0):
-            cp.start()
-
-    def body(blk, carry):
-        slot = jax.lax.rem(blk - lo, 2)
-
-        @pl.when(blk + 1 < hi)
-        def _prefetch_next():
-            for cp in block_copies(blk + 1, 1 - slot):
-                cp.start()
-
-        for cp in block_copies(blk, slot):
-            cp.wait()
-        contrib = jax.lax.dot_general(
-            vals_buf[slot].astype(jnp.float32),
-            b_buf[slot].astype(jnp.float32),
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if quantized:
-            contrib = contrib * scales_ref[blk]
-        acc_ref[...] += contrib
-        return carry
-
-    jax.lax.fori_loop(lo, hi, body, 0)
-    o_ref[...] = acc_ref[...].astype(o_ref.dtype)[None]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_windows", "v", "k_blk", "n_blk", "h",
-                     "vals_batched", "b_batched", "interpret", "quantized"),
-)
-def _batched_spmm_call(win_ptr, cols, scales, vals3, b3, *, num_windows, v,
-                       k_blk, n_blk, h, vals_batched, b_batched, interpret,
-                       quantized=False):
-    n_pad = b3.shape[-1]
-    grid = (h, n_pad // n_blk, num_windows)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # vals stay in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # B stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1, v, n_blk),
-                               lambda hh, j, w, wp, c, sc: (hh, w, j)),
-        scratch_shapes=[
-            pltpu.VMEM((v, n_blk), jnp.float32),           # fp32 accumulator
-            pltpu.VMEM((2, k_blk, v), vals3.dtype),        # vals double-buffer
-            pltpu.VMEM((2, k_blk, n_blk), b3.dtype),       # B-rows buffer
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    kernel = functools.partial(
-        _batched_spmm_kernel, k_blk=k_blk, n_blk=n_blk,
-        vals_batched=vals_batched, b_batched=b_batched, quantized=quantized,
-    )
-    out_shape = jax.ShapeDtypeStruct((h, num_windows * v, n_pad), b3.dtype)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(win_ptr, cols, scales, vals3, b3)
+    return _spmm(blocked, b_dense, window_steps(blocked.win_ptr),
+                 n_blk=n_blk, interpret=interpret, precision=precision,
+                 double_buffer=False)
 
 
 def spmm_pallas_batched(blocked, b_dense: jax.Array, *, n_blk: int = 128,
@@ -401,165 +347,13 @@ def spmm_pallas_batched(blocked, b_dense: jax.Array, *, n_blk: int = 128,
     ``blocked.vals`` may be ``(NNZP, V)`` (shared pattern values) or
     ``(H, NNZP, V)`` (per-head, e.g. attention probabilities);
     ``b_dense`` may be ``(K, N)`` or ``(H, K, N)``.  At least one operand
-    batched returns ``(H, M, N)``; neither batched falls through to the
-    single-head :func:`spmm_pallas`.  Results are bitwise-equal to stacking
-    H per-slice launches (identical per-cell accumulation order).
-    ``precision`` follows :func:`spmm_pallas`; ``"int8"`` requires shared
-    (2-D) pattern values.
+    batched returns ``(H, M, N)``.  The step metadata is shared by every
+    head, and results are bitwise-equal to stacking H per-slice launches
+    (identical per-cell accumulation order).  ``precision`` follows
+    :func:`spmm_pallas`; ``"int8"`` requires shared (2-D) pattern values.
     """
-    vb, bb = blocked.vals.ndim == 3, b_dense.ndim == 3
-    if not (vb or bb):
-        return spmm_pallas(blocked, b_dense, n_blk=n_blk, interpret=interpret,
-                           precision=precision)
-    vals, scales, quantized, b_dense = _apply_precision(
-        blocked, b_dense, precision)
-    h = vals.shape[0] if vb else b_dense.shape[0]
-    m, _ = blocked.shape
-    n = b_dense.shape[-1]
-    n_blk = min(n_blk, max(n, 1))
-    n_pad = -(-n // n_blk) * n_blk
-    b3 = b_dense if bb else b_dense[None]
-    if n_pad != n:
-        b3 = jnp.pad(b3, ((0, 0), (0, 0), (0, n_pad - n)))
-    vals3 = vals if vb else vals[None]
-    out = _batched_spmm_call(
-        blocked.win_ptr, blocked.cols, scales, vals3, b3,
-        num_windows=blocked.num_windows, v=blocked.vector_size,
-        k_blk=blocked.k_blk, n_blk=n_blk, h=h,
-        vals_batched=vb, b_batched=bb, interpret=interpret,
-        quantized=quantized,
-    )
-    return out[:, :m, :n]
-
-
-# ---------------------------------------------------------------------------
-# Block-parallel load-balanced kernel (DESIGN.md §11).  The grid runs over
-# uniform schedule segments — grid (H, N/N_BLK, NS) with the segment index
-# innermost — instead of ragged per-window loops: every cell contracts at
-# most ``split_blk`` K-blocks, so a hub window's work is spread over many
-# cells instead of serializing one.  Segments of one window are contiguous
-# in grid order (Schedule invariant), so consecutive cells revisit the same
-# resident output block: the fp32 accumulator scratch persists across the
-# sequential grid, is zeroed on ``seg_first``, accumulates blocks in the
-# same ascending order as the window-parallel kernel (bitwise-equal fp32),
-# and the epilogue casts + stores on ``seg_last``.  Empty windows are
-# zero-length segments — no DMA, no MXU work, just the predicated zero
-# store — so the all-empty matrix needs no dummy block and no post-pass.
-# Operands follow the batched convention: one (H, ...) launch for any head
-# count, shared operands passed as a (1, ...) slice.
-# ---------------------------------------------------------------------------
-
-
-def _balanced_spmm_kernel(seg_win_ref, seg_meta_ref, cols_ref, scales_ref,
-                          vals_hbm, b_hbm, o_ref, acc_ref, vals_buf, b_buf,
-                          sems, *, k_blk: int, n_blk: int,
-                          vals_batched: bool, b_batched: bool,
-                          quantized: bool):
-    h = pl.program_id(0)
-    j = pl.program_id(1)
-    s = pl.program_id(2)
-    vh = h if vals_batched else 0   # static: shared operands read slice 0
-    bh = h if b_batched else 0
-    lo = seg_meta_ref[s, 0]
-    hi = lo + seg_meta_ref[s, 1]
-    seg_first = seg_meta_ref[s, 2]
-    seg_last = seg_meta_ref[s, 3]
-
-    def block_copies(blk, slot):
-        base = blk * k_blk
-        vals_cp = pltpu.make_async_copy(
-            vals_hbm.at[vh, pl.ds(base, k_blk), :],
-            vals_buf.at[slot],
-            sems.at[slot, 0],
-        )
-        row_cps = [
-            pltpu.make_async_copy(
-                b_hbm.at[bh, pl.ds(cols_ref[base + r], 1),
-                         pl.ds(j * n_blk, n_blk)],
-                b_buf.at[slot, pl.ds(r, 1)],
-                sems.at[slot, 1],
-            )
-            for r in range(k_blk)
-        ]
-        return [vals_cp] + row_cps
-
-    @pl.when(seg_first == 1)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(lo < hi)
-    def _warmup():
-        for cp in block_copies(lo, 0):
-            cp.start()
-
-    def body(blk, carry):
-        slot = jax.lax.rem(blk - lo, 2)
-
-        @pl.when(blk + 1 < hi)
-        def _prefetch_next():
-            for cp in block_copies(blk + 1, 1 - slot):
-                cp.start()
-
-        for cp in block_copies(blk, slot):
-            cp.wait()
-        contrib = jax.lax.dot_general(
-            vals_buf[slot].astype(jnp.float32),
-            b_buf[slot].astype(jnp.float32),
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if quantized:
-            contrib = contrib * scales_ref[blk]
-        acc_ref[...] += contrib
-        return carry
-
-    jax.lax.fori_loop(lo, hi, body, 0)
-
-    @pl.when(seg_last == 1)
-    def _epilogue():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)[None]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_windows", "v", "k_blk", "n_blk", "h",
-                     "vals_batched", "b_batched", "interpret", "quantized"),
-)
-def _balanced_spmm_call(seg_win, seg_meta, cols, scales, vals3, b3, *,
-                        num_windows, v, k_blk, n_blk, h, vals_batched,
-                        b_batched, interpret, quantized=False):
-    n_pad = b3.shape[-1]
-    ns = seg_win.shape[0]
-    grid = (h, n_pad // n_blk, ns)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # vals stay in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # B stays in HBM
-        ],
-        out_specs=pl.BlockSpec(
-            (1, v, n_blk),
-            lambda hh, j, s, sw, sm, c, sc: (hh, sw[s], j)),
-        scratch_shapes=[
-            pltpu.VMEM((v, n_blk), jnp.float32),           # fp32 accumulator
-            pltpu.VMEM((2, k_blk, v), vals3.dtype),        # vals double-buffer
-            pltpu.VMEM((2, k_blk, n_blk), b3.dtype),       # B-rows buffer
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    kernel = functools.partial(
-        _balanced_spmm_kernel, k_blk=k_blk, n_blk=n_blk,
-        vals_batched=vals_batched, b_batched=b_batched, quantized=quantized,
-    )
-    out_shape = jax.ShapeDtypeStruct((h, num_windows * v, n_pad), b3.dtype)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(seg_win, seg_meta, cols, scales, vals3, b3)
+    return spmm_pallas(blocked, b_dense, n_blk=n_blk, interpret=interpret,
+                       precision=precision)
 
 
 def spmm_pallas_balanced(blocked, b_dense: jax.Array, *, schedule=None,
@@ -580,123 +374,39 @@ def spmm_pallas_balanced(blocked, b_dense: jax.Array, *, schedule=None,
     """
     if schedule is None:
         schedule = blocked.schedule(split_blk)
-    vals, scales, quantized, b_dense = _apply_precision(
-        blocked, b_dense, precision)
-    vb, bb = vals.ndim == 3, b_dense.ndim == 3
-    h = vals.shape[0] if vb else (b_dense.shape[0] if bb else 1)
-    m, _ = blocked.shape
-    n = b_dense.shape[-1]
-    n_blk = min(n_blk, max(n, 1))
-    n_pad = -(-n // n_blk) * n_blk
-    b3 = b_dense if bb else b_dense[None]
-    if n_pad != n:
-        b3 = jnp.pad(b3, ((0, 0), (0, 0), (0, n_pad - n)))
-    vals3 = vals if vb else vals[None]
-    out = _balanced_spmm_call(
-        schedule.seg_win, schedule.seg_meta, blocked.cols, scales, vals3, b3,
-        num_windows=blocked.num_windows, v=blocked.vector_size,
-        k_blk=blocked.k_blk, n_blk=n_blk, h=h,
-        vals_batched=vb, b_batched=bb, interpret=interpret,
-        quantized=quantized,
-    )
-    out = out[:, :m, :n]
-    return out if (vb or bb) else out[0]
-
-
-# ---------------------------------------------------------------------------
-# Staged-gather baseline (the pre-fusion pipeline, kept for the traffic
-# model and ablation benchmarks): bgath = B[cols] materialized in HBM, then
-# re-read through BlockSpecs; unvisited windows zeroed in a post-pass.
-# ---------------------------------------------------------------------------
-
-
-def _staged_spmm_kernel(block_win_ref, vals_ref, bg_ref, o_ref):
-    b = pl.program_id(1)
-    w = block_win_ref[b]
-    prev_w = block_win_ref[jnp.maximum(b - 1, 0)]
-    is_first = jnp.logical_or(b == 0, prev_w != w)
-
-    @pl.when(is_first)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    partial = jax.lax.dot_general(
-        vals_ref[...],
-        bg_ref[...],
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    o_ref[...] += partial
-
-
-@functools.partial(
-    jax.jit, static_argnames=("num_windows", "v", "k_blk", "n_blk", "interpret")
-)
-def _staged_spmm_call(block_win, vals, bgath, *, num_windows, v, k_blk, n_blk,
-                      interpret):
-    nb = block_win.shape[0]
-    n = bgath.shape[1]
-    grid = (n // n_blk, nb)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((k_blk, v), lambda j, b, bw: (b, 0)),
-            pl.BlockSpec((k_blk, n_blk), lambda j, b, bw: (b, j)),
-        ],
-        out_specs=pl.BlockSpec((v, n_blk), lambda j, b, bw: (bw[b], j)),
-    )
-    out_shape = jax.ShapeDtypeStruct((num_windows * v, n), jnp.float32)
-    return pl.pallas_call(
-        _staged_spmm_kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(block_win, vals, bgath)
-
-
-def _zero_unvisited(out, block_win, num_windows, v):
-    """Windows with no nonzero vectors are never visited by the staged grid —
-    their output tiles are uninitialized.  Zero them (NaN-safe ``where``)."""
-    visited = jnp.zeros((num_windows,), jnp.bool_).at[block_win].set(True)
-    mask = jnp.repeat(visited, v)[:, None]
-    return jnp.where(mask, out, 0.0)
+    return _spmm(blocked, b_dense,
+                 schedule_steps(schedule.seg_win, schedule.seg_meta),
+                 n_blk=n_blk, interpret=interpret, precision=precision)
 
 
 def spmm_pallas_staged(blocked, b_dense: jax.Array, *, n_blk: int = 128,
                        interpret: bool = True,
                        precision: str | None = None) -> jax.Array:
     """Legacy staged-gather SpMM: materializes ``bgath = B[cols]`` in HBM
-    (an ``avg_vectors_per_row ×`` blow-up of B) before the kernel.  Kept as
-    the baseline the fused path is measured against.  ``precision``
-    supports ``"fp32"``/``"bf16"`` (the staged grid has no scale prefetch,
-    so ``"int8"`` is not offered here)."""
+    (an ``avg_vectors_per_row ×`` blow-up of B) and then runs the window
+    kernel over the staged rows in order.  Kept as the baseline the fused
+    path is measured against; bitwise-equal to it.  ``precision``
+    supports ``"fp32"``/``"bf16"`` (no ``"int8"``: the baseline predates
+    the per-block scales)."""
     from repro.core.quantize import validate_precision
 
     validate_precision(precision)
     if precision == "int8":
         raise ValueError("spmm_pallas_staged has no int8 path (no per-block "
-                         "scale prefetch in the staged grid); use the fused "
+                         "scales in the staged baseline); use the fused "
                          "or balanced impls")
     vals = blocked.vals
     if precision is not None:
         tgt = jnp.float32 if precision == "fp32" else jnp.bfloat16
         b_dense = b_dense.astype(tgt)
         vals = vals.astype(tgt)
-    m, _ = blocked.shape
-    v = blocked.vector_size
-    num_windows = blocked.num_windows
-    n = b_dense.shape[1]
-    b_dense, n_blk = _pad_cols(b_dense, n_blk)
-
+    nnzp = blocked.cols.shape[0]
     bgath = jnp.take(b_dense, blocked.cols, axis=0)  # staged gather in HBM
-    out = _staged_spmm_call(
-        blocked.block_win, vals, bgath, num_windows=num_windows,
-        v=v, k_blk=blocked.k_blk, n_blk=n_blk, interpret=interpret,
-    )
-    out = _zero_unvisited(out, blocked.block_win, num_windows, v)
-    return out[:m, :n].astype(b_dense.dtype)
+    staged = dataclasses.replace(
+        blocked, vals=vals, cols=jnp.arange(nnzp, dtype=jnp.int32),
+        scales=None)
+    return _spmm(staged, bgath, window_steps(blocked.win_ptr), n_blk=n_blk,
+                 interpret=interpret, precision=None)
 
 
 # ---------------------------------------------------------------------------
@@ -716,7 +426,7 @@ def spmm_hbm_bytes(blocked, n: int, *, n_blk: int = 128,
     (4 for fp32, 2 for bf16 — callers derive it from the dtype, see
     :func:`benchmarks.common.dtype_bytes`); ``vals_value_bytes`` is the
     sparse-value element size when it differs (int8 values: 1, plus the
-    4-byte per-K-block scale the quantized kernels scalar-prefetch).
+    4-byte per-K-block scale the quantized kernels read).
     Defaults to ``value_bytes``.
 
     ``fused`` / ``noncoalesced``: each needed dense row is DMA'd from B
@@ -725,7 +435,7 @@ def spmm_hbm_bytes(blocked, n: int, *, n_blk: int = 128,
 
     ``balanced``: identical data movement to ``fused`` (same DMAs, same
     single output store per window — the schedule only re-maps work to
-    grid cells) plus the scalar-prefetched segment metadata (``seg_win`` +
+    grid cells) plus the segment metadata (``seg_win`` +
     ``seg_meta``, 20 bytes per segment).  Pass the ``schedule`` (defaults
     to ``blocked.schedule(1)``).  The *latency* difference the schedule
     exists for is modeled separately — see

@@ -17,17 +17,7 @@ from __future__ import annotations
 import math
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed after jax 0.4.x; Auto is the implicit default there
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
-
-
-def _axis_kwargs(n: int) -> dict:
-    """axis_types=Auto where supported; older Mesh lacks the kwarg."""
-    return {} if AxisType is None else {"axis_types": (AxisType.Auto,) * n}
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_host_mesh", "mesh_from_arg"]
 
@@ -45,7 +35,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     import numpy as np
 
     dev_array = np.asarray(devices[:need]).reshape(shape)
-    return Mesh(dev_array, axes, **_axis_kwargs(len(axes)))
+    return Mesh(dev_array, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_from_arg(spec: str, *, verbose: bool = True) -> Mesh:
@@ -73,4 +63,4 @@ def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     if len(devices) < need:
         raise RuntimeError(f"need {need} devices, have {len(jax.devices())}")
     return Mesh(np.asarray(devices).reshape(data, model), ("data", "model"),
-                **_axis_kwargs(2))
+                axis_types=(AxisType.Auto,) * 2)

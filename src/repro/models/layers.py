@@ -556,7 +556,6 @@ def moe_ffn_ep(p, x: jax.Array, cfg, mesh) -> Tuple[jax.Array, jax.Array]:
     inside the shard (ZeRO-3 semantics preserved: backward turns the
     gather into a reduce-scatter of expert grads).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, s, d = x.shape
@@ -617,13 +616,13 @@ def moe_ffn_ep(p, x: jax.Array, cfg, mesh) -> Tuple[jax.Array, jax.Array]:
         out = jax.lax.psum(part, "model").astype(x_loc.dtype)
         return out.reshape(x_loc.shape), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_spec, None, None), P(None, None),
                   P("model", "data", None), P("model", "data", None),
                   P("model", None, "data")),
         out_specs=(P(batch_spec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if cfg.moe_shared_experts:

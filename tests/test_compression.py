@@ -66,7 +66,6 @@ def test_traffic_accounting():
 
 
 def test_compressed_psum_matches_mean():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_host_mesh
     from repro.train.train_step import compressed_psum
@@ -74,9 +73,9 @@ def test_compressed_psum_matches_mean():
     mesh = make_host_mesh(1, 1)
     x = jnp.asarray(np.random.default_rng(0)
                     .standard_normal((4, 8)).astype(np.float32))
-    out = shard_map(lambda v: compressed_psum(v, "data"),
-                    mesh=mesh, in_specs=P(), out_specs=P(),
-                    check_rep=False)(x)
+    out = jax.shard_map(lambda v: compressed_psum(v, "data"),
+                        mesh=mesh, in_specs=P(), out_specs=P(),
+                        check_vma=False)(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x), rtol=2e-2,
                                atol=2e-2)
 
