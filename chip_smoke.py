@@ -10,16 +10,20 @@ The main path is the one users train with: ``from_coo`` → ``ad_plan`` →
 ``interpret=False``, on the Table-4 ``Amazon`` replica at scale 1.0, at the
 paper's widths (GCN: 5 layers, hidden 128, 128 input features; AGNN:
 hidden 32).  Weights, features and labels are random, made from
-``--seed``.  The run fails (non-zero exit) unless
+``--seed``.  The configuration is fixed: the module constants below are
+what the last line certifies.  The run fails (non-zero exit) unless
 
   * JAX's default backend is a TPU;
   * no dispatch fell back to another impl (``record_calls`` log);
   * every loss is finite;
-  * the first layer's aggregation, the step-0 loss and the step-0
-    gradient (the backward kernels; the momentum buffer after one step is
-    exactly that gradient) agree, to fp32 tolerance, with a plain ``jax.numpy``
-    reference (``segment_sum`` over the COO edges) written here,
-    independent of the kernels.
+  * the first layer's aggregation and the step-0 loss agree, to fp32
+    tolerance, with a plain ``jax.numpy`` reference (``segment_sum`` over
+    the COO edges) written here, independent of the kernels;
+  * the step-0 gradient (the backward kernels; the momentum buffer after
+    one step is exactly that gradient) agrees with the same reference run
+    in float64 on the host CPU.  Each gradient leaf is held to the bound
+    on its own scale, so a small leaf (an early layer, an attention beta)
+    cannot hide behind a large one.
 
 It prints phase times (host wall clock, compilation included where noted)
 and, as its last line, ``{"ok": true, "device": {...}}``.
@@ -33,19 +37,35 @@ import os
 import sys
 import time
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+# The gradient reference runs in float64 on the host's CPU backend, which
+# a platform list without "cpu" would leave out.
+_PLATFORMS = os.environ.get("JAX_PLATFORMS", "")
+if _PLATFORMS and "cpu" not in _PLATFORMS.split(","):
+    os.environ["JAX_PLATFORMS"] = _PLATFORMS + ",cpu"
 
-# fp32 tolerances of the comparisons with the reference.  Both sides run
-# fp32 with fp32-accurate matmuls, so they differ by summation order only.
-# Forward values sum at most ~20 edges per row.  Gradients also sum over
-# each node's in-edges, and the generated graph's hub node has almost n of
-# them (403,391 at Amazon scale 1.0); an fp32 sum of k terms may be off by
-# up to ~k * 6e-8 relative, so gradients get a wider bound.
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+# Forward values (aggregation, loss) against the fp32 reference: both
+# sides run fp32 with fp32-accurate matmuls and sum at most ~20 edges per
+# row, so they differ by summation order only.
 RTOL = 1e-4
-GRAD_RTOL = 1e-3
+# Step-0 gradients against the float64 reference, per leaf.  The fp32
+# reference cannot serve here: the generated graph's hub node has 403,391
+# in-edges, and the deeper AGNN betas are sums over 3.4M edges that
+# cancel to 1e-6..1e-7 of the largest gradient, so fp32 sums of either
+# side err by up to 1.2e-2 of such a leaf.  Against float64 the kernels'
+# worst leaf was 3.3e-5 (GCN) and 3.3e-4 (AGNN beta[2]) at Amazon scale
+# 1.0 on a v5e; each bound is about 3x that.
+GRAD_RTOL = {"gcn": 1e-4, "agnn": 1e-3}
 LR = 1e-2
+
+# The configuration: Table 4's Amazon at its published size, the paper's
+# widths.
+GRAPH, SCALE = "Amazon", 1.0
+STEPS = 3
+IN_DIM, HIDDEN_GCN, HIDDEN_AGNN, NUM_CLASSES = 128, 128, 32, 16
 
 
 def _log(msg: str) -> None:
@@ -130,13 +150,32 @@ def make_reference(rows, cols, vals, n):
                    "gcn": loss_and_grad(gcn), "agnn": loss_and_grad(agnn)}
 
 
-def rel_err(got, want) -> float:
-    """max |got - want| / max |want| over all leaves of two pytrees."""
-    got, want = (jnp.concatenate([jnp.ravel(a).astype(jnp.float32)
-                                  for a in jax.tree.leaves(t)])
-                 for t in (got, want))
-    scale = jnp.maximum(jnp.max(jnp.abs(want)), 1e-30)
-    return float(jnp.max(jnp.abs(got - want)) / scale)
+def rel_err(got, want) -> tuple[float, str]:
+    """The worst leaf of two pytrees: ``max |got - want| / max |want|``
+    taken leaf by leaf, and that leaf's path."""
+    worst = (0.0, "")
+    for (path, w), g in zip(jax.tree_util.tree_leaves_with_path(want),
+                            jax.tree.leaves(got)):
+        w, g = (np.asarray(a, np.float64) for a in (w, g))
+        err = float(np.max(np.abs(g - w), initial=0.0)
+                    / max(np.max(np.abs(w), initial=0.0), 1e-300))
+        if err >= worst[0]:
+            worst = (err, jax.tree_util.keystr(path))
+    return worst
+
+
+def on_host_f64(fn, *args):
+    """``fn(*args)`` in float64 on the host CPU: floating leaves are
+    widened (their values unchanged), integer leaves kept."""
+    cpu = jax.devices("cpu")[0]
+
+    def widen(a):
+        a = np.asarray(a)
+        return jax.device_put(a.astype(np.float64) if np.issubdtype(
+            a.dtype, np.floating) else a, cpu)
+
+    with jax.enable_x64(True):
+        return fn(*jax.tree.map(widen, args))
 
 
 def check(cond: bool, msg: str) -> None:
@@ -156,19 +195,19 @@ def check_no_fallback(log, where: str) -> None:
 # ---------------------------------------------------------------------------
 
 
-def build_task(args, phases):
+def build_task(seed, phases):
     from repro.core import from_coo
     from repro.sparse.graphs import make_dataset
 
-    g = make_dataset(args.graph, scale=args.scale, seed=args.seed)
+    g = make_dataset(GRAPH, scale=SCALE, seed=seed)
     n = g.num_nodes
     fmt = from_coo(g.rows, g.cols, g.vals, (n, n), vector_size=8)
-    _log(f"graph {args.graph} scale {args.scale}: {n} nodes, "
+    _log(f"graph {GRAPH} scale {SCALE}: {n} nodes, "
          f"{g.num_edges} edges, {fmt.nnzv} nonzero vectors")
     phases.done("host format build")
-    kx, ky, km = jax.random.split(jax.random.key(args.seed), 3)
-    x = jax.random.normal(kx, (n, args.in_dim), jnp.float32)
-    labels = jax.random.randint(ky, (n,), 0, args.num_classes)
+    kx, ky, km = jax.random.split(jax.random.key(seed), 3)
+    x = jax.random.normal(kx, (n, IN_DIM), jnp.float32)
+    labels = jax.random.randint(ky, (n,), 0, NUM_CLASSES)
     train_mask = (jax.random.uniform(km, (n,)) < 0.7).astype(jnp.float32)
     return g, fmt, x, labels, train_mask
 
@@ -202,14 +241,21 @@ def train(cfg, adj, x, labels, train_mask, steps, seed, phases, tag):
     return params0, grad0, losses
 
 
-def one_chip(args, phases) -> None:
+def check_close(what: str, got, want, bound: float) -> None:
+    err, leaf = rel_err(got, want)
+    _log(f"{what}: relative error {err:.3e}"
+         + (f" (worst leaf {leaf})" if leaf else ""))
+    check(err <= bound, f"{what}: error {err:.3e} > {bound}")
+
+
+def one_chip(seed, phases) -> None:
     from repro.core import spmm
     from repro.core.autodiff import ad_plan
     from repro.core.dispatch import record_calls
     from repro.models.gnn import GNNConfig
 
-    g, fmt, x, labels, train_mask = build_task(args, phases)
-    plan = ad_plan(fmt, impl="pallas", n_example=args.hidden_gcn)
+    g, fmt, x, labels, train_mask = build_task(seed, phases)
+    plan = ad_plan(fmt, impl="pallas", n_example=HIDDEN_GCN)
     phases.done("plan (transpose, blocking, value permutation)")
 
     edges, ref = make_reference(g.rows, g.cols, g.vals, g.num_nodes)
@@ -217,34 +263,31 @@ def one_chip(args, phases) -> None:
         agg = spmm(plan.fwd, x, impl="pallas", interpret=False, strict=True)
         agg.block_until_ready()
     check_no_fallback(log, "aggregation")
-    err = rel_err(agg, ref["aggregate"](edges, x))
-    _log(f"first-layer aggregation: max |kernel - reference| / max |ref| "
-         f"= {err:.3e}")
-    check(err <= RTOL, f"aggregation error {err:.3e} > {RTOL}")
+    check_close("first-layer aggregation vs reference", agg,
+                ref["aggregate"](edges, x), RTOL)
     phases.done("aggregation check (compile + run + reference)")
 
-    for model, hidden in (("gcn", args.hidden_gcn), ("agnn", args.hidden_agnn)):
-        cfg = GNNConfig(model=model, in_dim=args.in_dim, hidden_dim=hidden,
-                        num_classes=args.num_classes, impl="pallas",
+    for model, hidden in (("gcn", HIDDEN_GCN), ("agnn", HIDDEN_AGNN)):
+        cfg = GNNConfig(model=model, in_dim=IN_DIM, hidden_dim=hidden,
+                        num_classes=NUM_CLASSES, impl="pallas",
                         interpret=False)
         params0, grad0, losses = train(cfg, plan, x, labels, train_mask,
-                                       args.steps, args.seed, phases, model)
-        want, grad = ref[model](params0, edges, x, labels, train_mask)
+                                       STEPS, seed, phases, model)
+        want, _ = ref[model](params0, edges, x, labels, train_mask)
         want = float(want)
         err = abs(losses[0] - want) / max(1.0, abs(want))
         _log(f"{model} step-0 loss {losses[0]:.7f}, reference {want:.7f}, "
              f"relative error {err:.3e}")
         check(err <= RTOL, f"{model} step-0 loss error {err:.3e} > {RTOL}")
-        err = rel_err(grad0, grad)
-        _log(f"{model} step-0 gradient vs reference: relative error "
-             f"{err:.3e}")
-        check(err <= GRAD_RTOL,
-              f"{model} step-0 gradient error {err:.3e} > {GRAD_RTOL}")
-        phases.done(f"{model} reference loss and gradient")
+        _, grad = on_host_f64(ref[model], params0, edges, x, labels,
+                              train_mask)
+        check_close(f"{model} step-0 gradient vs float64 reference", grad0,
+                    grad, GRAD_RTOL[model])
+        phases.done(f"{model} reference loss (fp32) and gradient (float64)")
     log_peak_memory(jax.devices()[:1])
 
 
-def four_chips(args, phases) -> None:
+def four_chips(seed, phases) -> None:
     """The sharded GCN step on a 4x1 mesh against the one-chip step."""
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -256,11 +299,11 @@ def four_chips(args, phases) -> None:
 
     check(len(jax.devices()) >= 4,
           f"--four-chips needs 4 devices, found {len(jax.devices())}")
-    g, fmt, x, labels, train_mask = build_task(args, phases)
+    g, fmt, x, labels, train_mask = build_task(seed, phases)
     mesh = make_host_mesh(4, 1)
-    plan1 = ad_plan(fmt, impl="pallas", n_example=args.hidden_gcn)
+    plan1 = ad_plan(fmt, impl="pallas", n_example=HIDDEN_GCN)
     plan4 = ad_plan(fmt, impl="pallas_sharded", mesh=mesh,
-                    n_example=args.hidden_gcn)
+                    n_example=HIDDEN_GCN)
     plan4 = jax.device_put(plan4, sparse_format_shardings(plan4, mesh))
     phases.done("plans (one chip, 4-way sharded)")
 
@@ -284,28 +327,22 @@ def four_chips(args, phases) -> None:
     a1 = spmm(plan1.fwd, x, impl="pallas", interpret=False, strict=True)
     a4 = spmm(plan4.fwd, x4, impl="pallas_sharded", interpret=False,
               strict=True, mesh=mesh, part=plan4.fwd_part)
-    err = rel_err(a4, a1)
-    _log(f"aggregation 4 chips vs 1 chip: relative error {err:.3e}")
-    check(err <= RTOL, f"sharded aggregation error {err:.3e} > {RTOL}")
+    check_close("aggregation 4 chips vs 1 chip", a4, a1, RTOL)
     phases.done("aggregation 1 chip and 4 chips")
 
     losses, grads = {}, {}
     for impl, plan, ops in (("pallas", plan1, (x, labels, train_mask)),
                             ("pallas_sharded", plan4, (x4, labels4, mask4))):
-        cfg = GNNConfig(model="gcn", in_dim=args.in_dim,
-                        hidden_dim=args.hidden_gcn,
-                        num_classes=args.num_classes, impl=impl,
-                        interpret=False)
-        _, grads[impl], losses[impl] = train(cfg, plan, *ops, 1, args.seed,
+        cfg = GNNConfig(model="gcn", in_dim=IN_DIM, hidden_dim=HIDDEN_GCN,
+                        num_classes=NUM_CLASSES, impl=impl, interpret=False)
+        _, grads[impl], losses[impl] = train(cfg, plan, *ops, 1, seed,
                                              phases, f"gcn[{impl}]")
     err = abs(losses["pallas_sharded"][0] - losses["pallas"][0]) / max(
         1.0, abs(losses["pallas"][0]))
     _log(f"gcn step-0 loss 4 chips vs 1 chip: relative error {err:.3e}")
     check(err <= RTOL, f"sharded loss error {err:.3e} > {RTOL}")
-    err = rel_err(grads["pallas_sharded"], grads["pallas"])
-    _log(f"gcn step-0 gradient 4 chips vs 1 chip: relative error {err:.3e}")
-    check(err <= GRAD_RTOL,
-          f"sharded gradient error {err:.3e} > {GRAD_RTOL}")
+    check_close("gcn step-0 gradient 4 chips vs 1 chip",
+                grads["pallas_sharded"], grads["pallas"], GRAD_RTOL["gcn"])
     log_peak_memory(jax.devices()[:4])
 
 
@@ -321,14 +358,8 @@ def main(argv=None) -> int:
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the 4-chip pallas_sharded GCN step and "
                          "its one-chip comparison")
-    ap.add_argument("--graph", default="Amazon")
-    ap.add_argument("--scale", type=float, default=1.0)
-    ap.add_argument("--steps", type=int, default=3)
-    ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--in-dim", type=int, default=128)
-    ap.add_argument("--hidden-gcn", type=int, default=128)
-    ap.add_argument("--hidden-agnn", type=int, default=32)
-    ap.add_argument("--num-classes", type=int, default=16)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the graph, features, labels and weights")
     args = ap.parse_args(argv)
 
     backend = jax.default_backend()
@@ -349,9 +380,9 @@ def main(argv=None) -> int:
 
     phases = Phases()
     if args.four_chips:
-        four_chips(args, phases)
+        four_chips(args.seed, phases)
     else:
-        one_chip(args, phases)
+        one_chip(args.seed, phases)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}))

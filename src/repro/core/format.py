@@ -197,10 +197,6 @@ class Schedule:
       seg_meta (NS, 4) int32  per segment: [first K-block, K-block count,
                               is-first-segment-of-window,
                               is-last-segment-of-window]
-      blk_id   (NSB,)  int32  scheduled K-blocks, in segment order (for the
-                              block-grid SDDMM; identity for any non-empty
-                              matrix since every block is owned)
-      blk_win  (NSB,)  int32  owning window of each scheduled block
 
     Segments of one window are contiguous and emitted in ascending block
     order, so on a sequential Pallas grid consecutive cells of one window
@@ -218,8 +214,6 @@ class Schedule:
 
     seg_win: jax.Array
     seg_meta: jax.Array
-    blk_id: jax.Array
-    blk_win: jax.Array
     split_blk: int            # max K-blocks per segment (0 = unsplit)
     num_blocks: int           # total scheduled K-blocks (0 iff all-empty)
 
@@ -228,7 +222,7 @@ class Schedule:
         return int(self.seg_win.shape[0])
 
     def tree_flatten(self):
-        leaves = (self.seg_win, self.seg_meta, self.blk_id, self.blk_win)
+        leaves = (self.seg_win, self.seg_meta)
         return leaves, (self.split_blk, self.num_blocks)
 
     @classmethod
@@ -274,19 +268,10 @@ def build_schedule(blocked: BlockedMEBCRS, split_blk: int = 1,
 
     seg_meta = np.stack([seg_lo, seg_len, seg_first, seg_last],
                         axis=1).astype(np.int32)
-    # Segments walk each window's contiguous block range in ascending
-    # order and windows ascend, so the scheduled-block list is exactly
-    # the owned blocks 0..win_ptr[-1) in order (the dummy block of an
-    # all-empty matrix is never scheduled).
-    blk_id = np.arange(int(wp[-1]), dtype=np.int32)
-    blk_win = np.repeat(np.arange(w, dtype=np.int32),
-                        counts).astype(np.int32)
 
     return _validate.validate_schedule(Schedule(
         seg_win=jnp.asarray(seg_win.astype(np.int32)),
         seg_meta=jnp.asarray(seg_meta),
-        blk_id=jnp.asarray(blk_id),
-        blk_win=jnp.asarray(blk_win),
         split_blk=split_blk,
         num_blocks=int(wp[-1]),
     ), blocked=blocked, check=level)

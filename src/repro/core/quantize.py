@@ -11,8 +11,8 @@ share code:
     ``(K_BLK, V)`` value tile stores int8 with one fp32 scale, and the
     kernels dequantize in-VMEM via the block's scale — the
     dequantization commutes with the contraction
-    (``dot(s·q, b) = s·dot(q, b)``), so the MXU runs on narrow data and a
-    single fp32 multiply per block restores the magnitude.
+    (``dot(s·q, b) = s·dot(q, b)``), so a single fp32 multiply per block
+    restores the magnitude.
 
 The quantizer is jit-able (no host round trip), so the int8 execution
 paths can quantize *in trace* — e.g. the autodiff wrappers quantize the
@@ -24,8 +24,8 @@ straight-through to the fp32 masters.
   ``fp32``   operands cast to float32 (bitwise-identical to the legacy
              fp32-only kernels for fp32 inputs)
   ``bf16``   dense operands and float sparse values cast to bfloat16
-             before the kernel — inputs are DMA'd at 2 bytes/element, the
-             in-kernel accumulator stays fp32, the epilogue casts back
+             before the kernel — the in-kernel accumulator stays fp32,
+             the epilogue casts back
   ``int8``   sparse values quantized per K-block to int8 + fp32 scale
              (SpMM only — the dense operand rides at bf16); dense-operand
              int8 is not exposed because the per-row DMA granularity of
@@ -34,6 +34,11 @@ straight-through to the fp32 masters.
 ``precision=None`` everywhere means "run at the operand dtypes as given"
 — the pre-existing behavior, kept as the default so no caller changes
 meaning.
+
+The Pallas kernels have no packed narrow layout: bf16 and int8 run in
+interpret mode (rounded, then widened to fp32 words), and compiled kernels
+refuse them (:func:`repro.kernels.layout.require_fp32`).  The XLA impls
+(``blocked``) implement every level without Pallas.
 """
 
 from __future__ import annotations

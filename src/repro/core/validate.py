@@ -345,8 +345,6 @@ def validate_schedule(sched, blocked=None, check: Optional[str] = "full"):
 
     sw = _np(sched.seg_win)
     sm = _np(sched.seg_meta)
-    blk_id = _np(sched.blk_id)
-    blk_win = _np(sched.blk_win)
     ns = sw.shape[0]
     _require(sm.ndim == 2 and sm.shape == (ns, 4), "schedule-shape",
              f"seg_meta shape {sm.shape} != ({ns}, 4)")
@@ -357,11 +355,6 @@ def validate_schedule(sched, blocked=None, check: Optional[str] = "full"):
                   and np.isin(last, (0, 1)).all()), "seg-flags",
              "seg first/last flags must be 0/1")
     nb = sched.num_blocks
-    _require(blk_id.shape == blk_win.shape == (nb,), "blk-id-bounds",
-             f"blk_id/blk_win shapes {blk_id.shape}/{blk_win.shape} != "
-             f"({nb},)")
-    _require(nb == 0 or (blk_id.min() >= 0 and blk_id.max() < nb),
-             "blk-id-bounds", f"blk_id outside [0, {nb})")
     if blocked is None:
         return sched
     wptr = _np(blocked.win_ptr)
@@ -390,9 +383,6 @@ def validate_schedule(sched, blocked=None, check: Optional[str] = "full"):
         _require(np.array_equal(span, want), "seg-coverage",
                  f"window {wi}'s segments cover blocks {span.tolist()[:8]}…"
                  f" instead of [{int(wptr[wi])}, {int(wptr[wi + 1])})")
-    _require(np.array_equal(blk_win, np.repeat(np.arange(w), np.diff(wptr))),
-             "block-win-consistent",
-             "schedule blk_win disagrees with win_ptr")
     return sched
 
 

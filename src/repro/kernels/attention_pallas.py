@@ -44,9 +44,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .layout import (CACHE_ROWS, LANES, SmemRows, block_tile, chunks_per_block,
-                     dot, lane_tile, pad_cols, schedule_steps, smem_cache,
-                     steps_table, sublanes, table, vectors_on_lanes,
-                     window_steps)
+                     dot, lane_tile, pad_cols, require_fp32, schedule_steps,
+                     smem_cache, steps_table, sublanes, table,
+                     vectors_on_lanes, window_steps)
 from .sddmm_pallas import _cast_precision
 
 __all__ = [
@@ -170,6 +170,7 @@ def _attn_call(steps, cols, mask, q3, k3, v3, *, num_windows, k_blk,
     (scaled, window rows padded); ``k3``/``v3`` ``(1 | H, Mc, D | DV)``.
     Returns fp32 ``(H, num_windows·V, DV_pad)``; rows of windows no step
     stores are left unwritten."""
+    require_fp32(interpret, q3, k3, v3)
     _, v = mask.shape
     hq, _, d = q3.shape
     hk, kv_rows, _ = k3.shape
@@ -247,6 +248,7 @@ def attention_pallas(blocked, q: jax.Array, k: jax.Array, v: jax.Array, *,
     or ``(H, M, DV)`` in ``v`` dtype.  ``precision`` ("fp32"/"bf16")
     rounds Q/K/V before the launch; the online-softmax statistics and the
     output accumulator stay fp32 in VMEM either way (DESIGN.md §13).
+    bf16 runs in interpret mode only: compiled, the kernel takes fp32.
     """
     return _attention(blocked, q, k, v, window_steps(blocked.win_ptr),
                       scale=scale, precision=precision, interpret=interpret)
@@ -301,16 +303,16 @@ def attention_pallas_staged(blocked, q: jax.Array, k: jax.Array,
     from repro.core.sddmm import with_values
     from repro.core.softmax import sparse_softmax
 
-    from .sddmm_pallas import sddmm_pallas_batched
-    from .spmm_pallas import spmm_pallas_batched
+    from .sddmm_pallas import sddmm_pallas
+    from .spmm_pallas import spmm_pallas
 
     q, k, v = _cast_precision(precision, q, k, v)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    scores = sddmm_pallas_batched(blocked, q, k, f_blk=f_blk,
+    scores = sddmm_pallas(blocked, q, k, f_blk=f_blk,
                                   interpret=interpret)
     probs = sparse_softmax(blocked, scores.astype(jnp.float32) * scale)
-    return spmm_pallas_batched(with_values(blocked, probs.astype(v.dtype)),
+    return spmm_pallas(with_values(blocked, probs.astype(v.dtype)),
                                v, n_blk=n_blk, interpret=interpret)
 
 
@@ -329,6 +331,12 @@ def attention_hbm_bytes(blocked, d: int, dv: int, *, h: int = 1,
     softmax, plus its segment-stats traffic), and re-reads the
     probabilities inside the SpMM — four extra score-sized HBM passes per
     head that the fused kernel keeps resident in VMEM.
+
+    These are structural counts of a narrow layout, not of the committed
+    kernels (:mod:`.layout`): each K-block's mask arrives as a whole
+    128-lane chunk, Q/K/V are padded to 128 lanes, and every word is 4
+    bytes whatever the precision.  Use them to compare paths, not as the
+    bytes of a roofline share.
     """
     from .sddmm_pallas import sddmm_hbm_bytes
     from .spmm_pallas import spmm_hbm_bytes

@@ -390,11 +390,7 @@ def tune_spmm(fmt: MEBCRS, b_dense: jax.Array, *,
     devices must not satisfy an 8-device lookup), while ``0`` keeps the
     single-device kernels, so a no-axis sweep behaves exactly as before.
     """
-    from .spmm_pallas import (
-        spmm_pallas,
-        spmm_pallas_balanced,
-        spmm_pallas_batched,
-    )
+    from .spmm_pallas import spmm_pallas, spmm_pallas_balanced
 
     if any(ob > 0 for ob in overlap_batches):
         from repro.distributed.sparse_shard import _resolve_mesh
@@ -417,9 +413,6 @@ def tune_spmm(fmt: MEBCRS, b_dense: jax.Array, *,
             return spmm_pallas_balanced(blocked, b_dense, split_blk=split,
                                         n_blk=n_blk, interpret=interpret,
                                         precision=prec)
-        if b_dense.ndim == 3:
-            return spmm_pallas_batched(blocked, b_dense, n_blk=n_blk,
-                                       interpret=interpret, precision=prec)
         return spmm_pallas(blocked, b_dense, n_blk=n_blk,
                            interpret=interpret, precision=prec)
 
@@ -451,11 +444,7 @@ def tune_sddmm(fmt: MEBCRS, q: jax.Array, k: jax.Array, *,
     batch/head dim; the batched ``(H, NB, F/F_BLK)`` grid is then timed
     on the full batch and the batch size keys the bucket.
     """
-    from .sddmm_pallas import (
-        sddmm_pallas,
-        sddmm_pallas_balanced,
-        sddmm_pallas_batched,
-    )
+    from .sddmm_pallas import sddmm_pallas, sddmm_pallas_balanced
 
     batch = next((x.shape[0] for x in (q, k) if x.ndim == 3), 1)
 
@@ -465,9 +454,6 @@ def tune_sddmm(fmt: MEBCRS, q: jax.Array, k: jax.Array, *,
             return sddmm_pallas_balanced(blocked, q, k, split_blk=split,
                                          f_blk=f_blk, interpret=interpret,
                                          precision=prec)
-        if q.ndim == 3 or k.ndim == 3:
-            return sddmm_pallas_batched(blocked, q, k, f_blk=f_blk,
-                                        interpret=interpret, precision=prec)
         return sddmm_pallas(blocked, q, k, f_blk=f_blk, interpret=interpret,
                             precision=prec)
 

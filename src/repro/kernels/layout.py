@@ -8,10 +8,13 @@ on the other hand, must not be scalar-prefetched: SMEM is 1 MiB on v5e,
 so a graph with more than ~262k column ids would not fit.  Every kernel
 therefore sees its operands in one layout:
 
-  * **32-bit words only.**  Narrow precisions (bf16 rounding, int8 values
-    with per-block scales) are applied by the wrappers before the launch
-    and the kernels DMA fp32; they accumulate in fp32 either way, so
-    results are bitwise those of an in-kernel upcast.
+  * **32-bit words only.**  Compiled, the kernels take fp32 operands and
+    refuse narrow ones (:func:`require_fp32`): bf16 or int8 would have to
+    be widened before the launch, which moves more bytes than fp32, not
+    fewer.  Interpret mode keeps the narrow precisions (bf16 rounding,
+    int8 values with per-block scales) for the numerics tests: the
+    wrappers apply them and the kernels run on the widened words, which
+    is bitwise an in-kernel upcast.
   * **Lane-dense sparse tiles.**  ``vals``/``mask`` ``(NNZP, V)`` are
     passed transposed, ``(V, L)`` with the vector index on lanes
     (:func:`vectors_on_lanes`); a K-block is a ``(V, k_blk)`` window of
@@ -35,6 +38,23 @@ LANES = 128
 
 # Rows of a metadata table one SMEM cache holds (16 x 128 int32 = 8 KiB).
 CACHE_ROWS = 16
+
+
+def require_fp32(interpret: bool, *operands, quantized: bool = False):
+    """Refuse narrow operands (or int8-quantized values) of a compiled
+    kernel: there is no packed 16-bit or 8-bit layout, so they could only
+    run widened to fp32 words."""
+    if interpret:
+        return
+    narrow = sorted({str(x.dtype) for x in operands
+                     if x.dtype != jnp.float32} | ({"int8"} if quantized
+                                                   else set()))
+    if narrow:
+        raise ValueError(
+            f"compiled Pallas kernels take fp32 operands only, got "
+            f"{', '.join(narrow)}: narrow precisions would run widened to "
+            f"fp32 words, more traffic than fp32.  Use precision='fp32', "
+            f"the 'blocked' impl, or interpret mode")
 
 
 def lane_tile(requested: int, n: int) -> int:

@@ -30,13 +30,11 @@ from .sddmm_pallas import (
     sddmm_hbm_bytes,
     sddmm_pallas,
     sddmm_pallas_balanced,
-    sddmm_pallas_batched,
 )
 from .spmm_pallas import (
     spmm_hbm_bytes,
     spmm_pallas,
     spmm_pallas_balanced,
-    spmm_pallas_batched,
     spmm_pallas_noncoalesced,
     spmm_pallas_staged,
 )
@@ -46,8 +44,6 @@ __all__ = [
     "sddmm",
     "spmm_balanced",
     "sddmm_balanced",
-    "spmm_batched",
-    "sddmm_batched",
     "attention",
     "attention_balanced",
     "attention_staged",
@@ -107,24 +103,6 @@ def sddmm(blocked, q, k, *, f_blk: int = 128, interpret: bool | None = None,
     return sddmm_pallas(blocked, q, k, f_blk=f_blk,
                         interpret=_resolve_interpret(interpret),
                         precision=precision)
-
-
-def spmm_batched(blocked, b_dense, *, n_blk: int = 128,
-                 interpret: bool | None = None,
-                 precision: str | None = None):
-    """Batched SpMM: one (H, N/N_BLK, W) grid for any head count."""
-    return spmm_pallas_batched(blocked, b_dense, n_blk=n_blk,
-                               interpret=_resolve_interpret(interpret),
-                               precision=precision)
-
-
-def sddmm_batched(blocked, q, k, *, f_blk: int = 128,
-                  interpret: bool | None = None,
-                  precision: str | None = None):
-    """Batched SDDMM: one (H, NB, F/F_BLK) grid for any head count."""
-    return sddmm_pallas_batched(blocked, q, k, f_blk=f_blk,
-                                interpret=_resolve_interpret(interpret),
-                                precision=precision)
 
 
 def spmm_balanced(blocked, b_dense, *, schedule=None, split_blk: int = 1,
@@ -260,8 +238,7 @@ def spmm_tuned(fmt, b_dense, *, interpret: bool | None = None, cache=None,
                                     split_blk=cfg.split_blk, n_blk=cfg.n_blk,
                                     interpret=_resolve_interpret(interpret),
                                     precision=run_prec)
-    run = spmm_pallas_batched if b_dense.ndim == 3 else spmm_pallas
-    return run(blocked, b_dense, n_blk=cfg.n_blk,
+    return spmm_pallas(blocked, b_dense, n_blk=cfg.n_blk,
                interpret=_resolve_interpret(interpret), precision=run_prec)
 
 
@@ -294,10 +271,8 @@ def sddmm_tuned(fmt, q, k, *, interpret: bool | None = None, cache=None,
                               **kwargs)
     run_prec = cfg.precision if precisions is not None else None
     blocked = block_format(fmt, cfg.k_blk)
-    run = (sddmm_pallas_batched if (q.ndim == 3 or k.ndim == 3)
-           else sddmm_pallas)
-    vals = run(blocked, q, k, f_blk=cfg.n_blk, interpret=interpret,
-               precision=run_prec)
+    vals = sddmm_pallas(blocked, q, k, f_blk=cfg.n_blk, interpret=interpret,
+                        precision=run_prec)
     return with_values(blocked, vals)
 
 
@@ -363,12 +338,6 @@ def _sddmm_tuned_adapter(fmt, q, k, *, k_blk=8, f_blk=None, interpret=None,
                        interpret=interpret, precision=precision)
 
 
-def _spmm_batched_adapter(fmt, b, *, k_blk=8, n_blk=128, interpret=None,
-                          precision=None):
-    return spmm_batched(_ensure_blocked(fmt, k_blk), b, n_blk=n_blk,
-                        interpret=interpret, precision=precision)
-
-
 def _spmm_balanced_adapter(fmt, b, *, k_blk=8, n_blk=128, split_blk=1,
                            schedule=None, interpret=None, precision=None):
     return spmm_balanced(_ensure_blocked(fmt, k_blk), b, schedule=schedule,
@@ -391,12 +360,6 @@ def _attention_balanced_adapter(fmt, q, k, v, *, scale=None, k_blk=8,
                               schedule=schedule, split_blk=split_blk,
                               scale=scale, interpret=interpret,
                               precision=precision)
-
-
-def _sddmm_batched_adapter(fmt, q, k, *, k_blk=8, f_blk=128, interpret=None,
-                           precision=None):
-    return sddmm_batched(_ensure_blocked(fmt, k_blk), q, k, f_blk=f_blk,
-                         interpret=interpret, precision=precision)
 
 
 def _attention_fused_adapter(fmt, q, k, v, *, scale=None, k_blk=8,
@@ -423,7 +386,9 @@ def _attention_tuned_adapter(fmt, q, k, v, *, scale=None, k_blk=8,
 
 _dispatch.register("spmm", "pallas", _spmm_pallas_adapter, differentiable=True,
                    precisions=("fp32", "bf16", "int8"))
-_dispatch.register("spmm", "pallas_batched", _spmm_batched_adapter,
+# "pallas_batched" is the same kernel as "pallas" (H is a grid axis of
+# every kernel); the name stays for callers that ask for a batched impl.
+_dispatch.register("spmm", "pallas_batched", _spmm_pallas_adapter,
                    differentiable=True, batched=True,
                    precisions=("fp32", "bf16", "int8"))
 # Block-parallel load-balanced impls (DESIGN.md §11): uniform-segment grids
@@ -449,7 +414,7 @@ _dispatch.register("spmm", "pallas_noncoalesced", _spmm_noncoalesced_adapter,
                    precisions=("fp32", "bf16", "int8"))
 _dispatch.register("sddmm", "pallas", _sddmm_pallas_adapter,
                    differentiable=True, precisions=("fp32", "bf16"))
-_dispatch.register("sddmm", "pallas_batched", _sddmm_batched_adapter,
+_dispatch.register("sddmm", "pallas_batched", _sddmm_pallas_adapter,
                    differentiable=True, batched=True,
                    precisions=("fp32", "bf16"))
 _dispatch.register("sddmm", "pallas_tuned", _sddmm_tuned_adapter,

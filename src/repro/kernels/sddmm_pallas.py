@@ -29,20 +29,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .layout import (CACHE_ROWS, LANES, SmemRows, dot, lane_tile, pad_cols,
-                     smem_cache, table, vectors_on_lanes)
+                     require_fp32, smem_cache, table, vectors_on_lanes)
 
 __all__ = [
     "sddmm_pallas",
     "sddmm_pallas_balanced",
-    "sddmm_pallas_batched",
     "sddmm_hbm_bytes",
 ]
 
 
 def _cast_precision(precision, *operands):
     """Apply the SDDMM/attention precision policy (DESIGN.md §13): cast the
-    dense operands to the target dtype so they DMA narrow; the in-kernel
-    accumulator stays fp32 regardless.  ``int8`` is not offered here — the
+    dense operands to the target dtype; the in-kernel accumulator stays
+    fp32 regardless, and compiled kernels take fp32 only
+    (:func:`.layout.require_fp32`).  ``int8`` is not offered here — the
     sampled-QKᵀ operands are dense rows with no per-block scale to attach
     (int8 lives on the SpMM value side)."""
     from repro.core.quantize import cast_precision
@@ -152,6 +152,7 @@ def _sddmm_call(chunk0, cols, win_ptr, block_win, mask, q3, k3, *, k_blk,
     ``k3`` ``(1 | H, Mc, F)``.  Returns fp32 ``(H, V, num_chunks·128)``:
     the sampled scores with the vector index on lanes.
     """
+    require_fp32(interpret, q3, k3)
     nnzp, v = mask.shape
     hq, qrows, _ = q3.shape
     hk, k_rows, _ = k3.shape
@@ -246,26 +247,14 @@ def sddmm_pallas(blocked, q: jax.Array, k: jax.Array, *, f_blk: int = 128,
     sampled K rows in-kernel (no staged gather of K) and contracts them
     against the Q tile of every window it touches.  ``precision``
     ("fp32"/"bf16") rounds Q and K before the launch; accumulation stays
-    fp32 in-kernel.  Either operand may carry a leading head dim (see
-    :func:`sddmm_pallas_batched`).
+    fp32 in-kernel.  bf16 runs in interpret mode only.  ``q``/``k`` may
+    be ``(M, F)``/``(Mc, F)`` or carry a leading per-head dim: one
+    ``(H, chunks, F/F_BLK)`` grid serves every head, and at least one
+    batched operand returns ``(H, NNZP, V)``, bitwise-equal to stacking H
+    per-slice launches.
     """
     return _sddmm(blocked, q, k, f_blk=f_blk, interpret=interpret,
                   precision=precision)
-
-
-def sddmm_pallas_batched(blocked, q: jax.Array, k: jax.Array, *,
-                         f_blk: int = 128,
-                         interpret: bool = True,
-                         precision: str | None = None) -> jax.Array:
-    """Batched gather-free SDDMM: one ``(H, chunks, F/F_BLK)`` grid for H
-    heads.
-
-    ``q``/``k`` may be ``(M, F)``/``(Mc, F)`` shared or carry a leading
-    per-head dim.  At least one operand batched returns ``(H, NNZP, V)``.
-    Bitwise-equal to stacking H per-slice launches.
-    """
-    return sddmm_pallas(blocked, q, k, f_blk=f_blk, interpret=interpret,
-                        precision=precision)
 
 
 def sddmm_pallas_balanced(blocked, q: jax.Array, k: jax.Array, *,
@@ -280,7 +269,7 @@ def sddmm_pallas_balanced(blocked, q: jax.Array, k: jax.Array, *,
     decides the degenerate case — an all-empty matrix has zero scheduled
     blocks and returns zeros without a kernel launch.  ``schedule`` is
     built from ``blocked`` with ``split_blk`` when omitted (host-side).
-    Batching follows :func:`sddmm_pallas_batched`; outputs are
+    Batching follows :func:`sddmm_pallas`; outputs are
     bitwise-equal to the window-parallel path.
     """
     if schedule is None:
@@ -306,6 +295,12 @@ def sddmm_hbm_bytes(blocked, f: int, *, f_blk: int = 128,
     ``staged``: the pre-fusion pipeline additionally read K and wrote /
     re-read the ``(NB·K_BLK, F)`` gather buffer, and wrote an fp32
     intermediate recast in a post-pass.
+
+    These are structural counts of a narrow layout, not of the committed
+    kernel (:mod:`.layout`), which moves fp32 words only, pads Q and K to
+    128 lanes (4x the bytes at F=32), reads the mask as fp32 and writes
+    the scores fp32.  Use them to compare paths, not as the bytes of a
+    roofline share.
     """
     v = blocked.vector_size
     nnzp = int(blocked.cols.shape[0])

@@ -49,14 +49,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .layout import (CACHE_ROWS, LANES, SmemRows, block_tile, chunks_per_block,
-                     dot, lane_tile, pad_cols, schedule_steps, smem_cache,
-                     steps_table, sublanes, table, vectors_on_lanes,
-                     window_steps)
+                     dot, lane_tile, pad_cols, require_fp32, schedule_steps,
+                     smem_cache, steps_table, sublanes, table,
+                     vectors_on_lanes, window_steps)
 
 __all__ = [
     "spmm_pallas",
     "spmm_pallas_balanced",
-    "spmm_pallas_batched",
     "spmm_pallas_noncoalesced",
     "spmm_pallas_staged",
     "spmm_hbm_bytes",
@@ -78,7 +77,7 @@ def _apply_precision(blocked, b_dense, precision):
         values + per-block ``scales`` selects the quantized kernel path.
       * ``"fp32"`` / ``"bf16"`` — cast the dense operand (and float
         values) to the target dtype; the in-kernel accumulator is fp32
-        either way, only the DMA'd bytes narrow.
+        either way.
       * ``"int8"`` — quantize the values per K-block **in trace**
         (:func:`repro.core.quantize.quantize_block_values`) unless the
         format is already quantized; the dense operand rides at bf16.
@@ -87,6 +86,8 @@ def _apply_precision(blocked, b_dense, precision):
     quantized) so every kernel shares one operand signature; the
     static ``quantized`` flag gates the per-block multiply, keeping the
     unquantized path's arithmetic untouched (bitwise-identical).
+    Compiled kernels take fp32 only and refuse the narrow results
+    (:func:`.layout.require_fp32`); interpret mode runs them widened.
     """
     from repro.core.quantize import quantize_block_values, validate_precision
 
@@ -257,6 +258,7 @@ def _spmm_call(steps, cols, scales, vals3, b3, *, num_windows, k_blk, n_blk,
     ``(1 | H, K, N)``.  Returns fp32 ``(H, num_windows·V, N_pad)``; rows of
     windows no step stores are left unwritten.
     """
+    require_fp32(interpret, vals3, b3, quantized=quantized)
     hv, _, v = vals3.shape
     hb, k_rows, _ = b3.shape
     h = max(hv, hb)
@@ -321,9 +323,14 @@ def spmm_pallas(blocked, b_dense: jax.Array, *, n_blk: int = 128,
     is materialized.  ``precision`` selects the mixed-precision path
     (DESIGN.md §13): ``"bf16"`` rounds the operands to bf16 with fp32
     accumulation; ``"int8"`` additionally quantizes the values per
-    K-block, dequantizing in VMEM with the per-block scales.  Either
-    operand may carry a leading head dim (see
-    :func:`spmm_pallas_batched`)."""
+    K-block, dequantizing in VMEM with the per-block scales.  Both run in
+    interpret mode only: compiled, the kernel takes fp32.  Either
+    operand may carry a leading head dim: ``blocked.vals`` ``(NNZP, V)``
+    or ``(H, NNZP, V)`` (per-head, e.g. attention probabilities),
+    ``b_dense`` ``(K, N)`` or ``(H, K, N)``; one ``(H, N/N_BLK, W)`` grid
+    serves every head, and at least one batched operand returns
+    ``(H, M, N)``, bitwise-equal to stacking H per-slice launches.
+    ``"int8"`` requires shared (2-D) pattern values."""
     return _spmm(blocked, b_dense, window_steps(blocked.win_ptr),
                  n_blk=n_blk, interpret=interpret, precision=precision)
 
@@ -339,23 +346,6 @@ def spmm_pallas_noncoalesced(blocked, b_dense: jax.Array, *, n_blk: int = 128,
                  double_buffer=False)
 
 
-def spmm_pallas_batched(blocked, b_dense: jax.Array, *, n_blk: int = 128,
-                        interpret: bool = True,
-                        precision: str | None = None) -> jax.Array:
-    """Batched gather-free SpMM: one ``(H, N/N_BLK, W)`` grid for H heads.
-
-    ``blocked.vals`` may be ``(NNZP, V)`` (shared pattern values) or
-    ``(H, NNZP, V)`` (per-head, e.g. attention probabilities);
-    ``b_dense`` may be ``(K, N)`` or ``(H, K, N)``.  At least one operand
-    batched returns ``(H, M, N)``.  The step metadata is shared by every
-    head, and results are bitwise-equal to stacking H per-slice launches
-    (identical per-cell accumulation order).  ``precision`` follows
-    :func:`spmm_pallas`; ``"int8"`` requires shared (2-D) pattern values.
-    """
-    return spmm_pallas(blocked, b_dense, n_blk=n_blk, interpret=interpret,
-                       precision=precision)
-
-
 def spmm_pallas_balanced(blocked, b_dense: jax.Array, *, schedule=None,
                          split_blk: int = 1, n_blk: int = 128,
                          interpret: bool = True,
@@ -366,7 +356,7 @@ def spmm_pallas_balanced(blocked, b_dense: jax.Array, *, schedule=None,
     omitted, it is built (and memoized) from ``blocked`` with ``split_blk``
     — host-side, so pass it explicitly when calling under ``jit``
     (``ADPlan`` does).  Operand batching follows
-    :func:`spmm_pallas_batched`: ``blocked.vals`` may be ``(NNZP, V)`` or
+    :func:`spmm_pallas`: ``blocked.vals`` may be ``(NNZP, V)`` or
     ``(H, NNZP, V)``, ``b_dense`` ``(K, N)`` or ``(H, K, N)``; unbatched
     in → unbatched out.  Results are **bitwise-equal** to
     :func:`spmm_pallas` (same per-block contraction in the same ascending
@@ -411,8 +401,9 @@ def spmm_pallas_staged(blocked, b_dense: jax.Array, *, n_blk: int = 128,
 
 # ---------------------------------------------------------------------------
 # Modeled HBM traffic (bytes moved per SpMM) — the Fig. 12-style cost model
-# extended to the execution paths above.  Exact structural counts; dense
-# and output elements assume ``value_bytes`` (fp32 = 4).
+# extended to the execution paths above, for a narrow layout: dense and
+# output elements at ``value_bytes``, values at ``k_blk × V`` per block.
+# The committed kernels move more (see the docstring).
 # ---------------------------------------------------------------------------
 
 
@@ -444,6 +435,14 @@ def spmm_hbm_bytes(blocked, n: int, *, n_blk: int = 128,
     ``staged``: additionally reads B and writes the ``(NB·K_BLK, N)``
     gather buffer, then re-reads it inside the kernel — three full passes
     over the gathered dense rows instead of one.
+
+    These are the paper-style (Fig. 12) structural counts of a narrow
+    layout, not of the committed kernels (:mod:`.layout`), which move
+    more: each K-block's values arrive as a whole 128-lane chunk
+    (``128 / k_blk`` times the value bytes), dense operands are padded to
+    128 lanes (:func:`.layout.lane_tile`), and every word is 4 bytes
+    whatever the precision.  Use them to compare paths, not as the bytes
+    of a roofline share.
     """
     v = blocked.vector_size
     nnzp = int(blocked.cols.shape[0])

@@ -16,8 +16,8 @@ import jax.numpy as jnp
 from repro.core import block_format, dispatch, from_dense
 from repro.core.autodiff import ad_plan, sddmm_ad, spmm_ad
 from repro.core.sddmm import with_values
-from repro.kernels.sddmm_pallas import sddmm_pallas, sddmm_pallas_batched
-from repro.kernels.spmm_pallas import spmm_pallas, spmm_pallas_batched
+from repro.kernels.sddmm_pallas import sddmm_pallas
+from repro.kernels.spmm_pallas import spmm_pallas
 
 
 def make_blocked(rng, m=40, k=36, density=0.25, empty_window=True):
@@ -36,18 +36,17 @@ def test_spmm_batched_bitwise_vs_per_slice(h):
     v3 = jnp.stack([(1.0 + i) * blocked.vals for i in range(h)])
 
     # both operands per-head
-    out = spmm_pallas_batched(with_values(blocked, v3), b3, interpret=True)
+    out = spmm_pallas(with_values(blocked, v3), b3, interpret=True)
     ref = jnp.stack([spmm_pallas(with_values(blocked, v3[i]), b3[i],
                                  interpret=True) for i in range(h)])
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
     # shared vals / shared b (no HBM broadcast, slice-0 reads)
-    out_sv = spmm_pallas_batched(blocked, b3, interpret=True)
+    out_sv = spmm_pallas(blocked, b3, interpret=True)
     ref_sv = jnp.stack([spmm_pallas(blocked, b3[i], interpret=True)
                         for i in range(h)])
     np.testing.assert_array_equal(np.asarray(out_sv), np.asarray(ref_sv))
-    out_sb = spmm_pallas_batched(with_values(blocked, v3), b3[0],
-                                 interpret=True)
+    out_sb = spmm_pallas(with_values(blocked, v3), b3[0], interpret=True)
     ref_sb = jnp.stack([spmm_pallas(with_values(blocked, v3[i]), b3[0],
                                     interpret=True) for i in range(h)])
     np.testing.assert_array_equal(np.asarray(out_sb), np.asarray(ref_sb))
@@ -60,12 +59,12 @@ def test_sddmm_batched_bitwise_vs_per_slice(h):
     q3 = jnp.asarray(rng.standard_normal((h, 40, 13)).astype(np.float32))
     k3 = jnp.asarray(rng.standard_normal((h, 36, 13)).astype(np.float32))
 
-    out = sddmm_pallas_batched(blocked, q3, k3, interpret=True)
+    out = sddmm_pallas(blocked, q3, k3, interpret=True)
     ref = jnp.stack([sddmm_pallas(blocked, q3[i], k3[i], interpret=True)
                      for i in range(h)])
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
-    out_sk = sddmm_pallas_batched(blocked, q3, k3[0], interpret=True)
+    out_sk = sddmm_pallas(blocked, q3, k3[0], interpret=True)
     ref_sk = jnp.stack([sddmm_pallas(blocked, q3[i], k3[0], interpret=True)
                         for i in range(h)])
     np.testing.assert_array_equal(np.asarray(out_sk), np.asarray(ref_sk))
@@ -75,9 +74,11 @@ def test_batched_unbatched_inputs_fall_through():
     rng = np.random.default_rng(2)
     _, blocked = make_blocked(rng)
     b = jnp.asarray(rng.standard_normal((36, 10)).astype(np.float32))
+    out = spmm_pallas(blocked, b, interpret=True)
+    assert out.shape == (40, 10)
     np.testing.assert_array_equal(
-        np.asarray(spmm_pallas_batched(blocked, b, interpret=True)),
-        np.asarray(spmm_pallas(blocked, b, interpret=True)))
+        np.asarray(out), np.asarray(spmm_pallas(blocked, b[None],
+                                                interpret=True)[0]))
 
 
 @pytest.mark.parametrize("h", [1, 4])

@@ -86,3 +86,36 @@ def test_reference_matches_xla_model(small_task, model):
     for a, b in zip(jax.tree.leaves(got_grad), jax.tree.leaves(want_grad)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
+
+
+def test_rel_err_holds_each_leaf_to_its_own_scale():
+    """An error that is small next to the largest gradient but large next
+    to its own leaf is reported, with that leaf's path."""
+    cs = _load_chip_smoke()
+    want = {"big": np.ones(4, np.float32) * 1e3,
+            "small": np.ones(3, np.float32) * 1e-3}
+    got = {"big": want["big"], "small": want["small"] * 1.01}
+    err, leaf = cs.rel_err(got, want)
+    np.testing.assert_allclose(err, 1e-2, rtol=1e-4)
+    assert leaf == "['small']"
+    assert cs.rel_err(want, want) == (0.0, "['small']")
+
+
+@pytest.mark.parametrize("model", ["gcn", "agnn"])
+def test_float64_reference_gradient(small_task, model):
+    """The gradient reference runs the same code in float64 on the host:
+    float64 leaves, equal to the fp32 run up to fp32 rounding."""
+    _, edges, ref, x, labels, mask = small_task
+    cs = _load_chip_smoke()
+    cfg = GNNConfig(model=model, in_dim=16, hidden_dim=16, num_classes=4,
+                    num_layers=2, impl="blocked")
+    init = init_gcn if model == "gcn" else init_agnn
+    params = init(jax.random.key(1), cfg)
+    loss32, grad32 = ref[model](params, edges, x, labels, mask)
+    loss64, grad64 = cs.on_host_f64(ref[model], params, edges, x, labels,
+                                    mask)
+    assert loss64.dtype == np.float64
+    assert all(a.dtype == np.float64 for a in jax.tree.leaves(grad64))
+    np.testing.assert_allclose(float(loss64), float(loss32), rtol=1e-5)
+    err, leaf = cs.rel_err(grad32, grad64)
+    assert err < 1e-3, (err, leaf)

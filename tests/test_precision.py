@@ -143,15 +143,15 @@ def test_spmm_batched_ladder(h):
     rng = np.random.default_rng(2)
     a, blocked = make_blocked(rng, 40, 40, 0.2)
     b = jnp.asarray(rng.standard_normal((h, 40, 32)), jnp.float32)
-    base = np.asarray(ops.spmm_batched(blocked, b, interpret=True))
+    base = np.asarray(ops.spmm(blocked, b, interpret=True))
     np.testing.assert_array_equal(
-        np.asarray(ops.spmm_batched(blocked, b, interpret=True,
+        np.asarray(ops.spmm(blocked, b, interpret=True,
                                     precision="fp32")), base)
-    out16 = ops.spmm_batched(blocked, b, interpret=True, precision="bf16")
+    out16 = ops.spmm(blocked, b, interpret=True, precision="bf16")
     assert out16.dtype == jnp.bfloat16 and out16.shape == (h, 40, 32)
     np.testing.assert_allclose(np.asarray(out16, np.float32), base,
                                rtol=2e-2, atol=2e-2 * np.abs(base).max())
-    out8 = ops.spmm_batched(blocked, b, interpret=True, precision="int8")
+    out8 = ops.spmm(blocked, b, interpret=True, precision="int8")
     err = np.abs(np.asarray(out8, np.float32) - base)
     bound = np.stack([int8_output_bound(blocked, b[i]) for i in range(h)])
     slack = np.maximum(np.abs(base), 1.0) * 2 ** -7
@@ -387,3 +387,28 @@ def test_sharded_precision_ladder():
                          text=True, timeout=900, env=env)
     assert out.returncode == 0, f"child failed:\n{out.stdout}\n{out.stderr}"
     assert "sharded precision ladder OK" in out.stdout
+
+
+@pytest.mark.parametrize("op,precision", [
+    ("spmm", "bf16"), ("spmm", "int8"), ("spmm-quantized-format", None),
+    ("sddmm", "bf16"), ("attention", "bf16")])
+def test_compiled_kernels_refuse_narrow_operands(op, precision):
+    """Compiled (``interpret=False``) launches take fp32 only: a narrow
+    request raises at trace time instead of running widened to fp32
+    words.  The refusal comes before any lowering, so it shows here."""
+    rng = np.random.default_rng(0)
+    _, blocked = make_blocked(rng, 32, 32, 0.3)
+    x = jnp.asarray(rng.standard_normal((32, 16)).astype(np.float32))
+    run = {
+        "spmm": lambda: ops.spmm(blocked, x, interpret=False,
+                                 precision=precision),
+        "spmm-quantized-format": lambda: ops.spmm(
+            quantize_format(blocked), x, interpret=False),
+        "sddmm": lambda: ops.sddmm(blocked, x, x, interpret=False,
+                                   precision=precision),
+        "attention": lambda: ops.attention(blocked, x, x, x,
+                                           interpret=False,
+                                           precision=precision),
+    }[op]
+    with pytest.raises(ValueError, match="fp32 operands only"):
+        run()
