@@ -1,0 +1,12 @@
+"""SpMM kernels' share of their roofline: the least time of the step's
+aggregations (the larger of their operations over the peak FLOP/s and
+their bytes over the peak HBM bandwidth, counted from the graph and the
+widths in ``bench/models``) over the SpMM kernels' device time."""
+
+from bench import trace
+
+KERNEL = "_spmm_call"     # the Pallas launcher's name in the trace
+
+
+def read(ctx):
+    return trace.roofline_share(ctx, "spmm", KERNEL)
