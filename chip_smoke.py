@@ -25,8 +25,10 @@ what the last line certifies.  The run fails (non-zero exit) unless
     on its own scale, so a small leaf (an early layer, an attention beta)
     cannot hide behind a large one.
 
-It prints phase times (host wall clock, compilation included where noted)
-and, as its last line, ``{"ok": true, "device": {...}}``.
+It prints phase times (host wall clock, compilation included where noted),
+the program's own set-up spans (``repro.core.metrics.spans``: ``fs.from_coo``,
+``fs.ad_plan`` and its children) and, as its last line,
+``{"ok": true, "device": {...}}``.
 """
 
 from __future__ import annotations
@@ -241,6 +243,16 @@ def train(cfg, adj, x, labels, train_mask, steps, seed, phases, tag):
     return params0, grad0, losses
 
 
+def log_setup_spans() -> None:
+    """The program's host spans so far: calls, total seconds, parent."""
+    from repro.core import metrics
+
+    for name, rec in metrics.spans().items():
+        parent = f" in {rec['parent']}" if rec["parent"] else ""
+        _log(f"span {name}{parent}: {rec['count']} call(s), "
+             f"{rec['total_s']:.3f} s")
+
+
 def check_close(what: str, got, want, bound: float) -> None:
     err, leaf = rel_err(got, want)
     _log(f"{what}: relative error {err:.3e}"
@@ -257,6 +269,7 @@ def one_chip(seed, phases) -> None:
     g, fmt, x, labels, train_mask = build_task(seed, phases)
     plan = ad_plan(fmt, impl="pallas", n_example=HIDDEN_GCN)
     phases.done("plan (transpose, blocking, value permutation)")
+    log_setup_spans()
 
     edges, ref = make_reference(g.rows, g.cols, g.vals, g.num_nodes)
     with record_calls() as log:
@@ -306,6 +319,7 @@ def four_chips(seed, phases) -> None:
                     n_example=HIDDEN_GCN)
     plan4 = jax.device_put(plan4, sparse_format_shardings(plan4, mesh))
     phases.done("plans (one chip, 4-way sharded)")
+    log_setup_spans()
 
     # each device holds its own slice of the partition, not device 0 all
     part = plan4.fwd_part.seg_win

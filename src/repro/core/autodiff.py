@@ -59,6 +59,7 @@ import numpy as np
 
 from . import dispatch as _dispatch
 from .format import MEBCRS, BlockedMEBCRS, Schedule, block_format
+from .metrics import op_tag, span
 from .sddmm import with_values
 from .softmax import sparse_softmax
 
@@ -133,16 +134,18 @@ class ADPlan:
         never leaks into the transpose-SpMM.  ``perm`` is in bounds by
         construction, so the gather clamps instead of filling: the TPU
         compiler takes minutes over a program with several fill-mode
-        gathers of this size, and seconds with clamping ones.
+        gathers of this size, and seconds with clamping ones.  Its ops
+        carry the tag ``fs.transpose_vals`` (:func:`.metrics.op_tag`).
         """
-        perm = self.perm.reshape(-1)
-        if vals.ndim == 3:
-            flat = jnp.take(vals.reshape(vals.shape[0], -1), perm, axis=1,
-                            mode="clip")
-            return (flat.reshape((vals.shape[0],) + self.bwd.vals.shape)
-                    * self.bwd.mask)
-        flat = jnp.take(vals.reshape(-1), perm, axis=0, mode="clip")
-        return flat.reshape(self.bwd.vals.shape) * self.bwd.mask
+        with op_tag("fs.transpose_vals"):
+            perm = self.perm.reshape(-1)
+            if vals.ndim == 3:
+                flat = jnp.take(vals.reshape(vals.shape[0], -1), perm,
+                                axis=1, mode="clip")
+                return (flat.reshape((vals.shape[0],) + self.bwd.vals.shape)
+                        * self.bwd.mask)
+            flat = jnp.take(vals.reshape(-1), perm, axis=0, mode="clip")
+            return flat.reshape(self.bwd.vals.shape) * self.bwd.mask
 
     def tree_flatten(self):
         return ((self.fwd, self.bwd, self.perm, self.fwd_sched,
@@ -196,6 +199,7 @@ def _blocked_perm(blocked_a: BlockedMEBCRS,
     return perm
 
 
+@span("fs.ad_plan")
 def ad_plan(fmt: MEBCRS, *, impl: str = "blocked", k_blk: int = 8,
             n_blk: int = 128, f_blk: int = 128, split_blk: int = 1,
             n_example: int = 64, interpret: Optional[bool] = None,
@@ -240,6 +244,10 @@ def ad_plan(fmt: MEBCRS, *, impl: str = "blocked", k_blk: int = 8,
     NaN/Inf, returning fp32.  Gradients stay the plain straight-through
     duality (the backward reads the fp32 masters regardless of which
     branch ran).  A no-op for fp32/None plans.
+
+    The host work runs under the span ``fs.ad_plan`` with the children
+    ``fs.ad_plan.transpose``, ``fs.ad_plan.block`` and ``fs.ad_plan.perm``
+    (:func:`.metrics.span`).
     """
     from .quantize import validate_precision
 
@@ -289,7 +297,8 @@ def ad_plan(fmt: MEBCRS, *, impl: str = "blocked", k_blk: int = 8,
     if key in memo:
         return memo[key]
 
-    fmt_t = fmt.transpose()
+    with span("fs.ad_plan.transpose"):
+        fmt_t = fmt.transpose()
     k_blk_f = k_blk_t = k_blk
     n_blk_t = n_blk
     split_f = split_t = (split_blk if impl in ("pallas_balanced",
@@ -320,8 +329,9 @@ def ad_plan(fmt: MEBCRS, *, impl: str = "blocked", k_blk: int = 8,
         f_blk = cfg_s.n_blk
         split_f, split_t = cfg_f.split_blk, cfg_t.split_blk
 
-    blocked_f = block_format(fmt, k_blk_f)
-    blocked_t = block_format(fmt_t, k_blk_t)
+    with span("fs.ad_plan.block"):
+        blocked_f = block_format(fmt, k_blk_f)
+        blocked_t = block_format(fmt_t, k_blk_t)
     # pallas_balanced/_sharded always carry schedules — split_blk = 0 is the
     # valid *unsplit* schedule, not "no schedule"; for pallas_tuned a split
     # of 0 means the sweep chose the window-parallel kernel for that
@@ -351,9 +361,10 @@ def ad_plan(fmt: MEBCRS, *, impl: str = "blocked", k_blk: int = 8,
         fwd_part_wa = sharded_schedule(blocked_f, ndev, split_blk=split_f,
                                        n_blk=n_blk, window_split=False,
                                        n_batches=nbat)
-    plan = ADPlan(fwd=blocked_f, bwd=blocked_t,
-                  perm=jnp.asarray(_blocked_perm(blocked_f, blocked_t)),
-                  impl=impl, n_blk=n_blk, n_blk_t=n_blk_t, f_blk=f_blk,
+    with span("fs.ad_plan.perm"):
+        perm = jnp.asarray(_blocked_perm(blocked_f, blocked_t))
+    plan = ADPlan(fwd=blocked_f, bwd=blocked_t, perm=perm, impl=impl,
+                  n_blk=n_blk, n_blk_t=n_blk_t, f_blk=f_blk,
                   fwd_sched=blocked_f.schedule(split_f) if want_f else None,
                   bwd_sched=blocked_t.schedule(split_t) if want_t else None,
                   fwd_part=fwd_part, bwd_part=bwd_part,
@@ -411,6 +422,9 @@ def _map_slices(entry, fn, batched_args, shared_args):
 
 def _run_spmm(impl, interpret, plan: ADPlan, vals, b, *, transposed: bool,
               precision=None):
+    # The single-device Pallas launches name their direction on the trace
+    # (``kernel_metadata``); the sharded ones carry no metadata.
+    direction = "bwd" if transposed else "fwd"
     blocked = plan.bwd if transposed else plan.fwd
     n_blk = plan.n_blk_t if transposed else plan.n_blk
     sched = plan.bwd_sched if transposed else plan.fwd_sched
@@ -437,14 +451,17 @@ def _run_spmm(impl, interpret, plan: ADPlan, vals, b, *, transposed: bool,
                                   with_values(blocked, vals), b,
                                   k_blk=blocked.k_blk, n_blk=n_blk,
                                   schedule=sched, interpret=interpret,
-                                  precision=precision)
-    if ex == "pallas" and (vals.ndim == 3 or b.ndim == 3):
-        # native (H, N/N_BLK, W) grid: one launch for every head
-        ex = "pallas_batched"
+                                  precision=precision, direction=direction)
+    kw = {}
+    if ex == "pallas":
+        kw["direction"] = direction
+        if vals.ndim == 3 or b.ndim == 3:
+            # native (H, N/N_BLK, W) grid: one launch for every head
+            ex = "pallas_batched"
     return _dispatch.dispatch("spmm", ex,
                               with_values(blocked, vals), b,
                               k_blk=blocked.k_blk, n_blk=n_blk,
-                              interpret=interpret, precision=precision)
+                              interpret=interpret, precision=precision, **kw)
 
 
 def _run_sddmm(impl, interpret, plan: ADPlan, q, k, *, precision=None):

@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .metrics import span
+
 __all__ = [
     "MEBCRS",
     "BlockedMEBCRS",
@@ -302,6 +304,7 @@ def window_skew(fmt) -> float:
 # ---------------------------------------------------------------------------
 
 
+@span("fs.from_coo")
 def from_coo(
     rows: np.ndarray,
     cols: np.ndarray,
@@ -323,7 +326,8 @@ def from_coo(
     the triplets come from an external producer where duplicates signal a
     corrupted stream rather than an incremental build.  ``check`` follows
     :func:`repro.core.validate.resolve_check` (``None`` → ambient level);
-    the constructed format is audited before it is returned.
+    the constructed format is audited before it is returned.  The build
+    runs under the host span ``fs.from_coo`` (:func:`.metrics.span`).
     """
     from . import validate as _validate
 

@@ -1,5 +1,6 @@
 """Redundancy metrics reproducing the paper's analysis artifacts, plus
-runtime robustness counters (DESIGN.md §15).
+the runtime's own tracing: counters, host spans and device-op tags
+(DESIGN.md §15).
 
 Paper metrics (host numpy, derived from the ME-BCRS structure alone, so
 exact, not sampled):
@@ -17,17 +18,28 @@ dispatch fallbacks, fp32 nonfinite re-runs — without a metrics server:
 latter land through ``jax.debug.callback`` at run time, so jitted
 quantization still counts), :func:`counters` snapshots,
 :func:`reset_counters` clears.
+
+Host spans and device-op tags put the program's own names on a profiler
+trace.  :func:`span` times a block on the host (a
+``jax.profiler.TraceAnnotation`` on the device trace's clock, plus a
+bounded in-memory record per name: :func:`spans`, :func:`reset_spans`);
+:func:`op_tag` stamps every XLA op lowered inside it with the frontend
+attribute ``flashsparse_op``, so its device ops can be found on the
+trace.  Program span and tag names start with ``fs.``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
+import time
 from functools import partial
-from typing import Dict, Optional
+from typing import TYPE_CHECKING, Dict, Optional
 
 import numpy as np
 
-from .format import MEBCRS
+if TYPE_CHECKING:
+    from .format import MEBCRS
 
 __all__ = [
     "zeros_in_nonzero_vectors",
@@ -38,6 +50,10 @@ __all__ = [
     "record_counter",
     "counters",
     "reset_counters",
+    "span",
+    "spans",
+    "reset_spans",
+    "op_tag",
 ]
 
 
@@ -81,6 +97,89 @@ def reset_counters(name: Optional[str] = None) -> None:
             _counters.clear()
         else:
             _counters.pop(name, None)
+
+
+# ------------------------------------------------- host spans, op tags --
+
+PREFIX = "fs."
+
+_spans: Dict[str, Dict] = {}
+_spans_lock = threading.Lock()
+_open = threading.local()       # this thread's stack of open span names
+
+
+def _check_name(name: str) -> None:
+    if not name.startswith(PREFIX):
+        raise ValueError(f"program span and tag names start with "
+                         f"{PREFIX!r}; got {name!r}")
+
+
+@contextlib.contextmanager
+def span(name: str):
+    """Time the enclosed block on the host as span ``name``.
+
+    The block runs under ``jax.profiler.TraceAnnotation(name)``, so a
+    profiler trace shows it on the host plane, on the device ops' clock.
+    The record of ``name`` (:func:`spans`) keeps the call count, the
+    total, longest and last duration in seconds, and the span open
+    around its last call on this thread (``parent``, ``None`` at top
+    level): one entry per name, whatever the number of calls.  Also a
+    decorator.
+    """
+    import jax
+
+    _check_name(name)
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    parent = stack[-1] if stack else None
+    stack.append(name)
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(name):
+            yield
+    finally:
+        dt = time.perf_counter() - t0
+        stack.pop()
+        with _spans_lock:
+            rec = _spans.get(name)
+            if rec is None:
+                rec = _spans[name] = {"count": 0, "total_s": 0.0,
+                                      "max_s": 0.0}
+            rec["count"] += 1
+            rec["total_s"] += dt
+            rec["max_s"] = max(rec["max_s"], dt)
+            rec["last_s"] = dt
+            rec["parent"] = parent
+
+
+def spans() -> Dict[str, Dict]:
+    """Snapshot of the span records: name -> ``{count, total_s, max_s,
+    last_s, parent}``."""
+    with _spans_lock:
+        return {k: dict(v) for k, v in _spans.items()}
+
+
+def reset_spans(name: Optional[str] = None) -> None:
+    """Clear one span record, or all of them (``name=None``)."""
+    with _spans_lock:
+        if name is None:
+            _spans.clear()
+        else:
+            _spans.pop(name, None)
+
+
+def op_tag(name: str):
+    """Context manager: every XLA op lowered inside it carries the
+    frontend attribute ``flashsparse_op="<name>"``, which fusions that
+    absorb a tagged op keep and a profiler trace prints with the op's
+    HLO text.  It acts while a function is traced and lowered; the
+    compiled program runs as it would untagged."""
+    from jax.experimental.xla_metadata import set_xla_metadata
+
+    _check_name(name)
+    return set_xla_metadata(flashsparse_op=name)
+
 
 # MMA operand shapes (paper Table 1): (m, n, k)
 MMA_SHAPES = {

@@ -72,11 +72,11 @@ def _resolve_interpret(interpret: bool | None) -> bool:
 
 
 def spmm(blocked, b_dense, *, n_blk: int = 128, interpret: bool | None = None,
-         precision: str | None = None):
+         precision: str | None = None, direction: str | None = None):
     """Fused gather-free SpMM (dense rows DMA'd in-kernel)."""
     return spmm_pallas(blocked, b_dense, n_blk=n_blk,
                        interpret=_resolve_interpret(interpret),
-                       precision=precision)
+                       precision=precision, direction=direction)
 
 
 def spmm_noncoalesced(blocked, b_dense, *, n_blk: int = 128,
@@ -107,12 +107,12 @@ def sddmm(blocked, q, k, *, f_blk: int = 128, interpret: bool | None = None,
 
 def spmm_balanced(blocked, b_dense, *, schedule=None, split_blk: int = 1,
                   n_blk: int = 128, interpret: bool | None = None,
-                  precision: str | None = None):
+                  precision: str | None = None, direction: str | None = None):
     """Block-parallel load-balanced SpMM (uniform-segment grid, §11)."""
     return spmm_pallas_balanced(blocked, b_dense, schedule=schedule,
                                 split_blk=split_blk, n_blk=n_blk,
                                 interpret=_resolve_interpret(interpret),
-                                precision=precision)
+                                precision=precision, direction=direction)
 
 
 def sddmm_balanced(blocked, q, k, *, schedule=None, split_blk: int = 1,
@@ -301,9 +301,9 @@ def _require_canonical(fmt, impl: str):
 
 
 def _spmm_pallas_adapter(fmt, b, *, k_blk=8, n_blk=128, interpret=None,
-                         precision=None):
+                         precision=None, direction=None):
     return spmm(_ensure_blocked(fmt, k_blk), b, n_blk=n_blk,
-                interpret=interpret, precision=precision)
+                interpret=interpret, precision=precision, direction=direction)
 
 
 def _spmm_staged_adapter(fmt, b, *, k_blk=8, n_blk=128, interpret=None,
@@ -339,10 +339,12 @@ def _sddmm_tuned_adapter(fmt, q, k, *, k_blk=8, f_blk=None, interpret=None,
 
 
 def _spmm_balanced_adapter(fmt, b, *, k_blk=8, n_blk=128, split_blk=1,
-                           schedule=None, interpret=None, precision=None):
+                           schedule=None, interpret=None, precision=None,
+                           direction=None):
     return spmm_balanced(_ensure_blocked(fmt, k_blk), b, schedule=schedule,
                          split_blk=split_blk, n_blk=n_blk,
-                         interpret=interpret, precision=precision)
+                         interpret=interpret, precision=precision,
+                         direction=direction)
 
 
 def _sddmm_balanced_adapter(fmt, q, k, *, k_blk=8, f_blk=128, split_blk=1,

@@ -59,6 +59,7 @@ __all__ = [
     "spmm_pallas_noncoalesced",
     "spmm_pallas_staged",
     "spmm_hbm_bytes",
+    "spmm_launch_counts",
 ]
 
 
@@ -245,10 +246,11 @@ def _spmm_kernel(steps_hbm, cols_hbm, scales_hbm, vals_hbm, b_hbm, o_hbm,
 @functools.partial(
     jax.jit,
     static_argnames=("num_windows", "k_blk", "n_blk", "interpret",
-                     "quantized", "double_buffer"),
+                     "quantized", "double_buffer", "direction"),
 )
 def _spmm_call(steps, cols, scales, vals3, b3, *, num_windows, k_blk, n_blk,
-               interpret, quantized=False, double_buffer=True):
+               interpret, quantized=False, double_buffer=True,
+               direction=None):
     """Launch :func:`_spmm_kernel`.
 
     ``steps`` ``(S, 5)`` int32 ``[lo, len, first, last, win]``
@@ -257,6 +259,12 @@ def _spmm_call(steps, cols, scales, vals3, b3, *, num_windows, k_blk, n_blk,
     ``quantized``); ``vals3`` ``(1 | H, NNZP, V)``; ``b3``
     ``(1 | H, K, N)``.  Returns fp32 ``(H, num_windows·V, N_pad)``; rows of
     windows no step stores are left unwritten.
+
+    ``direction`` (``"fwd"`` on A, ``"bwd"`` on Aᵀ) stamps the kernel's
+    ``kernel_metadata`` with it and :func:`spmm_launch_counts`, which
+    hold when the steps run every K-block once and store every window
+    once.  Sharded launches, whose steps cover one device's share, pass
+    none and carry no metadata.
     """
     require_fp32(interpret, vals3, b3, quantized=quantized)
     hv, _, v = vals3.shape
@@ -275,6 +283,16 @@ def _spmm_call(steps, cols, scales, vals3, b3, *, num_windows, k_blk, n_blk,
         num_windows=num_windows, vals_batched=hv > 1,
         b_batched=hb > 1, quantized=quantized, double_buffer=double_buffer)
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    metadata = None
+    if direction is not None:
+        if direction not in ("fwd", "bwd"):
+            raise ValueError(f"direction must be 'fwd' or 'bwd', got "
+                             f"{direction!r}")
+        counts = spmm_launch_counts(
+            nnzp=cols.shape[0], num_windows=num_windows, num_steps=ns,
+            heads=h, n_pad=n_pad, k_blk=k_blk, n_blk=n_blk, v=v)
+        metadata = {"op": "spmm", "dir": direction,
+                    **{k: str(c) for k, c in counts.items()}}
     return pl.pallas_call(
         kernel,
         grid=(h, n_pad // n_blk, ns),
@@ -292,13 +310,14 @@ def _spmm_call(steps, cols, scales, vals3, b3, *, num_windows, k_blk, n_blk,
             pltpu.SemaphoreType.DMA((2, 4)),
         ],
         interpret=interpret,
+        metadata=metadata,
     )(steps_table(steps), table(cols, cols_rows), table(scales),
       vals_t.reshape(hv * v, -1), b_tiles.reshape(-1, n_blk)
       ).reshape(h, num_windows * v, -1)
 
 
 def _spmm(blocked, b_dense, steps, *, n_blk, interpret, precision,
-          num_windows=None, double_buffer=True):
+          num_windows=None, double_buffer=True, direction=None):
     """Precision policy + launch + unpad: ``(M, N)`` or ``(H, M, N)`` in
     the (narrowed) dense operand's dtype."""
     vals, scales, quantized, b_dense = _apply_precision(
@@ -309,14 +328,14 @@ def _spmm(blocked, b_dense, steps, *, n_blk, interpret, precision,
         b_dense if bb else b_dense[None],
         num_windows=num_windows or blocked.num_windows, k_blk=blocked.k_blk,
         n_blk=n_blk, interpret=interpret, quantized=quantized,
-        double_buffer=double_buffer)
+        double_buffer=double_buffer, direction=direction)
     out = out[:, :blocked.shape[0], :b_dense.shape[-1]].astype(b_dense.dtype)
     return out if (vb or bb) else out[0]
 
 
 def spmm_pallas(blocked, b_dense: jax.Array, *, n_blk: int = 128,
-                interpret: bool = True, precision: str | None = None
-                ) -> jax.Array:
+                interpret: bool = True, precision: str | None = None,
+                direction: str | None = None) -> jax.Array:
     """Gather-free SpMM over a :class:`BlockedMEBCRS`, one grid step per
     output window.  Returns ``(M, N)`` in ``b`` dtype.  Dense rows are
     DMA'd HBM→VMEM inside the kernel (double-buffered); no staging buffer
@@ -330,9 +349,12 @@ def spmm_pallas(blocked, b_dense: jax.Array, *, n_blk: int = 128,
     ``b_dense`` ``(K, N)`` or ``(H, K, N)``; one ``(H, N/N_BLK, W)`` grid
     serves every head, and at least one batched operand returns
     ``(H, M, N)``, bitwise-equal to stacking H per-slice launches.
-    ``"int8"`` requires shared (2-D) pattern values."""
+    ``"int8"`` requires shared (2-D) pattern values.  ``direction``
+    (``"fwd"`` / ``"bwd"``) names the launch on the trace
+    (:func:`_spmm_call`)."""
     return _spmm(blocked, b_dense, window_steps(blocked.win_ptr),
-                 n_blk=n_blk, interpret=interpret, precision=precision)
+                 n_blk=n_blk, interpret=interpret, precision=precision,
+                 direction=direction)
 
 
 def spmm_pallas_noncoalesced(blocked, b_dense: jax.Array, *, n_blk: int = 128,
@@ -349,7 +371,8 @@ def spmm_pallas_noncoalesced(blocked, b_dense: jax.Array, *, n_blk: int = 128,
 def spmm_pallas_balanced(blocked, b_dense: jax.Array, *, schedule=None,
                          split_blk: int = 1, n_blk: int = 128,
                          interpret: bool = True,
-                         precision: str | None = None) -> jax.Array:
+                         precision: str | None = None,
+                         direction: str | None = None) -> jax.Array:
     """Block-parallel load-balanced SpMM over a :class:`BlockedMEBCRS`.
 
     ``schedule`` is the precomputed :class:`~repro.core.format.Schedule`;
@@ -360,13 +383,15 @@ def spmm_pallas_balanced(blocked, b_dense: jax.Array, *, schedule=None,
     ``(H, NNZP, V)``, ``b_dense`` ``(K, N)`` or ``(H, K, N)``; unbatched
     in → unbatched out.  Results are **bitwise-equal** to
     :func:`spmm_pallas` (same per-block contraction in the same ascending
-    order); only the work-to-grid mapping differs.
+    order); only the work-to-grid mapping differs.  ``direction`` as in
+    :func:`spmm_pallas`.
     """
     if schedule is None:
         schedule = blocked.schedule(split_blk)
     return _spmm(blocked, b_dense,
                  schedule_steps(schedule.seg_win, schedule.seg_meta),
-                 n_blk=n_blk, interpret=interpret, precision=precision)
+                 n_blk=n_blk, interpret=interpret, precision=precision,
+                 direction=direction)
 
 
 def spmm_pallas_staged(blocked, b_dense: jax.Array, *, n_blk: int = 128,
@@ -442,7 +467,8 @@ def spmm_hbm_bytes(blocked, n: int, *, n_blk: int = 128,
     (``128 / k_blk`` times the value bytes), dense operands are padded to
     128 lanes (:func:`.layout.lane_tile`), and every word is 4 bytes
     whatever the precision.  Use them to compare paths, not as the bytes
-    of a roofline share.
+    of a roofline share; :func:`spmm_launch_counts` counts what the
+    committed kernel starts.
     """
     v = blocked.vector_size
     nnzp = int(blocked.cols.shape[0])
@@ -472,3 +498,35 @@ def spmm_hbm_bytes(blocked, n: int, *, n_blk: int = 128,
         postpass = 2 * w * v * n_pad * 4
         return 3 * dense_pass + vals_bytes + meta_bytes + out_bytes + postpass
     raise ValueError(f"unknown impl {impl!r}")
+
+
+def spmm_launch_counts(*, nnzp: int, num_windows: int, num_steps: int,
+                       heads: int, n_pad: int, k_blk: int, n_blk: int,
+                       v: int = 8) -> dict:
+    """What one launch of the committed SpMM kernel starts, from its
+    static shapes: ``grid_steps``, ``dmas``, ``dma_bytes`` and
+    ``mxu_macs``.
+
+    Per head and per ``n_blk`` column tile of the ``n_pad``-wide dense
+    operand, each of the ``nnzp / k_blk`` K-blocks starts one value-chunk
+    DMA of ``v × width × 4`` bytes (``width`` the 128-lane chunks that
+    hold any block, :func:`.layout.chunks_per_block`) and ``k_blk``
+    dense-row DMAs of ``n_blk × 4`` bytes, and contracts ``v × k_blk ×
+    n_blk`` multiply-adds on the MXU; each of the ``num_windows`` windows
+    is stored once, ``v × n_blk × 4`` bytes.  This holds for step tables
+    that run every K-block once and store every window once
+    (:func:`.layout.window_steps`, a whole Schedule's
+    :func:`.layout.schedule_steps`).  The SMEM metadata refills
+    (:class:`.layout.SmemRows`) are left out.  :func:`spmm_hbm_bytes`
+    remains the narrow-layout model for comparing paths.
+    """
+    nb = nnzp // k_blk
+    tiles = heads * (n_pad // n_blk)
+    width = chunks_per_block(k_blk) * LANES
+    return {
+        "grid_steps": tiles * num_steps,
+        "dmas": tiles * (nb * (1 + k_blk) + num_windows),
+        "dma_bytes": 4 * tiles * (nb * (v * width + k_blk * n_blk)
+                                  + num_windows * v * n_blk),
+        "mxu_macs": tiles * nb * v * k_blk * n_blk,
+    }

@@ -156,9 +156,13 @@ def make_gnn_train_step(cfg, lr: float = 1e-2):
 
     ``step(params, mom, adj, x, labels, train_mask)`` — ``adj`` is an
     ``ADPlan`` or ``BlockedMEBCRS`` pytree, jit-traced like any operand.
+    Each call runs under the host span ``fs.step``
+    (:func:`repro.core.metrics.span`): on the host it lasts the dispatch,
+    not the device's work.
     """
     from repro.core import dispatch as sparse_dispatch
     from repro.core.autodiff import ADPlan
+    from repro.core.metrics import span
     from repro.models.gnn import gnn_loss
 
     sparse_dispatch.require("spmm", cfg.impl, differentiable=True)
@@ -182,7 +186,8 @@ def make_gnn_train_step(cfg, lr: float = 1e-2):
                 f"impl={cfg.impl!r} trains only through an ADPlan adjacency "
                 f"(build one with ad_plan(fmt, impl={cfg.impl!r})); got "
                 f"{type(adj).__name__}")
-        return jit_step(params, mom, adj, x, labels, train_mask)
+        with span("fs.step"):
+            return jit_step(params, mom, adj, x, labels, train_mask)
 
     return step
 

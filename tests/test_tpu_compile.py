@@ -16,17 +16,23 @@ imported: one process at a time may load the TPU library.
 
 from __future__ import annotations
 
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import ad_plan, from_coo
 from repro.core.format import BlockedMEBCRS, Schedule
 from repro.kernels.attention_pallas import attention_pallas
 from repro.kernels.sddmm_pallas import sddmm_pallas
-from repro.kernels.spmm_pallas import spmm_pallas, spmm_pallas_balanced
+from repro.kernels.spmm_pallas import (spmm_launch_counts, spmm_pallas,
+                                       spmm_pallas_balanced)
+from repro.models.gnn import GNNConfig, init_gcn, make_train_step
 
 # make_dataset("Amazon", scale=1.0) blocked at V=8, K_BLK=8: nodes, nonzero
 # vectors padded to whole K-blocks (NNZP), K-blocks (NB), and the same for
@@ -141,3 +147,32 @@ def test_fused_attention_compiles(one_chip):
         lambda a, q, k, v: attention_pallas(a, q, k, v, interpret=False),
         blocked, h, h, h)
     assert "tpu_custom_call" in text
+
+
+def test_gcn_step_kernels_carry_their_launch_counts(one_chip):
+    """Lowered for the chip, each SpMM kernel of a GCN step names its
+    direction and the work it starts in its ``kernel_metadata``.  The two
+    layers' forward launches share one lowered kernel.  Lowering is
+    enough."""
+    rng = np.random.default_rng(0)
+    n, nnz = 64, 300
+    plan = ad_plan(from_coo(rng.integers(0, n, nnz), rng.integers(0, n, nnz),
+                            np.ones(nnz, np.float32), (n, n)), impl="pallas")
+    cfg = GNNConfig(in_dim=16, hidden_dim=16, num_classes=4, num_layers=2,
+                    impl="pallas", interpret=False)
+    params = init_gcn(jax.random.key(0), cfg)
+    args = (params, params, plan, jnp.ones((n, 16)),
+            jnp.zeros((n,), jnp.int32), jnp.ones((n,)))
+    shapes = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), args)
+    text = jax.jit(make_train_step(cfg)).lower(*shapes).as_text(dialect="hlo")
+    metas = [json.loads(m) for m in
+             re.findall(r"kernel_metadata=(\{[^{}]*\})", text)]
+    assert sorted(m["dir"] for m in metas) == ["bwd", "fwd"]
+    for m in metas:
+        blocked = plan.fwd if m["dir"] == "fwd" else plan.bwd
+        want = spmm_launch_counts(
+            nnzp=blocked.cols.shape[0], num_windows=blocked.num_windows,
+            num_steps=blocked.num_windows, heads=1, n_pad=128, k_blk=8,
+            n_blk=128)
+        assert m == {"op": "spmm", "dir": m["dir"],
+                     **{k: str(v) for k, v in want.items()}}
