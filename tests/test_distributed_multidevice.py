@@ -5,29 +5,7 @@ so everything needing a multi-device mesh runs in a child process with
 ``--xla_force_host_platform_device_count`` pinned before jax import.
 """
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
-
-import pytest
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-
-
-def run_child(code: str, devices: int = 8, timeout: int = 600) -> str:
-    prog = (
-        "import os\n"
-        f"os.environ['XLA_FLAGS'] = "
-        f"'--xla_force_host_platform_device_count={devices}'\n"
-        + textwrap.dedent(code)
-    )
-    env = dict(os.environ, PYTHONPATH=SRC)
-    out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, timeout=timeout, env=env)
-    assert out.returncode == 0, f"child failed:\n{out.stdout}\n{out.stderr}"
-    return out.stdout
+from _child import run_child
 
 
 def test_param_sharding_rules():
@@ -65,7 +43,7 @@ def test_param_sharding_rules():
         expect = ("model", "data") if v % 2 == 0 else (None, "data")
         assert by_name["embed"][1] == expect, by_name["embed"]
         print("PARAM_RULES_OK")
-    """)
+    """, timeout=60)
     assert "PARAM_RULES_OK" in out
 
 
@@ -93,7 +71,7 @@ def test_cache_sharding_rules():
         spec1 = tuple(sh1["layers"]["k"].spec)
         assert spec1[2] in ("data", ("data", "model")), spec1
         print("CACHE_RULES_OK")
-    """)
+    """, timeout=60)
     assert "CACHE_RULES_OK" in out
 
 
@@ -119,7 +97,7 @@ def test_elastic_reshard_roundtrip():
                         jax.tree.leaves(sb["params"])):
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
         print("ELASTIC_OK")
-    """)
+    """, timeout=60)
     assert "ELASTIC_OK" in out
 
 
@@ -146,7 +124,7 @@ def test_tiny_dryrun_cell_compiles():
         assert r.bottleneck in ("compute", "memory", "collective")
         assert res.memory_stats["temp_bytes"] >= 0
         print("DRYRUN_OK", r.bottleneck)
-    """, devices=8)
+    """, devices=8, timeout=60)
     assert "DRYRUN_OK" in out
 
 
@@ -177,7 +155,7 @@ def test_moe_ep_matches_dense_path():
                                    rtol=2e-2, atol=2e-3)
         np.testing.assert_allclose(float(aux_ref), float(aux_ep), rtol=1e-3)
         print("MOE_EP_OK")
-    """)
+    """, timeout=60)
     assert "MOE_EP_OK" in out
 
 
@@ -197,5 +175,5 @@ def test_collective_matmul_matches_dot():
         np.testing.assert_allclose(np.asarray(y), np.asarray(x @ w),
                                    rtol=1e-4, atol=1e-4)
         print("CM_OK")
-    """)
+    """, timeout=60)
     assert "CM_OK" in out

@@ -5,7 +5,6 @@ impls, strictness modes, and (in child processes) sharded/overlap runs."""
 import os
 import subprocess
 import sys
-import textwrap
 
 import pytest
 
@@ -19,19 +18,7 @@ from repro.testing.faults import (  # noqa: E402
     run_fault_suite,
 )
 
-
-def run_child(code: str, devices: int = 8, timeout: int = 900) -> str:
-    prog = (
-        "import os\n"
-        f"os.environ['XLA_FLAGS'] = "
-        f"'--xla_force_host_platform_device_count={devices}'\n"
-        + textwrap.dedent(code)
-    )
-    env = dict(os.environ, PYTHONPATH=SRC)
-    out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, timeout=timeout, env=env)
-    assert out.returncode == 0, f"child failed:\n{out.stdout}\n{out.stderr}"
-    return out.stdout
+from _child import run_child  # noqa: E402
 
 
 # ---------------------------------------------------------------------------
@@ -91,7 +78,7 @@ def test_cli_entry_point():
         [sys.executable, "-m", "repro.testing.faults", "--op", "spmm",
          "--impl", "blocked", "--strict", "--fault", "oob_col"],
         capture_output=True, text=True,
-        env=dict(os.environ, PYTHONPATH=SRC), timeout=600)
+        env=dict(os.environ, PYTHONPATH=SRC), timeout=60)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "1/1 fault classes handled" in out.stdout
 
@@ -157,7 +144,7 @@ def test_sharded_validation_and_fallback_child():
     except Exception:
         pass
     print("SHARDED_FAULTS_OK")
-    """, devices=2)
+    """, devices=2, timeout=60)
 
 
 def test_overlap_validation_and_fallback_child():
@@ -192,4 +179,4 @@ def test_overlap_validation_and_fallback_child():
     assert any(issubclass(w.category, dispatch.FallbackWarning)
                for w in wlog)
     print("OVERLAP_FAULTS_OK")
-    """, devices=2)
+    """, devices=2, timeout=60)

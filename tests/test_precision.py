@@ -16,23 +16,18 @@ unsupported combinations, and the ladder holds on the edge cases that
 bit the fused kernels before (empty windows, ragged N, H ∈ {1, 4}).
 """
 
-import os
-import subprocess
-import sys
-import textwrap
-
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+from repro.core import block_format, from_dense
+from repro.core import dispatch as sparse_dispatch
+from repro.core.quantize import quantize_block_values, quantize_format
+from repro.kernels import ops
 
-from repro.core import block_format, from_dense  # noqa: E402
-from repro.core import dispatch as sparse_dispatch  # noqa: E402
-from repro.core.quantize import quantize_block_values, quantize_format  # noqa: E402
-from repro.kernels import ops  # noqa: E402
+from _child import run_child
 
 
 def random_sparse(rng, m, k, density):
@@ -378,15 +373,8 @@ def test_sharded_precision_ladder():
     np.testing.assert_allclose(out, ref, rtol=5e-2, atol=8e-2)
     print("sharded precision ladder OK")
     """
-    prog = ("import os\n"
-            "os.environ['XLA_FLAGS'] = "
-            "'--xla_force_host_platform_device_count=8'\n"
-            + textwrap.dedent(code))
-    env = dict(os.environ, PYTHONPATH=SRC)
-    out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, timeout=900, env=env)
-    assert out.returncode == 0, f"child failed:\n{out.stdout}\n{out.stderr}"
-    assert "sharded precision ladder OK" in out.stdout
+    out = run_child(code, devices=8, timeout=120)
+    assert "sharded precision ladder OK" in out
 
 
 @pytest.mark.parametrize("op,precision", [

@@ -6,18 +6,21 @@ Two tiers:
   needed): segment-coverage invariants, window alignment, ownership
   disjointness, padding inertness, and the balance floor the BENCH
   records enforce.
-* **Parity tests** run in child processes with
-  ``--xla_force_host_platform_device_count`` pinned before jax import
-  (the main pytest process must keep the single real CPU device),
-  asserting allclose (fp32) of sharded SpMM/SDDMM/attention — forward
-  and gradients — against the single-device ``pallas_balanced`` path
-  for device counts {1, 2, 4, 8} on standard and skewed matrices.
+* **Parity tests** run in child processes (``tests/_child.py``) with
+  8 forced host devices (the main pytest process must keep the single
+  real CPU device), asserting allclose (fp32) of sharded
+  SpMM/SDDMM/attention — forward and gradients — against the
+  single-device ``pallas_balanced`` path on standard and skewed
+  matrices.  Forward parity is one child: it computes each single-device
+  reference once, then checks the meshes 1x1, 2x1, 2x2 and 4x2 in turn
+  and prints one line per mesh, which its own parametrised case reads.
+
+The overlapped (``ppermute`` ring) path's tests are in
+``tests/test_sparse_shard_overlap*.py``.
 """
 
 import os
-import subprocess
 import sys
-import textwrap
 
 import numpy as np
 import pytest
@@ -32,19 +35,7 @@ from repro.distributed.sparse_shard import (  # noqa: E402
 )
 from repro.sparse.graphs import hub_row_graph  # noqa: E402
 
-
-def run_child(code: str, devices: int = 8, timeout: int = 900) -> str:
-    prog = (
-        "import os\n"
-        f"os.environ['XLA_FLAGS'] = "
-        f"'--xla_force_host_platform_device_count={devices}'\n"
-        + textwrap.dedent(code)
-    )
-    env = dict(os.environ, PYTHONPATH=SRC)
-    out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, timeout=timeout, env=env)
-    assert out.returncode == 0, f"child failed:\n{out.stdout}\n{out.stderr}"
-    return out.stdout
+from _child import assert_mesh_ok, run_child  # noqa: E402
 
 
 def _example_blocked(m=64, density=0.1, hub=True, seed=0, k_blk=8):
@@ -166,7 +157,10 @@ def test_all_empty_matrix_partitions():
 # Multi-device parity (child processes)
 # ---------------------------------------------------------------------------
 
+_MESHES = [(1, 1, 1), (2, 1, 2), (2, 2, 4), (4, 2, 8)]
+
 _PARITY = """
+    import traceback
     import numpy as np, jax, jax.numpy as jnp
     from repro.core import from_dense, block_format
     from repro.kernels import ops
@@ -174,8 +168,6 @@ _PARITY = """
     from repro.distributed.sparse_shard import (
         spmm_sharded, sddmm_sharded, attention_sharded)
 
-    data, model = {data}, {model}
-    mesh = make_host_mesh(data, model)
     rng = np.random.default_rng(0)
     mats = []
     for seed, hub in [(0, False), (1, True)]:
@@ -185,41 +177,58 @@ _PARITY = """
         if hub:
             a[5, :] = rng.standard_normal(m) * (rng.random(m) < 0.8)
         mats.append(a)
+    # operands and single-device references, drawn and computed once
+    cases = []
     for a in mats:
         m = a.shape[0]
         blocked = block_format(from_dense(a), 8)
         b = jnp.asarray(rng.standard_normal((m, 32)).astype(np.float32))
         q = jnp.asarray(rng.standard_normal((m, 16)).astype(np.float32))
         k = jnp.asarray(rng.standard_normal((m, 16)).astype(np.float32))
-        out = spmm_sharded(blocked, b, mesh=mesh)
-        ref = ops.spmm_balanced(blocked, b, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-        sd = sddmm_sharded(blocked, q, k, mesh=mesh)
-        sd_ref = ops.sddmm_balanced(blocked, q, k, interpret=True)
-        np.testing.assert_allclose(np.asarray(sd), np.asarray(sd_ref),
-                                   rtol=2e-5, atol=2e-5)
-        # batched heads (H=2): heads ride the model axis when it divides
         q3 = jnp.asarray(rng.standard_normal((2, m, 16)).astype(np.float32))
         v3 = jnp.asarray(rng.standard_normal((2, m, 16)).astype(np.float32))
-        att = attention_sharded(blocked, q3, k, v3, mesh=mesh)
-        att_ref = ops.attention_balanced(blocked, q3, k, v3, interpret=True)
-        np.testing.assert_allclose(np.asarray(att), np.asarray(att_ref),
+        b3 = jnp.stack([b, 2 * b])
+        refs = (ops.spmm_balanced(blocked, b, interpret=True),
+                ops.sddmm_balanced(blocked, q, k, interpret=True),
+                ops.attention_balanced(blocked, q3, k, v3, interpret=True),
+                ops.spmm_balanced(blocked, b3, interpret=True))
+        cases.append((blocked, b, q, k, q3, v3, b3, refs))
+
+    def close(out, ref):
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
-        out3 = spmm_sharded(blocked, jnp.stack([b, 2 * b]), mesh=mesh)
-        ref3 = ops.spmm_balanced(blocked, jnp.stack([b, 2 * b]),
-                                 interpret=True)
-        np.testing.assert_allclose(np.asarray(out3), np.asarray(ref3),
-                                   rtol=2e-5, atol=2e-5)
-    print("PARITY_OK", data, model)
+
+    for data, model in {meshes}:
+        try:
+            mesh = make_host_mesh(data, model)
+            for blocked, b, q, k, q3, v3, b3, refs in cases:
+                close(spmm_sharded(blocked, b, mesh=mesh), refs[0])
+                close(sddmm_sharded(blocked, q, k, mesh=mesh), refs[1])
+                # batched heads (H=2): heads ride the model axis when it
+                # divides
+                close(attention_sharded(blocked, q3, k, v3, mesh=mesh),
+                      refs[2])
+                close(spmm_sharded(blocked, b3, mesh=mesh), refs[3])
+            print("PARITY_OK", data, model, flush=True)
+        except Exception as e:
+            traceback.print_exc()
+            print("PARITY_FAIL", data, model, " ".join(str(e).split()),
+                  flush=True)
 """
 
 
-@pytest.mark.parametrize("data,model,devices",
-                         [(1, 1, 1), (2, 1, 2), (2, 2, 4), (4, 2, 8)])
-def test_sharded_parity_vs_balanced(data, model, devices):
-    out = run_child(_PARITY.format(data=data, model=model), devices=devices)
-    assert f"PARITY_OK {data} {model}" in out
+@pytest.fixture(scope="module")
+def parity_out():
+    """One 8-device child checks every mesh of ``_MESHES``."""
+    meshes = [(d, m) for d, m, _ in _MESHES]
+    return run_child(_PARITY.format(meshes=meshes), devices=8,
+                     timeout=420)
+
+
+@pytest.mark.parametrize("data,model,devices", _MESHES)
+def test_sharded_parity_vs_balanced(parity_out, data, model, devices):
+    assert data * model == devices
+    assert_mesh_ok(parity_out, "PARITY", data, model)
 
 
 def test_sharded_gradients_match_balanced():
@@ -276,7 +285,7 @@ def test_sharded_gradients_match_balanced():
         np.testing.assert_allclose(np.asarray(ga), np.asarray(ga_r),
                                    rtol=2e-4, atol=2e-4)
         print("GRADS_OK")
-    """, devices=8)
+    """, devices=8, timeout=240)
     assert "GRADS_OK" in out
 
 
@@ -302,7 +311,7 @@ def test_sharded_empty_and_registry_flags():
         sd = sddmm_sharded(blocked, b, b, mesh=mesh)
         assert not np.asarray(sd).any()
         print("EMPTY_OK")
-    """, devices=2)
+    """, devices=2, timeout=60)
     assert "EMPTY_OK" in out
 
 
@@ -336,5 +345,5 @@ def test_sharded_format_shardings_place_partition_on_data_axis():
         assert tuple(sparse_operand_pspec(
             mesh, batched=False, heads_over_model=True)) == ()
         print("SHARDINGS_OK")
-    """, devices=8)
+    """, devices=8, timeout=60)
     assert "SHARDINGS_OK" in out

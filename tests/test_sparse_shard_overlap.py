@@ -9,19 +9,22 @@ Two tiers, mirroring ``tests/test_sparse_shard.py``:
   store-only dummy batches when devices outnumber non-empty segments,
   agree with :func:`device_balance` on per-device totals, and clear the
   modeled makespan floor the BENCH records enforce.
-* **Parity tests** run in child processes with
-  ``--xla_force_host_platform_device_count`` pinned before jax import,
-  asserting allclose (fp32) of the double-buffered ``ppermute`` ring —
-  forward and gradients — against the bulk-psum ``pallas_sharded`` /
-  single-device ``pallas_balanced`` paths for device counts
-  {1, 2, 4, 8} × ``n_batches`` {1, 2, 4}, including empty-window and
-  ragged-N matrices, plus the bf16/int8 tolerance ladder.
+* **Multi-device tests** run in 8-device child processes
+  (``tests/_child.py``) on a 4x2 mesh: gradients of the double-buffered
+  ``ppermute`` ring allclose (fp32) to the single-device
+  ``pallas_balanced`` plan, and the bf16/int8 tolerance ladder.
+
+Forward parity of the ring against ``pallas_balanced`` (meshes 1x1,
+2x1, 2x2 and 4x2 × ``n_batches`` {1, 2, 4}, including empty-window and
+ragged-N matrices) is one child program, ``tests/_overlap_parity.py``,
+which checks several meshes per process.  Its cases are split over
+``tests/test_sparse_shard_overlap_parity_1x1_4x2.py`` and
+``tests/test_sparse_shard_overlap_parity_2x1_2x2.py`` so that
+``--dist loadfile`` runs the two halves on different workers.
 """
 
 import os
-import subprocess
 import sys
-import textwrap
 
 import numpy as np
 import pytest
@@ -37,19 +40,7 @@ from repro.distributed.sparse_shard import (  # noqa: E402
 )
 from repro.sparse.graphs import hub_row_graph  # noqa: E402
 
-
-def run_child(code: str, devices: int = 8, timeout: int = 900) -> str:
-    prog = (
-        "import os\n"
-        f"os.environ['XLA_FLAGS'] = "
-        f"'--xla_force_host_platform_device_count={devices}'\n"
-        + textwrap.dedent(code)
-    )
-    env = dict(os.environ, PYTHONPATH=SRC)
-    out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, timeout=timeout, env=env)
-    assert out.returncode == 0, f"child failed:\n{out.stdout}\n{out.stderr}"
-    return out.stdout
+from _child import run_child  # noqa: E402
 
 
 def _example_blocked(m=64, density=0.1, hub=True, seed=0, k_blk=8):
@@ -260,71 +251,8 @@ def test_autotune_v4_cache_discarded_with_one_warning(tmp_path, caplog):
 
 
 # ---------------------------------------------------------------------------
-# Multi-device parity (child processes)
+# Multi-device gradients and precision (child processes)
 # ---------------------------------------------------------------------------
-
-_PARITY = """
-    import numpy as np, jax, jax.numpy as jnp
-    from repro.core import from_dense, block_format
-    from repro.kernels import ops
-    from repro.launch.mesh import make_host_mesh
-    from repro.distributed.sparse_shard_overlap import (
-        attention_sharded_overlap, sddmm_sharded_overlap,
-        spmm_sharded_overlap)
-
-    data, model = {data}, {model}
-    mesh = make_host_mesh(data, model)
-    rng = np.random.default_rng(0)
-    mats = []
-    for seed, hub, m in [(0, False, 64), (1, True, 64), (2, False, 24)]:
-        a = ((rng.random((m, m)) < 0.1)
-             * rng.standard_normal((m, m))).astype(np.float32)
-        if hub:
-            a[5, :] = rng.standard_normal(m) * (rng.random(m) < 0.8)
-        if seed == 2:
-            a[:] = 0.0          # all-empty windows
-        mats.append(a)
-    for a in mats:
-        m = a.shape[0]
-        blocked = block_format(from_dense(a), 8)
-        # ragged N (not a multiple of n_blk) on purpose
-        b = jnp.asarray(rng.standard_normal((m, 20)).astype(np.float32))
-        ref = ops.spmm_balanced(blocked, b, interpret=True)
-        for nb in (1, 2, 4):
-            out = spmm_sharded_overlap(blocked, b, mesh=mesh, n_batches=nb)
-            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                       rtol=2e-5, atol=2e-5)
-        q = jnp.asarray(rng.standard_normal((m, 16)).astype(np.float32))
-        k = jnp.asarray(rng.standard_normal((m, 16)).astype(np.float32))
-        sd = sddmm_sharded_overlap(blocked, q, k, mesh=mesh, n_batches=2)
-        sd_ref = ops.sddmm_balanced(blocked, q, k, interpret=True)
-        np.testing.assert_allclose(np.asarray(sd), np.asarray(sd_ref),
-                                   rtol=2e-5, atol=2e-5)
-        # batched heads (H=2) through the window-aligned megakernel path
-        q3 = jnp.asarray(rng.standard_normal((2, m, 16)).astype(np.float32))
-        v3 = jnp.asarray(rng.standard_normal((2, m, 16)).astype(np.float32))
-        att = attention_sharded_overlap(blocked, q3, k, v3, mesh=mesh,
-                                        n_batches=2)
-        att_ref = ops.attention_balanced(blocked, q3, k, v3, interpret=True)
-        np.testing.assert_allclose(np.asarray(att), np.asarray(att_ref),
-                                   rtol=2e-5, atol=2e-5)
-        # stacked dense operand (H=2 SpMM)
-        out3 = spmm_sharded_overlap(blocked, jnp.stack([b, 2 * b]),
-                                    mesh=mesh, n_batches=2)
-        ref3 = ops.spmm_balanced(blocked, jnp.stack([b, 2 * b]),
-                                 interpret=True)
-        np.testing.assert_allclose(np.asarray(out3), np.asarray(ref3),
-                                   rtol=2e-5, atol=2e-5)
-    print("OVERLAP_PARITY_OK", data, model)
-"""
-
-
-@pytest.mark.parametrize("data,model,devices",
-                         [(1, 1, 1), (2, 1, 2), (2, 2, 4), (4, 2, 8)])
-def test_overlap_parity_vs_balanced(data, model, devices):
-    out = run_child(_PARITY.format(data=data, model=model), devices=devices)
-    assert f"OVERLAP_PARITY_OK {data} {model}" in out
-
 
 def test_overlap_gradients_match_sharded():
     """spmm_ad / sddmm_ad / attention_ad with impl=pallas_sharded_overlap:
@@ -384,7 +312,7 @@ def test_overlap_gradients_match_sharded():
         np.testing.assert_allclose(np.asarray(ga), np.asarray(ga_r),
                                    rtol=2e-4, atol=2e-4)
         print("OVERLAP_GRADS_OK")
-    """, devices=8)
+    """, devices=8, timeout=720)
     assert "OVERLAP_GRADS_OK" in out
 
 
@@ -425,5 +353,5 @@ def test_overlap_precision_ladder():
             precision="bf16"), np.float32)
         np.testing.assert_allclose(out, ref, rtol=5e-2, atol=8e-2)
         print("OVERLAP_LADDER_OK")
-    """, devices=8)
+    """, devices=8, timeout=180)
     assert "OVERLAP_LADDER_OK" in out
