@@ -658,13 +658,19 @@ def sddmm_ad(plan: ADPlan, q: jax.Array, k: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 
+def _attend(impl, interpret, plan: ADPlan, scores, v, scale):
+    """``softmax_sparse(scale · scores) @ V``: the staged composition's
+    softmax and SpMM."""
+    probs = sparse_softmax(plan.fwd, scores * scale)
+    return _spmm_ad(impl, interpret, plan, probs.astype(v.dtype), v)
+
+
 def _staged_attention(impl, interpret, plan: ADPlan, q, k, v, scale):
     """The 3-dispatch differentiable composition (scores through HBM).
     Serves as the XLA execution path, the fused kernel's recompute
     backward, and the parity/benchmark baseline."""
-    scores = _sddmm_ad(impl, interpret, plan, q, k)
-    probs = sparse_softmax(plan.fwd, scores * scale)
-    return _spmm_ad(impl, interpret, plan, probs.astype(v.dtype), v)
+    return _attend(impl, interpret, plan, _sddmm_ad(impl, interpret, plan,
+                                                    q, k), v, scale)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -712,11 +718,16 @@ def _attention_ad_bwd(impl, interpret, res, g):
     # schedule every layer's recompute early and keep all their
     # score-sized buffers live at once.
     q, k, v, scale, g = jax.lax.optimization_barrier((q, k, v, scale, g))
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_, s_: _staged_attention(impl, interpret, plan,
-                                                 q_, k_, v_, s_),
-        q, k, v, scale)
-    dq, dk, dv, ds = vjp(g)
+    # The recomputed scores carry the tag ``fs.attn_recompute``.  They are
+    # computed outside ``jax.vjp``, whose linear ops keep the tag context
+    # they were traced in and would hand it to the whole backward; the
+    # SDDMM's backward is its custom_vjp rule, called directly.
+    with op_tag("fs.attn_recompute"):
+        scores = _sddmm_ad(impl, interpret, plan, q, k)
+    _, vjp = jax.vjp(partial(_attend, impl, interpret, plan), scores, v,
+                     scale)
+    d_scores, dv, ds = vjp(g)
+    _, dq, dk = _sddmm_ad_bwd(impl, interpret, (plan, q, k), d_scores)
     return None, dq, dk, dv, ds
 
 

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from .format import BlockedMEBCRS
+from .metrics import op_tag
 
 __all__ = ["sparse_softmax"]
 
@@ -25,9 +26,11 @@ def sparse_softmax(blocked: BlockedMEBCRS, scores: jax.Array) -> jax.Array:
     — the reduction is per row per head.  Returns probabilities in the
     same layout; masked/padding entries are 0.
     """
-    if scores.ndim == 3:
-        return jax.vmap(_sparse_softmax_2d, in_axes=(None, 0))(blocked, scores)
-    return _sparse_softmax_2d(blocked, scores)
+    with op_tag("fs.sparse_softmax"):
+        if scores.ndim == 3:
+            return jax.vmap(_sparse_softmax_2d, in_axes=(None, 0))(blocked,
+                                                                   scores)
+        return _sparse_softmax_2d(blocked, scores)
 
 
 @jax.jit

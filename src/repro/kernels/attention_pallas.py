@@ -54,6 +54,7 @@ __all__ = [
     "attention_pallas_balanced",
     "attention_pallas_staged",
     "attention_hbm_bytes",
+    "attention_launch_counts",
 ]
 
 _NEG = float(jnp.finfo(jnp.float32).min)  # same sentinel as sparse_softmax
@@ -162,14 +163,21 @@ def _attn_kernel(steps_hbm, cols_hbm, mask_hbm, q_hbm, k_hbm, v_hbm, o_hbm,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_windows", "k_blk", "interpret"))
+    jax.jit, static_argnames=("num_windows", "k_blk", "interpret",
+                              "direction"))
 def _attn_call(steps, cols, mask, q3, k3, v3, *, num_windows, k_blk,
-               interpret):
+               interpret, direction=None):
     """Launch :func:`_attn_kernel` over ``steps`` (``(S, 5)``, see
     :func:`layout.window_steps`).  ``q3`` ``(1 | H, num_windows·V, D)``
     (scaled, window rows padded); ``k3``/``v3`` ``(1 | H, Mc, D | DV)``.
     Returns fp32 ``(H, num_windows·V, DV_pad)``; rows of windows no step
-    stores are left unwritten."""
+    stores are left unwritten.
+
+    ``direction`` (``"fwd"``: the kernel samples A's pattern) stamps the
+    kernel's ``kernel_metadata`` with it and
+    :func:`attention_launch_counts`, which hold when the steps run every
+    K-block once and store every window once.  Sharded launches, whose
+    steps cover one device's share, pass none and carry no metadata."""
     require_fp32(interpret, q3, k3, v3)
     _, v = mask.shape
     hq, _, d = q3.shape
@@ -187,6 +195,14 @@ def _attn_call(steps, cols, mask, q3, k3, v3, *, num_windows, k_blk,
         v_batched=hv > 1)
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     dp, dvp = q3.shape[-1], v3.shape[-1]
+    metadata = None
+    if direction is not None:
+        counts = attention_launch_counts(
+            nnzp=cols.shape[0], num_windows=num_windows,
+            num_steps=steps.shape[0], heads=h, d_pad=dp, dv_pad=dvp,
+            k_blk=k_blk, v=v)
+        metadata = {"op": "attention", "dir": direction,
+                    **{k: str(c) for k, c in counts.items()}}
     return pl.pallas_call(
         kernel,
         grid=(h, steps.shape[0]),
@@ -207,6 +223,7 @@ def _attn_call(steps, cols, mask, q3, k3, v3, *, num_windows, k_blk,
             pltpu.SemaphoreType.DMA((2, 5)),
         ],
         interpret=interpret,
+        metadata=metadata,
     )(steps_table(steps), table(cols, cols_rows),
       vectors_on_lanes(mask, k_blk), q3.reshape(-1, dp),
       k3.reshape(-1, dp), v3.reshape(-1, dvp)).reshape(h, num_windows * v,
@@ -230,7 +247,8 @@ def _attention(blocked, q, k, v, steps, *, scale, precision, interpret,
                      ).at[:, : q3.shape[1], :].set(q3)
     out = _attn_call(steps, blocked.cols, blocked.mask, qpad,
                      k if kb else k[None], v if vb else v[None],
-                     num_windows=w, k_blk=blocked.k_blk, interpret=interpret)
+                     num_windows=w, k_blk=blocked.k_blk, interpret=interpret,
+                     direction="fwd")
     out = out[:, :m, : v.shape[-1]].astype(v.dtype)
     return out if (qb or kb or vb) else out[0]
 
@@ -367,3 +385,34 @@ def attention_hbm_bytes(blocked, d: int, dv: int, *, h: int = 1,
                                      value_bytes=value_bytes))
         return h * per_head
     raise ValueError(f"unknown impl {impl!r}")
+
+
+def attention_launch_counts(*, nnzp: int, num_windows: int, num_steps: int,
+                            heads: int, d_pad: int, dv_pad: int, k_blk: int,
+                            v: int = 8) -> dict:
+    """What one launch of the committed attention kernel starts, from its
+    static shapes: ``grid_steps``, ``dmas``, ``dma_bytes`` and
+    ``mxu_macs``.
+
+    Per head, each of the ``num_steps`` steps copies its window's
+    ``(v, d_pad)`` Q tile; each of the ``nnzp / k_blk`` K-blocks starts
+    one mask-chunk DMA of ``v × width × 4`` bytes (``width`` as in
+    :func:`.spmm_pallas.spmm_launch_counts`) and ``k_blk`` K-row and
+    ``k_blk`` V-row DMAs of ``d_pad × 4`` and ``dv_pad × 4`` bytes, and
+    contracts ``v × k_blk × (d_pad + dv_pad)`` multiply-adds on the MXU
+    (scores, then probabilities against V); each of the ``num_windows``
+    windows is stored once, ``v × dv_pad × 4`` bytes.  This holds for
+    step tables that run every K-block once and store every window once;
+    the SMEM metadata refills are left out.
+    """
+    nb = nnzp // k_blk
+    width = chunks_per_block(k_blk) * LANES
+    return {
+        "grid_steps": heads * num_steps,
+        "dmas": heads * (num_steps + nb * (1 + 2 * k_blk) + num_windows),
+        "dma_bytes": 4 * heads * (num_steps * v * d_pad
+                                  + nb * (v * width
+                                          + k_blk * (d_pad + dv_pad))
+                                  + num_windows * v * dv_pad),
+        "mxu_macs": heads * nb * v * k_blk * (d_pad + dv_pad),
+    }

@@ -35,6 +35,7 @@ __all__ = [
     "sddmm_pallas",
     "sddmm_pallas_balanced",
     "sddmm_hbm_bytes",
+    "sddmm_launch_counts",
 ]
 
 
@@ -142,15 +143,21 @@ def _sddmm_kernel(chunk0_ref, cols_hbm, wp_hbm, bw_hbm, q_hbm, k_hbm,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("k_blk", "f_blk", "num_chunks", "interpret"))
+    jax.jit, static_argnames=("k_blk", "f_blk", "num_chunks", "interpret",
+                              "direction"))
 def _sddmm_call(chunk0, cols, win_ptr, block_win, mask, q3, k3, *, k_blk,
-                f_blk, num_chunks, interpret):
+                f_blk, num_chunks, interpret, direction=None):
     """Launch :func:`_sddmm_kernel` over ``num_chunks`` chunks of 128
     vectors starting at chunk ``chunk0`` (a traced ``(1,)`` int32).
 
     ``mask`` ``(NNZP, V)``; ``q3`` ``(1 | H, W·V, F)`` (window rows padded);
     ``k3`` ``(1 | H, Mc, F)``.  Returns fp32 ``(H, V, num_chunks·128)``:
     the sampled scores with the vector index on lanes.
+
+    ``direction`` (``"fwd"``: the kernel samples A's pattern) stamps the
+    kernel's ``kernel_metadata`` with it and :func:`sddmm_launch_counts`,
+    for a launch whose chunks cover the whole pattern.  Launches over a
+    chunk range (a device's share) pass none and carry no metadata.
     """
     require_fp32(interpret, q3, k3)
     nnzp, v = mask.shape
@@ -171,6 +178,13 @@ def _sddmm_call(chunk0, cols, win_ptr, block_win, mask, q3, k3, *, k_blk,
         num_windows=qrows // v, k_rows=k_rows, q_batched=hq > 1,
         k_batched=hk > 1)
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    metadata = None
+    if direction is not None:
+        counts = sddmm_launch_counts(
+            nnzp=nnzp, num_windows=qrows // v, num_chunks=num_chunks,
+            heads=h, f_pad=q3.shape[-1], f_blk=f_blk, v=v)
+        metadata = {"op": "sddmm", "dir": direction,
+                    **{k: str(c) for k, c in counts.items()}}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(h, num_chunks, nf),
@@ -192,6 +206,7 @@ def _sddmm_call(chunk0, cols, win_ptr, block_win, mask, q3, k3, *, k_blk,
         out_shape=jax.ShapeDtypeStruct((h, v, num_chunks * LANES),
                                        jnp.float32),
         interpret=interpret,
+        metadata=metadata,
     )(chunk0, table(cols, num_chunks + CACHE_ROWS), table(win_ptr),
       table(block_win), q_tiles.reshape(-1, f_blk),
       k_tiles.reshape(-1, f_blk), mask_t)
@@ -225,7 +240,8 @@ def _sddmm(blocked, q, k, *, f_blk, interpret, precision, chunk0=None,
     out = _sddmm_call(chunk0, blocked.cols, blocked.win_ptr,
                       blocked.block_win, blocked.mask, qpad, k3,
                       k_blk=blocked.k_blk, f_blk=f_blk,
-                      num_chunks=num_chunks, interpret=interpret)
+                      num_chunks=num_chunks, interpret=interpret,
+                      direction=None if ranged else "fwd")
     out = jnp.swapaxes(out, 1, 2)                        # (H, C·128, V)
     if ranged:
         full = jnp.zeros((out.shape[0], nnzp + num_chunks * LANES, v),
@@ -320,3 +336,38 @@ def sddmm_hbm_bytes(blocked, f: int, *, f_blk: int = 128,
         postpass = 2 * nnzp * v * 4
         return 3 * k_pass + q_bytes + mask_bytes + meta_bytes + out_bytes + postpass
     raise ValueError(f"unknown impl {impl!r}")
+
+
+def sddmm_launch_counts(*, nnzp: int, num_windows: int, num_chunks: int,
+                        heads: int, f_pad: int, f_blk: int,
+                        v: int = 8) -> dict:
+    """What one launch of the committed SDDMM kernel over the whole
+    pattern starts, from its static shapes: ``grid_steps``, ``dmas``,
+    ``dma_bytes`` and ``mxu_macs``.
+
+    The grid runs ``heads × num_chunks × (f_pad / f_blk)`` steps.  Per
+    head and chunk of 128 vectors the kernel DMAs the 128 sampled K rows
+    at every feature tile (``f_blk × 4`` bytes each), and the pipeline
+    writes back the chunk's ``(v, 128)`` output block and copies in its
+    ``(v, 128)`` mask block, which it keeps across heads where the launch
+    has one chunk.  Per head and feature tile, each window
+    that meets a chunk costs one ``(v, f_blk)`` Q-tile DMA and
+    ``v × 128 × f_blk`` MXU multiply-adds.  Which windows meet which
+    chunk is data, not a shape: the count takes every window as nonempty
+    and no window as ending on a chunk boundary, ``num_windows +
+    num_chunks - 1`` meetings.  That bounds the Q tiles from above and
+    is exact on such patterns; each empty window and each window end on
+    an inner chunk boundary takes one meeting off.  The SMEM metadata
+    refills are left out.
+    """
+    nf = f_pad // f_blk
+    tiles = heads * nf * (num_windows + num_chunks - 1)
+    chunks = heads * num_chunks
+    blocks = chunks + (chunks if num_chunks > 1 else 1)   # outputs, masks
+    return {
+        "grid_steps": chunks * nf,
+        "dmas": chunks * nf * LANES + blocks + tiles,
+        "dma_bytes": 4 * (chunks * nf * LANES * f_blk + blocks * v * LANES
+                          + tiles * v * f_blk),
+        "mxu_macs": tiles * v * LANES * f_blk,
+    }

@@ -156,3 +156,81 @@ def test_training_reduces_loss(model):
         params, mom, loss, acc = step(params, mom, adj, x, labels, mask)
         losses.append(float(loss))
     assert losses[-1] < 0.5 * losses[0], losses[::30]
+
+
+def agnn_edge_reference(params, rows, cols, n, x, labels, mask):
+    """AGNN's loss over a de-duplicated edge list, in plain ``jax.numpy``:
+    the equations of the benchmark's plain reference (cosine over each
+    edge's two rows with the norm floored at 1e-6, a shift-free softmax
+    over each row's edges, aggregation by segment sum), independent of
+    the sparse format and the kernels."""
+    def edge_sum(e):
+        return jax.ops.segment_sum(e, rows, num_segments=n)
+
+    h = jax.nn.relu(x @ params["w_in"])
+    for beta in params["beta"]:
+        hn = h / jnp.maximum(jnp.linalg.norm(h, axis=-1, keepdims=True), 1e-6)
+        s = beta * jnp.sum(hn[rows] * hn[cols], axis=1)
+        row_max = jax.lax.stop_gradient(
+            jax.ops.segment_max(s, rows, num_segments=n))
+        e = jnp.exp(s - row_max[rows])
+        p = e / jnp.maximum(edge_sum(e), 1e-20)[rows]
+        h = edge_sum(p[:, None] * h[cols])
+    logp = jax.nn.log_softmax(h @ params["w_out"], axis=-1)
+    nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
+    return jnp.sum(nll * mask) / jnp.sum(mask)
+
+
+def test_agnn_pallas_step_matches_an_edge_list_reference():
+    """The AGNN train step through the Pallas path (fused attention
+    forward, recompute backward; interpret mode) against the edge-list
+    reference in float64, on seeded random weights and learned betas, on
+    a graph with a hub column and an empty row."""
+    from repro.core.autodiff import ad_plan
+    from repro.core.format import from_coo
+
+    n, rng = 40, np.random.default_rng(11)
+    rows = rng.integers(0, n, 160)
+    cols = rng.integers(0, n, 160)
+    rows = np.concatenate([rows, np.arange(0, n, 2)])   # hub column 7
+    cols = np.concatenate([cols, np.full(n // 2, 7)])
+    keep = rows != 13                                    # empty row 13
+    edges = np.unique(np.stack([rows[keep], cols[keep]], 1), axis=0)
+    rows, cols = edges[:, 0], edges[:, 1]
+    plan = ad_plan(from_coo(rows, cols, np.ones(rows.size, np.float32),
+                            (n, n)), impl="pallas")
+    cfg = GNNConfig(model="agnn", in_dim=8, hidden_dim=32, num_classes=4,
+                    num_layers=2, impl="pallas", interpret=True)
+    params = init_agnn(jax.random.key(5), cfg)
+    params["beta"] = [jnp.float32(b) for b in 1 + rng.standard_normal(2)]
+    x = jax.random.normal(jax.random.key(6), (n, 8))
+    labels = jax.random.randint(jax.random.key(7), (n,), 0, 4)
+    mask = (jnp.arange(n) % 3 != 0).astype(jnp.float32)
+    mom = jax.tree.map(jnp.zeros_like, params)
+    _, grad, loss, _ = make_train_step(cfg)(params, mom, plan, x, labels,
+                                            mask)
+
+    cpu = jax.devices("cpu")[0]
+    with jax.enable_x64(True):
+        wide = jax.tree.map(
+            lambda a: jax.device_put(np.asarray(a, np.float64), cpu),
+            (params, x))
+        ref_loss, ref_grad = jax.value_and_grad(agnn_edge_reference)(
+            wide[0], rows, cols, n, wide[1], np.asarray(labels),
+            np.asarray(mask, np.float64))
+        ref_grad = jax.tree.map(np.asarray, ref_grad)
+    # float32 rounds the loss, a mean of 26 terms, by about 1e-7
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
+    for (path, want), got in zip(
+            jax.tree_util.tree_leaves_with_path(ref_grad),
+            jax.tree.leaves(grad)):
+        name = jax.tree_util.keystr(path)
+        err = (np.max(np.abs(np.asarray(got, np.float64) - want))
+               / np.max(np.abs(want)))
+        # A weight's gradient sums float32 products over at most a few
+        # dozen edges and nodes: about 3e-7 of its largest entry.  A
+        # beta's gradient is a sum over every edge of terms that cancel
+        # row by row, so float32 rounds it relative to the terms, up to
+        # 1e-5 of the (smaller) result.
+        tol = 1e-4 if name.startswith("['beta']") else 1e-5
+        assert err <= tol, (name, err)

@@ -28,11 +28,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import ad_plan, from_coo
 from repro.core.format import BlockedMEBCRS, Schedule
-from repro.kernels.attention_pallas import attention_pallas
-from repro.kernels.sddmm_pallas import sddmm_pallas
+from repro.kernels.attention_pallas import (attention_launch_counts,
+                                            attention_pallas)
+from repro.kernels.sddmm_pallas import sddmm_launch_counts, sddmm_pallas
 from repro.kernels.spmm_pallas import (spmm_launch_counts, spmm_pallas,
                                        spmm_pallas_balanced)
-from repro.models.gnn import GNNConfig, init_gcn, make_train_step
+from repro.models.gnn import (GNNConfig, init_agnn, init_gcn,
+                              make_train_step)
 
 # make_dataset("Amazon", scale=1.0) blocked at V=8, K_BLK=8: nodes, nonzero
 # vectors padded to whole K-blocks (NNZP), K-blocks (NB), and the same for
@@ -176,3 +178,43 @@ def test_gcn_step_kernels_carry_their_launch_counts(one_chip):
             n_blk=128)
         assert m == {"op": "spmm", "dir": m["dir"],
                      **{k: str(v) for k, v in want.items()}}
+
+
+def test_agnn_step_kernels_carry_their_launch_counts(one_chip):
+    """Lowered for the chip, an AGNN step's fused attention kernel and its
+    SDDMM kernels (the recomputed scores and the probabilities' gradient)
+    carry ``kernel_metadata`` with their launch counts, and the step
+    carries the recompute's and the softmax's tags."""
+    rng = np.random.default_rng(1)
+    n, nnz, width = 64, 300, AGNN_WIDTH
+    plan = ad_plan(from_coo(rng.integers(0, n, nnz), rng.integers(0, n, nnz),
+                            np.ones(nnz, np.float32), (n, n)), impl="pallas")
+    cfg = GNNConfig(model="agnn", in_dim=16, hidden_dim=width,
+                    num_classes=4, num_layers=1, impl="pallas",
+                    interpret=False)
+    params = init_agnn(jax.random.key(0), cfg)
+    args = (params, params, plan, jnp.ones((n, 16)),
+            jnp.zeros((n,), jnp.int32), jnp.ones((n,)))
+    shapes = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), args)
+    text = jax.jit(make_train_step(cfg)).lower(*shapes).as_text(dialect="hlo")
+    metas = [json.loads(m) for m in
+             re.findall(r"kernel_metadata=(\{[^{}]*\})", text)]
+    fwd = plan.fwd
+    nnzp = fwd.cols.shape[0]
+    want = {
+        "attention": attention_launch_counts(
+            nnzp=nnzp, num_windows=fwd.num_windows,
+            num_steps=fwd.num_windows, heads=1, d_pad=128, dv_pad=128,
+            k_blk=8),
+        "sddmm": sddmm_launch_counts(
+            nnzp=nnzp, num_windows=fwd.num_windows,
+            num_chunks=-(-nnzp // 128), heads=1, f_pad=128, f_blk=128),
+    }
+    # launches of one signature share a lowered kernel
+    assert {m["op"] for m in metas} == {"attention", "sddmm", "spmm"}
+    for m in metas:
+        if m["op"] in want:
+            assert m == {"op": m["op"], "dir": "fwd",
+                         **{k: str(v) for k, v in want[m["op"]].items()}}
+    for tag in ("fs.attn_recompute", "fs.sparse_softmax"):
+        assert f'flashsparse_op="{tag}"' in text
