@@ -19,12 +19,31 @@ precompute: :func:`ad_plan` builds an :class:`ADPlan` carrying
   * ``fwd``  — A as a :class:`BlockedMEBCRS` (the forward layout),
   * ``bwd``  — Aᵀ blocked (the transpose-SpMM layout; ``MEBCRS.transpose``
     is memoized on the canonical format instance),
-  * ``perm`` — a gather map re-laying ``fwd``-layout values into
-    ``bwd``-layout, so value rebinding (the live ``vals`` residual for dB,
-    the upstream cotangent for dK) is one ``jnp.take``,
+  * the **re-layout map** that re-lays ``fwd``-layout values into
+    ``bwd`` layout, so value rebinding (the live ``vals`` residual for
+    dB, the upstream cotangent for dK) is a gather of one value per
+    Aᵀ vector, not one per padded slot (:meth:`ADPlan.transpose_vals`),
 
-plus the tile parameters each direction runs with.  The plan is a pytree:
-pass it through ``jit``/``grad``/``shard_map`` like the format itself.
+plus the tile parameters each direction runs with.
+
+The re-layout map is built per *vector* of Aᵀ (one row of a ``V×V`` tile
+of A, ``V`` slots).  Level ``j`` holds, for every vector, the index of
+its ``j``-th real entry in the ``fwd`` values read slot-major, ``(V,
+NNZP)`` as the kernels lay them out (``vec_idx``, int32), and that
+entry's slot (``vec_row``, int8; ``V`` marks a vector with no ``j``-th
+entry).  Level 0 is always kept; level ``j`` only while at least 1/8 of
+the non-empty vectors have more than ``j`` entries, so no level gathers
+mostly padding and a dense-tile pattern keeps at most ``V`` levels (one
+gather per slot, as a per-slot map would).  Entries past the kept levels
+are the **overflow**: one compact gather ``ovf_src`` and a unique,
+sorted scatter to the flat ``bwd`` slots ``ovf_dst``.  Graphs with about
+one nonzero per tile (Amazon, DD) keep one level and overflow a few
+dozen entries.  The plan build records the counters
+``fs.ad_plan.relayout_levels`` (levels kept) and
+``fs.ad_plan.relayout_overflow`` (entries left to the scatter).
+
+The plan is a pytree: pass it through ``jit``/``grad``/``shard_map``
+like the format itself.
 ``impl="pallas_tuned"`` resolves the autotuner **at plan-build time**
 (fwd, transpose and SDDMM directions tuned independently, the SDDMM
 ``k_blk`` pinned to the forward layout), so the traced computation never
@@ -59,7 +78,7 @@ import numpy as np
 
 from . import dispatch as _dispatch
 from .format import MEBCRS, BlockedMEBCRS, Schedule, block_format
-from .metrics import op_tag, span
+from .metrics import op_tag, record_counter, span
 from .sddmm import with_values
 from .softmax import sparse_softmax
 
@@ -73,7 +92,11 @@ class ADPlan:
 
     fwd: BlockedMEBCRS    # A, forward layout
     bwd: BlockedMEBCRS    # Aᵀ, transpose-SpMM layout (vals = re-laid A vals)
-    perm: jax.Array       # (NNZP_T, V) flat indices into fwd-layout vals
+    # Re-layout map (module docstring): level after level, per Aᵀ vector
+    vec_idx: jax.Array    # (L·NNZP_T,) int32 slot-major index into fwd vals
+    vec_row: jax.Array    # (L·NNZP_T,) int8 slot of that entry, V = none
+    ovf_src: jax.Array    # (n_ovf,) int32 the same, for entries past level L
+    ovf_dst: jax.Array    # (n_ovf,) int32 flat bwd slots, sorted, unique
     impl: str             # impl the tile parameters below were chosen for
     n_blk: int            # forward SpMM column tile
     n_blk_t: int          # transpose-SpMM (dB / dK) column tile
@@ -129,26 +152,41 @@ class ADPlan:
         """Re-lay ``fwd``-layout values (NNZP, V) into ``bwd`` layout;
         a leading head dim (H, NNZP, V) is re-laid per head.
 
-        Pure gather: sources are exclusively mask-true ``fwd`` entries and
-        padding targets are zeroed, so junk in masked-off input positions
-        never leaks into the transpose-SpMM.  ``perm`` is in bounds by
-        construction, so the gather clamps instead of filling: the TPU
-        compiler takes minutes over a program with several fill-mode
-        gathers of this size, and seconds with clamping ones.  Its ops
-        carry the tag ``fs.transpose_vals`` (:func:`.metrics.op_tag`).
+        One gather of a value per Aᵀ vector and kept level (``vec_idx``),
+        expanded onto the vector's ``V`` slots by a ``where`` against the
+        entry's slot (``vec_row``), then a unique scatter of the overflow
+        entries.  Sources are exclusively mask-true ``fwd`` entries and
+        every other slot is a literal zero, so junk (NaN included) in
+        masked-off input positions never leaks into the transpose-SpMM.
+        The indices are in bounds by construction, so the gathers clamp
+        instead of filling: the TPU compiler takes minutes over a program
+        with several fill-mode gathers of this size, and seconds with
+        clamping ones.  Every op carries the tag ``fs.transpose_vals``
+        (:func:`.metrics.op_tag`).
         """
         with op_tag("fs.transpose_vals"):
-            perm = self.perm.reshape(-1)
-            if vals.ndim == 3:
-                flat = jnp.take(vals.reshape(vals.shape[0], -1), perm,
-                                axis=1, mode="clip")
-                return (flat.reshape((vals.shape[0],) + self.bwd.vals.shape)
-                        * self.bwd.mask)
-            flat = jnp.take(vals.reshape(-1), perm, axis=0, mode="clip")
-            return flat.reshape(self.bwd.vals.shape) * self.bwd.mask
+            lead = vals.shape[:-2]                     # () or (H,)
+            # slot-major, as the kernels lay values out (vectors_on_lanes):
+            # a row-major flatten would copy (NNZP, V) padded to 128 lanes
+            flat = jnp.swapaxes(vals, -1, -2).reshape(lead + (-1,))
+            nvec, v = self.bwd.mask.shape
+            got = jnp.take(flat, self.vec_idx, axis=-1, mode="clip")
+            # expanded slot-major too, (..., V, NNZP_T)
+            slots = jnp.arange(v, dtype=self.vec_row.dtype)[:, None]
+            out = jnp.zeros(lead + (v, nvec), vals.dtype)
+            for lo in range(0, self.vec_idx.shape[0], nvec):  # each level
+                out = jnp.where(self.vec_row[lo:lo + nvec] == slots,
+                                got[..., None, lo:lo + nvec], out)
+            out = jnp.swapaxes(out, -1, -2)
+            if self.ovf_src.shape[0]:
+                more = jnp.take(flat, self.ovf_src, axis=-1, mode="clip")
+                out = out.at[..., self.ovf_dst // v, self.ovf_dst % v].set(
+                    more, indices_are_sorted=True, unique_indices=True)
+            return out
 
     def tree_flatten(self):
-        return ((self.fwd, self.bwd, self.perm, self.fwd_sched,
+        return ((self.fwd, self.bwd, self.vec_idx, self.vec_row,
+                 self.ovf_src, self.ovf_dst, self.fwd_sched,
                  self.bwd_sched, self.fwd_part, self.bwd_part,
                  self.fwd_part_wa),
                 (self.impl, self.n_blk, self.n_blk_t, self.f_blk, self.mesh,
@@ -157,11 +195,12 @@ class ADPlan:
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        (fwd, bwd, perm, fwd_sched, bwd_sched, fwd_part, bwd_part,
-         fwd_part_wa) = leaves
+        (fwd, bwd, vec_idx, vec_row, ovf_src, ovf_dst, fwd_sched, bwd_sched,
+         fwd_part, bwd_part, fwd_part_wa) = leaves
         (impl, n_blk, n_blk_t, f_blk, mesh, precision, overlap_batches,
          guard_nonfinite) = aux
-        return cls(fwd=fwd, bwd=bwd, perm=perm, impl=impl, n_blk=n_blk,
+        return cls(fwd=fwd, bwd=bwd, vec_idx=vec_idx, vec_row=vec_row,
+                   ovf_src=ovf_src, ovf_dst=ovf_dst, impl=impl, n_blk=n_blk,
                    n_blk_t=n_blk_t, f_blk=f_blk, fwd_sched=fwd_sched,
                    bwd_sched=bwd_sched, fwd_part=fwd_part,
                    bwd_part=bwd_part, fwd_part_wa=fwd_part_wa, mesh=mesh,
@@ -169,11 +208,12 @@ class ADPlan:
                    guard_nonfinite=guard_nonfinite)
 
 
-def _blocked_perm(blocked_a: BlockedMEBCRS,
-                  blocked_t: BlockedMEBCRS) -> np.ndarray:
-    """Gather map: ``perm[t', r']`` = flat index into ``blocked_a`` vals of
-    the matrix element stored at ``blocked_t`` entry (t', r'); 0 where the
-    target entry is padding/masked-off (zeroed by the mask multiply)."""
+def _relayout_map(blocked_a: BlockedMEBCRS, blocked_t: BlockedMEBCRS
+                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """The re-layout map (module docstring) from ``blocked_a`` (A) values
+    to ``blocked_t`` (Aᵀ) layout: ``(vec_idx, vec_row, ovf_src,
+    ovf_dst)``.  Records the counters ``fs.ad_plan.relayout_levels`` and
+    ``fs.ad_plan.relayout_overflow``."""
     v = blocked_a.vector_size
     _, k = blocked_a.shape
 
@@ -183,10 +223,10 @@ def _blocked_perm(blocked_a: BlockedMEBCRS,
     key_a = rows_a.astype(np.int64) * k + np.asarray(blocked_a.cols)[ta]
     order = np.argsort(key_a)
     key_sorted = key_a[order]
-    flat_sorted = (ta * v + ra)[order]
+    flat_sorted = (ra * mask_a.shape[0] + ta)[order]     # slot-major
 
     mask_t = np.asarray(blocked_t.mask)
-    tt, rt = np.nonzero(mask_t)
+    tt, rt = np.nonzero(mask_t)            # row-major: per vector, by slot
     rows_t = np.asarray(blocked_t.block_win)[tt // blocked_t.k_blk] * v + rt
     # entry (rows_t, cols_t) of Aᵀ is element (cols_t, rows_t) of A
     key_t = np.asarray(blocked_t.cols)[tt].astype(np.int64) * k + rows_t
@@ -194,9 +234,29 @@ def _blocked_perm(blocked_a: BlockedMEBCRS,
     if not (pos.size == 0 or np.array_equal(key_sorted[pos], key_t)):
         raise AssertionError("transpose layouts disagree on the sparsity "
                              "pattern (corrupt format?)")
-    perm = np.zeros(mask_t.shape, np.int32)
-    perm[tt, rt] = flat_sorted[pos]
-    return perm
+    src = flat_sorted[pos].astype(np.int32)
+
+    nvec = mask_t.shape[0]
+    fill = np.bincount(tt, minlength=nvec)
+    level = np.arange(tt.size) - (np.cumsum(fill) - fill)[tt]
+    # keep level j while 8 * (vectors with more than j entries) >= the
+    # non-empty vectors: no kept level gathers mostly padding
+    deeper = np.bincount(fill, minlength=v + 1)[::-1].cumsum()[::-1]
+    levels = 1
+    while (levels < v and deeper[levels + 1] > 0
+           and 8 * deeper[levels + 1] >= deeper[1]):
+        levels += 1
+    # flat, level-major: a 2-D int8 map of one level would pad to 4 rows
+    vec_idx = np.zeros(levels * nvec, np.int32)
+    vec_row = np.full(levels * nvec, v, np.int8)
+    kept = level < levels
+    vec_idx[level[kept] * nvec + tt[kept]] = src[kept]
+    vec_row[level[kept] * nvec + tt[kept]] = rt[kept]
+    ovf_src = src[~kept]
+    ovf_dst = (tt * v + rt)[~kept].astype(np.int32)   # sorted, unique
+    record_counter("fs.ad_plan.relayout_levels", levels)
+    record_counter("fs.ad_plan.relayout_overflow", ovf_src.size)
+    return vec_idx, vec_row, ovf_src, ovf_dst
 
 
 @span("fs.ad_plan")
@@ -362,9 +422,11 @@ def ad_plan(fmt: MEBCRS, *, impl: str = "blocked", k_blk: int = 8,
                                        n_blk=n_blk, window_split=False,
                                        n_batches=nbat)
     with span("fs.ad_plan.perm"):
-        perm = jnp.asarray(_blocked_perm(blocked_f, blocked_t))
-    plan = ADPlan(fwd=blocked_f, bwd=blocked_t, perm=perm, impl=impl,
-                  n_blk=n_blk, n_blk_t=n_blk_t, f_blk=f_blk,
+        vec_idx, vec_row, ovf_src, ovf_dst = map(
+            jnp.asarray, _relayout_map(blocked_f, blocked_t))
+    plan = ADPlan(fwd=blocked_f, bwd=blocked_t, vec_idx=vec_idx,
+                  vec_row=vec_row, ovf_src=ovf_src, ovf_dst=ovf_dst,
+                  impl=impl, n_blk=n_blk, n_blk_t=n_blk_t, f_blk=f_blk,
                   fwd_sched=blocked_f.schedule(split_f) if want_f else None,
                   bwd_sched=blocked_t.schedule(split_t) if want_t else None,
                   fwd_part=fwd_part, bwd_part=bwd_part,
@@ -509,8 +571,6 @@ def _spmm_ad(impl, interpret, plan: ADPlan, vals, b):
     # Nonfinite rescue (DESIGN.md §15): guarded output is always fp32 —
     # both lax.cond branches must share a dtype, and casting the fp32
     # rescue back down would re-overflow the very values it saved.
-    from .metrics import record_counter
-
     ok = jnp.all(jnp.isfinite(out))
     record_counter("guard_nonfinite_rerun",
                    (1 - ok.astype(jnp.int32)))

@@ -260,7 +260,7 @@ def sparse_format_shardings(fmt_tree: Any, mesh: Mesh) -> Any:
     """Shardings for a sparse-format pytree (``MEBCRS``, ``BlockedMEBCRS``,
     ``ADPlan``, or anything embedding a ``ShardedSchedule``).
 
-    The pattern metadata (cols / win_ptr / mask / transpose perm) is tiny
+    The pattern metadata (cols / win_ptr / mask / re-layout map) is tiny
     next to the dense operands — §6's footprint math puts ME-BCRS at
     ``4(W+NNZV) + 2·NNZV·V`` bytes, and the autodiff plan at ~2× that
     (DESIGN.md §9) — and the fused kernels read it from HBM by index, so

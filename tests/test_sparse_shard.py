@@ -334,7 +334,8 @@ def test_sharded_format_shardings_place_partition_on_data_axis():
         assert tuple(sh.fwd_part.seg_win.spec) == ("data",)
         assert tuple(sh.bwd_part.row_own.spec) == ("data",)
         assert tuple(sh.fwd.vals.spec) == ()
-        assert tuple(sh.perm.spec) == ()
+        for leaf in (sh.vec_idx, sh.vec_row, sh.ovf_src, sh.ovf_dst):
+            assert tuple(leaf.spec) == ()
 
         # heads_over_model placement matches the sharded ops' head-mode
         # in_specs: leading head dim over "model", nothing over "data"

@@ -27,9 +27,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import ad_plan, from_coo
+from repro.core.autodiff import ADPlan
 from repro.core.format import BlockedMEBCRS, Schedule
 from repro.kernels.attention_pallas import (attention_launch_counts,
                                             attention_pallas)
+from repro.kernels.layout import vectors_on_lanes
 from repro.kernels.sddmm_pallas import sddmm_launch_counts, sddmm_pallas
 from repro.kernels.spmm_pallas import (spmm_launch_counts, spmm_pallas,
                                        spmm_pallas_balanced)
@@ -43,6 +45,7 @@ NODES = 403_394
 V, K_BLK = 8, 8
 FWD = dict(nnzp=1_817_584, nb=227_198)
 BWD = dict(nnzp=3_440_536, nb=430_067)
+OVERFLOW = 71        # Aᵀ entries past the re-layout map's one level
 GCN_WIDTH, AGNN_WIDTH = 128, 32
 
 
@@ -149,6 +152,27 @@ def test_fused_attention_compiles(one_chip):
         lambda a, q, k, v: attention_pallas(a, q, k, v, interpret=False),
         blocked, h, h, h)
     assert "tpu_custom_call" in text
+
+
+def test_value_relayout_gathers_one_value_per_vector(one_chip):
+    """The transpose plan's value re-layout, compiled for the chip at
+    Amazon's shapes (one level, 71 overflow entries) and fed to the
+    kernels' value layout: one gather of a value per Aᵀ vector, one of
+    the overflow, and no copy of the values padded to 128 lanes (a
+    ``(rows, 8)`` float array in the row-major ``{1,0}`` tiled layout)."""
+    idx = lambda n, dt: _sds((n,), dt, one_chip)
+    plan = ADPlan(fwd=_blocked(one_chip, **FWD), bwd=_blocked(one_chip, **BWD),
+                  vec_idx=idx(BWD["nnzp"], jnp.int32),
+                  vec_row=idx(BWD["nnzp"], jnp.int8),
+                  ovf_src=idx(OVERFLOW, jnp.int32),
+                  ovf_dst=idx(OVERFLOW, jnp.int32),
+                  impl="pallas", n_blk=128, n_blk_t=128, f_blk=128)
+    text = _compile_text(
+        lambda p, x: vectors_on_lanes(p.transpose_vals(x), K_BLK), plan,
+        _sds((FWD["nnzp"], V), jnp.float32, one_chip))
+    gathers = re.findall(r"= f32\[(\d+)\]\{[^}]*\} gather\(", text)
+    assert sorted(map(int, gathers)) == [OVERFLOW, BWD["nnzp"]]
+    assert not re.search(r"f32\[\d+,8\]\{1,0:T\(8,128\)", text)
 
 
 def test_gcn_step_kernels_carry_their_launch_counts(one_chip):
