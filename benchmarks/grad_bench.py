@@ -31,7 +31,7 @@ from repro.core.autodiff import ad_plan, sddmm_ad, spmm_ad
 
 from .common import geomean, suite, time_fn, write_csv
 
-IMPLS = ("blocked", "pallas", "pallas_tuned")
+IMPLS = ("blocked", "pallas")
 N_FEAT = 32
 H_BATCHED = 4  # multi-head shape: batched grid vs per-slice loop
 
@@ -70,7 +70,7 @@ def _bench_matrix(g, op: str, impls) -> list:
         (H_BATCHED, m, N_FEAT)).astype(np.float32))
     recs = []
     for impl in impls:
-        plan = ad_plan(fmt, impl=impl, n_example=N_FEAT, interpret=True)
+        plan = ad_plan(fmt, impl=impl)
 
         def run_op(vals_or_q, dense):
             if op == "spmm":

@@ -54,7 +54,7 @@ op benchmark modes (--op NAME, not part of the default figure suite):
   grad_sddmm   same fwd+bwd trajectory for SDDMM; emits BENCH_grad.json
   attn         single-pass fused sparse-attention megakernel vs the staged
                3-dispatch pipeline (DESIGN.md §10); emits BENCH_attn.json
-  spmm         SpMM kernel-path records (fused/staged/noncoalesced/tuned);
+  spmm         SpMM kernel-path records (fused/staged/noncoalesced);
                emits BENCH_spmm.json
 
 modifier flags:

@@ -42,7 +42,7 @@ DTYPE_LEVELS = (
 
 
 def bench_records(scale: float = 0.002, n_values=(128,),
-                  include_tuned: bool = True, verbose: bool = True):
+                  verbose: bool = True):
     """Machine-readable per-impl records (op, impl, shape, sparsity, dtype,
     median_ms, hbm_bytes) for the perf trajectory (BENCH_spmm.json).
 
@@ -94,32 +94,6 @@ def bench_records(scale: float = 0.002, n_values=(128,),
                     "hbm_bytes": ops.spmm_hbm_bytes(
                         blocked, n, n_blk=n_blk_eff, impl="fused",
                         value_bytes=vb, vals_value_bytes=vvb),
-                })
-            if include_tuned:
-                # the same tune → re-block plan users get from spmm_tuned
-                cfg, blocked_t = ops.spmm_tuned_plan(
-                    fmt, b, interpret=True, k_blks=(8, 16), n_blks=(64, 128))
-                if cfg.split_blk:
-                    sched_t = blocked_t.schedule(cfg.split_blk)
-                    run_t = lambda: ops.spmm_balanced(
-                        blocked_t, b, schedule=sched_t,
-                        n_blk=cfg.n_blk, interpret=True)
-                    model_t = "balanced"
-                else:
-                    sched_t = None
-                    run_t = lambda: ops.spmm(blocked_t, b, n_blk=cfg.n_blk,
-                                             interpret=True)
-                    model_t = "fused"
-                recs.append({
-                    "op": "spmm", "impl": "pallas_tuned", "matrix": g.name,
-                    "shape": [shape[0], shape[1], n], "sparsity": sparsity,
-                    "dtype": "float32",
-                    "vector_size": 8, "k_blk": cfg.k_blk, "n_blk": cfg.n_blk,
-                    "split_blk": cfg.split_blk,
-                    "median_ms": time_fn(run_t, reps=3, warmup=1),
-                    "hbm_bytes": ops.spmm_hbm_bytes(
-                        blocked_t, n, n_blk=cfg.n_blk, impl=model_t,
-                        schedule=sched_t),
                 })
             if verbose:
                 by = {r["impl"]: r for r in recs
@@ -376,7 +350,7 @@ def run_op(scale: float = 0.002, skewed: bool = False,
     """``benchmarks.run --op spmm [--skewed] [--datasets]``: emit
     BENCH_spmm.json.
 
-    Always contains the standard fused/staged/noncoalesced/tuned records
+    Always contains the standard fused/staged/noncoalesced records
     (so the staged-vs-fused HBM floor stays checkable from the same
     artifact); ``skewed=True`` appends the hub-row balanced-vs-window
     records (the ≥ 1.3× CI floor on skew ≥ 1.5 matrices), the device-

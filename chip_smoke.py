@@ -267,7 +267,7 @@ def one_chip(seed, phases) -> None:
     from repro.models.gnn import GNNConfig
 
     g, fmt, x, labels, train_mask = build_task(seed, phases)
-    plan = ad_plan(fmt, impl="pallas", n_example=HIDDEN_GCN)
+    plan = ad_plan(fmt, impl="pallas")
     phases.done("plan (transpose, blocking, value permutation)")
     log_setup_spans()
 
@@ -314,9 +314,8 @@ def four_chips(seed, phases) -> None:
           f"--four-chips needs 4 devices, found {len(jax.devices())}")
     g, fmt, x, labels, train_mask = build_task(seed, phases)
     mesh = make_host_mesh(4, 1)
-    plan1 = ad_plan(fmt, impl="pallas", n_example=HIDDEN_GCN)
-    plan4 = ad_plan(fmt, impl="pallas_sharded", mesh=mesh,
-                    n_example=HIDDEN_GCN)
+    plan1 = ad_plan(fmt, impl="pallas")
+    plan4 = ad_plan(fmt, impl="pallas_sharded", mesh=mesh)
     plan4 = jax.device_put(plan4, sparse_format_shardings(plan4, mesh))
     phases.done("plans (one chip, 4-way sharded)")
     log_setup_spans()

@@ -6,11 +6,11 @@ f32 vs bf16 numerics — the offline counterpart of paper Fig. 16 / Table 8.
 
 The adjacency is wrapped in an autodiff plan (``ad_plan``), so ``--impl``
 selects any differentiable registry implementation — ``blocked`` (XLA),
-``pallas`` or ``pallas_tuned`` — and the backward pass runs the dispatched
+``pallas`` or ``pallas_balanced`` — and the backward pass runs the dispatched
 transpose-SpMM/SDDMM duality (DESIGN.md §9) through the same kernels.
 
   PYTHONPATH=src python examples/gnn_train.py [--graph GitHub] [--epochs 60]
-  PYTHONPATH=src python examples/gnn_train.py --steps 2 --impl pallas_tuned \
+  PYTHONPATH=src python examples/gnn_train.py --steps 2 --impl pallas \
       --scale 0.002
       # smoke: one config at --scale, asserts finite decreasing loss
   XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \
@@ -55,7 +55,7 @@ def train_one(g, x_np, labels, train_mask, *, model, v, dtype, impl,
                     impl=impl, dtype=dtype)
     fmt = from_coo(g.rows, g.cols, g.vals, (g.num_nodes, g.num_nodes),
                    vector_size=v, dtype=dtype)
-    adj = ad_plan(fmt, impl=impl, n_example=cfg.hidden_dim, mesh=mesh)
+    adj = ad_plan(fmt, impl=impl, mesh=mesh)
     x = jnp.asarray(x_np, dtype)
     init = init_gcn if model == "gcn" else init_agnn
     params = init(jax.random.key(0), cfg)
@@ -80,7 +80,7 @@ def main():
     ap.add_argument("--model", default="both", choices=["gcn", "agnn", "both"])
     ap.add_argument("--impl", default="blocked",
                     help="registry impl: blocked | pallas | pallas_balanced "
-                         "| pallas_tuned | pallas_sharded")
+                         "| pallas_sharded")
     ap.add_argument("--steps", type=int, default=None,
                     help="smoke mode: run STEPS steps of one config at "
                          "--scale and assert a finite loss decrease")

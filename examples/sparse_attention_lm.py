@@ -8,7 +8,7 @@ scores are computed only at the nonzero pattern, row-normalized, and
 aggregated.
 
 The layer lives in ``repro.models.layers.sparse_attention``.  With
-``--impl pallas``/``pallas_tuned`` it executes the **single-pass fused
+``--impl pallas``/``pallas_balanced`` it executes the **single-pass fused
 megakernel** (DESIGN.md §10): one ``(H, W)`` grid launch computes SDDMM
 scores into VMEM, applies the row-segment online softmax, and accumulates
 against V — the scores never exist in HBM — and ``jax.grad`` flows through
@@ -64,15 +64,14 @@ def train_value_projection(plan, q, k, v, impl: str, steps: int,
     loss_grad = jax.jit(jax.value_and_grad(loss_fn))
     with sparse_dispatch.record_calls() as log:
         loss0, _ = loss_grad(w)
-    if impl in ("pallas", "pallas_balanced", "pallas_tuned",
-                "pallas_sharded"):
+    if impl in ("pallas", "pallas_balanced", "pallas_sharded"):
         n_fused = (log.count(("attention", "pallas_fused_attn"))
                    + log.count(("attention", "pallas_balanced"))
                    + log.count(("attention", "pallas_sharded")))
         assert n_fused >= 1, f"train step did not hit the fused kernel: {log}"
         n_bwd = sum(1 for op, i in log
                     if op in ("spmm", "sddmm")
-                    and i in ("pallas_batched", "pallas_balanced",
+                    and i in ("pallas", "pallas_balanced",
                               "pallas_sharded"))
         print(f"train step traced {n_fused} fused-megakernel forward and "
               f"{n_bwd} batched duality-kernel backward dispatches")
@@ -92,7 +91,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--impl", default="blocked",
                     help="registry impl: blocked | pallas | "
-                         "pallas_balanced | pallas_tuned | pallas_sharded")
+                         "pallas_balanced | pallas_sharded")
     ap.add_argument("--seq", type=int, default=512)
     ap.add_argument("--heads", type=int, default=2)
     ap.add_argument("--steps", type=int, default=0,
@@ -116,7 +115,7 @@ def main():
     rows, cols = block_sparse_causal_pattern(seq)
     vals = np.ones_like(rows, np.float32)
     fmt = from_coo(rows, cols, vals, (seq, seq), vector_size=8)
-    plan = ad_plan(fmt, impl=args.impl, n_example=d, mesh=mesh)
+    plan = ad_plan(fmt, impl=args.impl, mesh=mesh)
     density = len(rows) / seq ** 2
     print(f"pattern: {len(rows):,} nonzeros of {seq * seq:,} "
           f"({density:.1%} dense) — compute saved vs full: {1 - density:.1%}")
@@ -128,10 +127,9 @@ def main():
 
     with sparse_dispatch.record_calls() as log:
         out_sparse = sparse_attention(plan, q, k, v, impl=args.impl)
-    if args.impl in ("pallas", "pallas_balanced", "pallas_tuned",
-                     "pallas_sharded"):
-        # a tuned/balanced/sharded plan may route onto the block-parallel
-        # or multi-device megakernel
+    if args.impl in ("pallas", "pallas_balanced", "pallas_sharded"):
+        # a balanced/sharded plan routes onto the block-parallel or
+        # multi-device megakernel
         assert len(log) == 1 and log[0][0] == "attention" and \
             log[0][1] in ("pallas_fused_attn", "pallas_balanced",
                           "pallas_sharded"), log

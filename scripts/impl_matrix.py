@@ -18,8 +18,6 @@ FLAGS = (
     ("load_balanced", "balanced"),
     ("multi_device", "multi-dev"),
     ("overlapped", "overlap"),
-    ("needs_canonical", "canonical-in"),
-    ("returns_format", "format-out"),
 )
 
 

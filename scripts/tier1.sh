@@ -42,10 +42,10 @@ if [[ $# -eq 0 ]]; then
 fi
 
 # Gradient-path smoke (full runs only): two training steps through the
-# autotuned Pallas impl must produce a finite, decreasing loss — the
-# backward runs the transpose-SpMM/SDDMM duality (DESIGN.md §9).
+# Pallas impl must produce a finite, decreasing loss — the backward runs
+# the transpose-SpMM/SDDMM duality (DESIGN.md §9).
 if [[ $# -eq 0 && "${TIER1_SMOKE:-1}" == "1" ]]; then
-  python examples/gnn_train.py --steps 2 --impl pallas_tuned \
+  python examples/gnn_train.py --steps 2 --impl pallas \
     --model gcn --scale 0.002
 
   # Fused-attention smoke (interpret mode): LM example forward + one

@@ -44,10 +44,6 @@ dozen entries.  The plan build records the counters
 
 The plan is a pytree: pass it through ``jit``/``grad``/``shard_map``
 like the format itself.
-``impl="pallas_tuned"`` resolves the autotuner **at plan-build time**
-(fwd, transpose and SDDMM directions tuned independently, the SDDMM
-``k_blk`` pinned to the forward layout), so the traced computation never
-re-enters the host-side tuner.
 
 All wrappers accept a leading batch dim on the dense operands and/or the
 bound values (per-head sparse attention).  The Pallas paths execute the
@@ -97,14 +93,13 @@ class ADPlan:
     vec_row: jax.Array    # (L·NNZP_T,) int8 slot of that entry, V = none
     ovf_src: jax.Array    # (n_ovf,) int32 the same, for entries past level L
     ovf_dst: jax.Array    # (n_ovf,) int32 flat bwd slots, sorted, unique
-    impl: str             # impl the tile parameters below were chosen for
-    n_blk: int            # forward SpMM column tile
-    n_blk_t: int          # transpose-SpMM (dB / dK) column tile
+    impl: str             # impl every traced call on the plan runs
+    n_blk: int            # SpMM column tile, forward and transposed (dB / dK)
     f_blk: int            # SDDMM feature tile (dVals / forward SDDMM)
-    # Block-parallel schedules (DESIGN.md §11), present when the impl (or
-    # the tuner, per direction) chose the balanced kernels.  A and Aᵀ are
-    # scheduled independently — the transposed format has its own skew
-    # (hub *columns* of A become hub windows of Aᵀ).
+    # Block-parallel schedules (DESIGN.md §11), present for the balanced
+    # and sharded impls.  A and Aᵀ are scheduled independently — the
+    # transposed format has its own skew (hub *columns* of A become hub
+    # windows of Aᵀ).
     fwd_sched: Optional[Schedule] = None
     bwd_sched: Optional[Schedule] = None
     # Multi-device partitions (DESIGN.md §12), present for
@@ -189,7 +184,7 @@ class ADPlan:
                  self.ovf_src, self.ovf_dst, self.fwd_sched,
                  self.bwd_sched, self.fwd_part, self.bwd_part,
                  self.fwd_part_wa),
-                (self.impl, self.n_blk, self.n_blk_t, self.f_blk, self.mesh,
+                (self.impl, self.n_blk, self.f_blk, self.mesh,
                  self.precision, self.overlap_batches,
                  self.guard_nonfinite))
 
@@ -197,11 +192,11 @@ class ADPlan:
     def tree_unflatten(cls, aux, leaves):
         (fwd, bwd, vec_idx, vec_row, ovf_src, ovf_dst, fwd_sched, bwd_sched,
          fwd_part, bwd_part, fwd_part_wa) = leaves
-        (impl, n_blk, n_blk_t, f_blk, mesh, precision, overlap_batches,
+        (impl, n_blk, f_blk, mesh, precision, overlap_batches,
          guard_nonfinite) = aux
         return cls(fwd=fwd, bwd=bwd, vec_idx=vec_idx, vec_row=vec_row,
                    ovf_src=ovf_src, ovf_dst=ovf_dst, impl=impl, n_blk=n_blk,
-                   n_blk_t=n_blk_t, f_blk=f_blk, fwd_sched=fwd_sched,
+                   f_blk=f_blk, fwd_sched=fwd_sched,
                    bwd_sched=bwd_sched, fwd_part=fwd_part,
                    bwd_part=bwd_part, fwd_part_wa=fwd_part_wa, mesh=mesh,
                    precision=precision, overlap_batches=overlap_batches,
@@ -262,22 +257,20 @@ def _relayout_map(blocked_a: BlockedMEBCRS, blocked_t: BlockedMEBCRS
 @span("fs.ad_plan")
 def ad_plan(fmt: MEBCRS, *, impl: str = "blocked", k_blk: int = 8,
             n_blk: int = 128, f_blk: int = 128, split_blk: int = 1,
-            n_example: int = 64, interpret: Optional[bool] = None,
-            cache=None, mesh=None, overlap_batches: Optional[int] = None,
+            n_example: int = 64, mesh=None,
+            overlap_batches: Optional[int] = None,
             precision: Optional[str] = None,
             guard_nonfinite: bool = False) -> ADPlan:
     """Build (and memoize on ``fmt``) the differentiable-op plan.
 
     Host-side precompute, like ``block_format`` — call outside ``jit``.
-    For ``impl="pallas_tuned"`` the autotuner picks ``(k_blk, n_blk,
-    split_blk)`` per direction now (timing dummies of ``n_example``
-    feature columns in the format's dtype), so traced forward/backward
-    calls run the fused kernel directly with the plan's tiles and never
-    hit the tuner.  ``impl="pallas_balanced"`` builds the block-parallel
+    Every direction runs the tiles given here: ``k_blk`` for A and Aᵀ,
+    ``n_blk`` for both SpMMs, ``f_blk`` for the SDDMM.  ``n_example`` is
+    accepted and ignored (the benchmark harness still passes it).
+    ``impl="pallas_balanced"`` builds the block-parallel
     :class:`Schedule` for **both** directions with ``split_blk`` (A and Aᵀ
-    scheduled independently — the transpose has its own skew); a tuned
-    plan carries a schedule for whichever direction the sweep preferred
-    balanced.  ``impl="pallas_sharded"`` (DESIGN.md §12) additionally
+    scheduled independently — the transpose has its own skew).
+    ``impl="pallas_sharded"`` (DESIGN.md §12) additionally
     partitions each direction's schedule over ``mesh``'s "data" axis —
     cost-balanced with hub-window straddling allowed for SpMM/SDDMM,
     plus a window-aligned forward variant for the fused attention
@@ -337,19 +330,10 @@ def ad_plan(fmt: MEBCRS, *, impl: str = "blocked", k_blk: int = 8,
             f"ad_plan(mesh=...) is only meaningful for a multi-device "
             f"impl like 'pallas_sharded' (got impl={impl!r}); dropping "
             f"the mesh would silently run single-device")
-    del entry
+    del entry, n_example
 
-    # Only the tuned path consults interpret/cache (the tiles it picks
-    # differ per execution mode and per cache file) — resolve them into
-    # the memo key there; the fixed-tile impls share one plan.
-    interp = cache_tag = None
-    if impl == "pallas_tuned":
-        from repro.kernels import ops
-
-        interp = ops._resolve_interpret(interpret)
-        cache_tag = getattr(cache, "path", None) if cache is not None else None
-    key = (impl, k_blk, n_blk, f_blk, int(split_blk), int(n_example), interp,
-           cache_tag, mesh, precision, int(overlap_batches), guard_nonfinite)
+    key = (impl, k_blk, n_blk, f_blk, int(split_blk), mesh, precision,
+           int(overlap_batches), guard_nonfinite)
     memo = getattr(fmt, "_ad_plans", None)
     if memo is None:
         memo = {}
@@ -359,46 +343,14 @@ def ad_plan(fmt: MEBCRS, *, impl: str = "blocked", k_blk: int = 8,
 
     with span("fs.ad_plan.transpose"):
         fmt_t = fmt.transpose()
-    k_blk_f = k_blk_t = k_blk
-    n_blk_t = n_blk
-    split_f = split_t = (split_blk if impl in ("pallas_balanced",
-                                               "pallas_sharded",
-                                               "pallas_sharded_overlap")
-                         else 0)
-    if impl == "pallas_tuned":
-        from repro.kernels import autotune
-
-        m, k = fmt.shape
-        dt = fmt.values.dtype
-        b_ex = jnp.zeros((k, n_example), dt)
-        g_ex = jnp.zeros((m, n_example), dt)
-        # pin the sweep to the plan's precision so the timings match the
-        # path the traced calls will run
-        pk = {} if precision is None else {"precisions": (precision,)}
-        pk_d = ({} if precision is None
-                else {"precisions": (_dense_precision(precision),)})
-        cfg_f = autotune.tune_spmm(fmt, b_ex, interpret=interp, cache=cache,
-                                   **pk)
-        cfg_t = autotune.tune_spmm(fmt_t, g_ex, interpret=interp, cache=cache,
-                                   **pk)
-        # dVals must land in the forward value layout → pin the SDDMM k_blk
-        cfg_s = autotune.tune_sddmm(fmt, g_ex, b_ex, k_blks=(cfg_f.k_blk,),
-                                    interpret=interp, cache=cache, **pk_d)
-        k_blk_f, n_blk = cfg_f.k_blk, cfg_f.n_blk
-        k_blk_t, n_blk_t = cfg_t.k_blk, cfg_t.n_blk
-        f_blk = cfg_s.n_blk
-        split_f, split_t = cfg_f.split_blk, cfg_t.split_blk
-
     with span("fs.ad_plan.block"):
-        blocked_f = block_format(fmt, k_blk_f)
-        blocked_t = block_format(fmt_t, k_blk_t)
+        blocked_f = block_format(fmt, k_blk)
+        blocked_t = block_format(fmt_t, k_blk)
     # pallas_balanced/_sharded always carry schedules — split_blk = 0 is the
-    # valid *unsplit* schedule, not "no schedule"; for pallas_tuned a split
-    # of 0 means the sweep chose the window-parallel kernel for that
-    # direction.
+    # valid *unsplit* schedule, not "no schedule".
     sharded_impls = ("pallas_sharded", "pallas_sharded_overlap")
-    want_f = impl in ("pallas_balanced",) + sharded_impls or split_f > 0
-    want_t = impl in ("pallas_balanced",) + sharded_impls or split_t > 0
+    scheduled = impl in ("pallas_balanced",) + sharded_impls
+    split = int(split_blk) if scheduled else 0
     fwd_part = bwd_part = fwd_part_wa = None
     if impl in sharded_impls:
         from repro.distributed.sparse_shard import sharded_schedule
@@ -407,18 +359,17 @@ def ad_plan(fmt: MEBCRS, *, impl: str = "blocked", k_blk: int = 8,
         # SpMM/SDDMM partitions may cut inside hub windows (the balance
         # lever — partials recombine in the psum); attention gets its own
         # window-aligned forward partition (softmax cannot straddle).
-        # Each direction's partition is cost-balanced for the tile that
-        # direction runs (SDDMM reuses fwd_part; its f_blk and the SpMM
-        # n_blk share the 128 default, and the cut positions are only
-        # mildly tile-sensitive).  The overlap impl builds the same
+        # Each partition is cost-balanced for the SpMM tile ``n_blk``
+        # (SDDMM reuses fwd_part; its f_blk and n_blk share the 128
+        # default, and the cut positions are only mildly tile-sensitive).  The overlap impl builds the same
         # partitions with ``overlap_batches`` segment batches per device
         # (batch cuts inherit each partition's window_split rule).
         nbat = overlap_batches
-        fwd_part = sharded_schedule(blocked_f, ndev, split_blk=split_f,
+        fwd_part = sharded_schedule(blocked_f, ndev, split_blk=split,
                                     n_blk=n_blk, n_batches=nbat)
-        bwd_part = sharded_schedule(blocked_t, ndev, split_blk=split_t,
-                                    n_blk=n_blk_t, n_batches=nbat)
-        fwd_part_wa = sharded_schedule(blocked_f, ndev, split_blk=split_f,
+        bwd_part = sharded_schedule(blocked_t, ndev, split_blk=split,
+                                    n_blk=n_blk, n_batches=nbat)
+        fwd_part_wa = sharded_schedule(blocked_f, ndev, split_blk=split,
                                        n_blk=n_blk, window_split=False,
                                        n_batches=nbat)
     with span("fs.ad_plan.perm"):
@@ -426,9 +377,9 @@ def ad_plan(fmt: MEBCRS, *, impl: str = "blocked", k_blk: int = 8,
             jnp.asarray, _relayout_map(blocked_f, blocked_t))
     plan = ADPlan(fwd=blocked_f, bwd=blocked_t, vec_idx=vec_idx,
                   vec_row=vec_row, ovf_src=ovf_src, ovf_dst=ovf_dst,
-                  impl=impl, n_blk=n_blk, n_blk_t=n_blk_t, f_blk=f_blk,
-                  fwd_sched=blocked_f.schedule(split_f) if want_f else None,
-                  bwd_sched=blocked_t.schedule(split_t) if want_t else None,
+                  impl=impl, n_blk=n_blk, f_blk=f_blk,
+                  fwd_sched=blocked_f.schedule(split) if scheduled else None,
+                  bwd_sched=blocked_t.schedule(split) if scheduled else None,
                   fwd_part=fwd_part, bwd_part=bwd_part,
                   fwd_part_wa=fwd_part_wa, mesh=mesh, precision=precision,
                   overlap_batches=overlap_batches,
@@ -445,18 +396,10 @@ def _dense_precision(precision: Optional[str]) -> Optional[str]:
     return "bf16" if precision == "int8" else precision
 
 
-def _exec_impl(impl: str) -> str:
-    """The impl the traced computation actually runs.  ``pallas_tuned``
-    fixed its tiles at plan-build time → execute the plain fused kernel
-    (or the balanced one — decided per direction via the plan's
-    schedules, see ``_run_spmm``)."""
-    return "pallas" if impl == "pallas_tuned" else impl
-
-
 def _is_pallas(impl: str) -> bool:
     """Pallas-family impls run native batched grids (no per-slice loop)."""
-    return _exec_impl(impl) in ("pallas", "pallas_balanced", "pallas_sharded",
-                                "pallas_sharded_overlap")
+    return impl in ("pallas", "pallas_balanced", "pallas_sharded",
+                    "pallas_sharded_overlap")
 
 
 def _map_slices(entry, fn, batched_args, shared_args):
@@ -488,65 +431,56 @@ def _run_spmm(impl, interpret, plan: ADPlan, vals, b, *, transposed: bool,
     # (``kernel_metadata``); the sharded ones carry no metadata.
     direction = "bwd" if transposed else "fwd"
     blocked = plan.bwd if transposed else plan.fwd
-    n_blk = plan.n_blk_t if transposed else plan.n_blk
     sched = plan.bwd_sched if transposed else plan.fwd_sched
-    ex = _exec_impl(impl)
-    if ex in ("pallas_sharded", "pallas_sharded_overlap"):
+    if impl in ("pallas_sharded", "pallas_sharded_overlap"):
         # one local balanced launch per device over this direction's own
         # partition, outputs reassembled by the psum (DESIGN.md §12) —
         # dB's transpose-SpMM runs on the Aᵀ partition, which is exactly
         # the "psum for dB" of the sharded backward; the overlap impl
         # rides the same partitions (batched to plan.overlap_batches)
         # with the ppermute ring in place of the psum (§14)
-        return _dispatch.dispatch("spmm", ex,
+        return _dispatch.dispatch("spmm", impl,
                                   with_values(blocked, vals), b,
-                                  k_blk=blocked.k_blk, n_blk=n_blk,
+                                  k_blk=blocked.k_blk, n_blk=plan.n_blk,
                                   schedule=sched, mesh=plan.mesh,
                                   part=plan.bwd_part if transposed
                                   else plan.fwd_part,
                                   interpret=interpret, precision=precision)
-    if ex == "pallas_balanced" or (impl == "pallas_tuned"
-                                   and sched is not None):
+    if impl == "pallas_balanced":
         # block-parallel (H, N/N_BLK, NS) grid with this direction's own
         # schedule (Aᵀ is re-scheduled: its skew differs from A's)
         return _dispatch.dispatch("spmm", "pallas_balanced",
                                   with_values(blocked, vals), b,
-                                  k_blk=blocked.k_blk, n_blk=n_blk,
+                                  k_blk=blocked.k_blk, n_blk=plan.n_blk,
                                   schedule=sched, interpret=interpret,
                                   precision=precision, direction=direction)
-    kw = {}
-    if ex == "pallas":
-        kw["direction"] = direction
-        if vals.ndim == 3 or b.ndim == 3:
-            # native (H, N/N_BLK, W) grid: one launch for every head
-            ex = "pallas_batched"
-    return _dispatch.dispatch("spmm", ex,
+    # "pallas" runs a native (H, N/N_BLK, W) grid: one launch for any head
+    # count
+    kw = {"direction": direction} if impl == "pallas" else {}
+    return _dispatch.dispatch("spmm", impl,
                               with_values(blocked, vals), b,
-                              k_blk=blocked.k_blk, n_blk=n_blk,
+                              k_blk=blocked.k_blk, n_blk=plan.n_blk,
                               interpret=interpret, precision=precision, **kw)
 
 
 def _run_sddmm(impl, interpret, plan: ADPlan, q, k, *, precision=None):
     precision = _dense_precision(precision)   # SDDMM has no int8 level
-    ex = _exec_impl(impl)
-    if ex in ("pallas_sharded", "pallas_sharded_overlap"):
+    if impl in ("pallas_sharded", "pallas_sharded_overlap"):
         # SDDMM samples A's pattern → the forward partition's block list
-        return _dispatch.dispatch("sddmm", ex, plan.fwd, q, k,
+        return _dispatch.dispatch("sddmm", impl, plan.fwd, q, k,
                                   k_blk=plan.fwd.k_blk, f_blk=plan.f_blk,
                                   schedule=plan.fwd_sched, mesh=plan.mesh,
                                   part=plan.fwd_part, interpret=interpret,
                                   precision=precision)
-    if ex == "pallas_balanced" or (impl == "pallas_tuned"
-                                   and plan.fwd_sched is not None):
+    if impl == "pallas_balanced":
         # SDDMM samples A's pattern → the forward schedule's block list
         return _dispatch.dispatch("sddmm", "pallas_balanced", plan.fwd, q, k,
                                   k_blk=plan.fwd.k_blk, f_blk=plan.f_blk,
                                   schedule=plan.fwd_sched,
                                   interpret=interpret, precision=precision)
-    if ex == "pallas" and (q.ndim == 3 or k.ndim == 3):
-        # native (H, NB, F/F_BLK) grid: one launch for every head
-        ex = "pallas_batched"
-    return _dispatch.dispatch("sddmm", ex, plan.fwd, q, k,
+    # "pallas" runs a native (H, NB, F/F_BLK) grid: one launch for any head
+    # count
+    return _dispatch.dispatch("sddmm", impl, plan.fwd, q, k,
                               k_blk=plan.fwd.k_blk, f_blk=plan.f_blk,
                               interpret=interpret, precision=precision)
 
@@ -560,7 +494,7 @@ def _spmm_ad(impl, interpret, plan: ADPlan, vals, b):
         if not (vb or bb) or _is_pallas(impl):
             return _run_spmm(impl, interpret, plan, vals_m, b,
                              transposed=False, precision=precision)
-        entry = _dispatch.get("spmm", _exec_impl(impl))
+        entry = _dispatch.get("spmm", impl)
         run = lambda v_, b_: _run_spmm(impl, interpret, plan, v_, b_,
                                        transposed=False, precision=precision)
         return _map_slices(entry, run, [(vals_m, vb), (b, bb)], ())
@@ -610,7 +544,7 @@ def _spmm_ad_bwd(impl, interpret, res, g):
         dvals = d_vals(g, b)
         dvals = dvals if vb else jnp.sum(dvals, axis=0)
     else:
-        entry = _dispatch.get("spmm", _exec_impl(impl))
+        entry = _dispatch.get("spmm", impl)
         db_sl = _map_slices(entry, d_b, [(vals, vb), (g, True)], ())
         db = db_sl if bb else jnp.sum(db_sl, axis=0)
         dv_sl = _map_slices(entry, d_vals, [(g, True), (b, bb)], ())
@@ -650,7 +584,7 @@ def _sddmm_ad(impl, interpret, plan: ADPlan, q, k):
     prec = _dense_precision(plan.precision)
     if not (qb or kb) or _is_pallas(impl):
         return _run_sddmm(impl, interpret, plan, q, k, precision=prec)
-    entry = _dispatch.get("sddmm", _exec_impl(impl))
+    entry = _dispatch.get("sddmm", impl)
     run = lambda q_, k_: _run_sddmm(impl, interpret, plan, q_, k_,
                                     precision=prec)
     return _map_slices(entry, run, [(q, qb), (k, kb)], ())
@@ -686,7 +620,7 @@ def _sddmm_ad_bwd(impl, interpret, res, g):
         dk = d_k(g, q)
         dk = dk if kb else jnp.sum(dk, axis=0)
     else:
-        entry = _dispatch.get("spmm", _exec_impl(impl))
+        entry = _dispatch.get("spmm", impl)
         dq_sl = _map_slices(entry, d_q, [(g, True), (k, kb)], ())
         dq = dq_sl if qb else jnp.sum(dq_sl, axis=0)
         dk_sl = _map_slices(entry, d_k, [(g, True), (q, qb)], ())
@@ -702,10 +636,9 @@ def sddmm_ad(plan: ADPlan, q: jax.Array, k: jax.Array, *,
              interpret: Optional[bool] = None) -> jax.Array:
     """Differentiable SDDMM → forward-layout values (NNZP, V).
 
-    ``q``: (M, F) / (H, M, F); ``k``: (Mc, F) / (H, Mc, F).  Unlike
-    ``core.sddmm(impl="pallas_tuned")`` this always returns a bare value
-    array in the **plan's** forward layout (the tuner already ran at plan
-    build), so SDDMM → sparse softmax → SpMM compose without re-blocking.
+    ``q``: (M, F) / (H, M, F); ``k``: (Mc, F) / (H, Mc, F).  The values
+    come back in the **plan's** forward layout, so SDDMM → sparse softmax
+    → SpMM compose without re-blocking.
     Backward is two dispatched SpMMs: dQ on A, dK on the cached Aᵀ.
     """
     impl = impl or plan.impl
@@ -735,20 +668,18 @@ def _staged_attention(impl, interpret, plan: ADPlan, q, k, v, scale):
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _attention_ad(impl, interpret, plan: ADPlan, q, k, v, scale):
-    if _exec_impl(impl) in ("pallas_sharded", "pallas_sharded_overlap"):
+    if impl in ("pallas_sharded", "pallas_sharded_overlap"):
         # sharded single-pass megakernel on the window-aligned forward
         # partition; the recompute backward (below) re-dispatches the
         # sharded duality ops on each direction's own partition.  The
         # overlap impl pipelines window-aligned segment batches, so the
         # online-softmax state never crosses a ring step (§14).
-        return _dispatch.dispatch("attention", _exec_impl(impl), plan.fwd,
+        return _dispatch.dispatch("attention", impl, plan.fwd,
                                   q, k, v, scale=scale, k_blk=plan.fwd.k_blk,
                                   schedule=plan.fwd_sched, mesh=plan.mesh,
                                   part=plan.fwd_part_wa, interpret=interpret,
                                   precision=_dense_precision(plan.precision))
-    if _exec_impl(impl) == "pallas_balanced" or (impl == "pallas_tuned"
-                                                 and plan.fwd_sched
-                                                 is not None):
+    if impl == "pallas_balanced":
         # balanced (H, NS) megakernel: online softmax carried across the
         # split segments of each window via the plan's forward schedule
         return _dispatch.dispatch("attention", "pallas_balanced", plan.fwd,
@@ -811,19 +742,12 @@ def attention_ad(plan: ADPlan, q: jax.Array, k: jax.Array, v: jax.Array, *,
     survives as :func:`repro.models.layers.sparse_attention_staged` for
     parity tests and traffic benchmarks.
 
-    ``impl="pallas_balanced"`` (or a tuned plan whose forward sweep chose
-    a split) runs the **block-parallel** megakernel instead: the same
-    single-pass math on the uniform-segment ``(H, NS)`` grid, with the
-    online-softmax statistics carried across each window's split segments
-    (bitwise-equal outputs), and the recompute backward dispatching the
-    balanced duality kernels on each direction's own schedule.
-
-    ``impl="pallas_tuned"`` runs the megakernel on the plan's blocked
-    layout, i.e. with the ``k_blk`` the plan's SpMM sweep picked (the
-    backward must rebind values in that layout).  The forward-only
-    attention-specific sweep lives in the registry as
-    ``("attention", "pallas_fused_attn_tuned")`` /
-    :func:`repro.kernels.ops.attention_tuned`.
+    ``impl="pallas_balanced"`` runs the **block-parallel** megakernel
+    instead: the same single-pass math on the uniform-segment ``(H, NS)``
+    grid, with the online-softmax statistics carried across each window's
+    split segments (bitwise-equal outputs), and the recompute backward
+    dispatching the balanced duality kernels on each direction's own
+    schedule.
     """
     impl = impl or plan.impl
     _dispatch.require("spmm", impl, differentiable=True)

@@ -3,7 +3,7 @@
 Before this module, four separate ``impl=`` string ladders resolved the
 execution path — ``core/spmm.py``, ``core/sddmm.py``, ``kernels/ops.py``
 and ``models/gnn.py`` each kept their own if/elif chain, and they drifted
-(the GNN aggregation, for one, silently ignored ``impl="pallas_tuned"``).
+(the GNN aggregation, for one, silently ignored an impl it did not know).
 Now every implementation of an op registers here exactly once, with
 capability flags, and every layer — core dispatch, autodiff backward
 passes, models, train steps, benchmarks — resolves ``(op, impl)`` through
@@ -20,18 +20,10 @@ Capability flags:
                    blocked einsum) or via :mod:`repro.core.autodiff`'s
                    custom_vjp wrappers (Pallas paths)
   batched          handles a leading head/batch dim in ONE call: XLA
-                   impls are safe under ``jax.vmap``; the ``*_batched``
-                   Pallas impls and the attention megakernel run native
-                   ``(H, ...)`` grids — one kernel launch for any head
-                   count.  Unflagged impls get an unrolled per-slice
-                   loop from the autodiff wrappers instead.
-  tpu_only         compiled execution requires a TPU backend (no
-                   interpret-mode fallback)
-  needs_canonical  requires the canonical :class:`MEBCRS` (re-blocks it,
-                   e.g. the autotuned paths sweep ``k_blk``)
-  returns_format   returns a :class:`BlockedMEBCRS` with values bound
-                   instead of a bare value array (tuned SDDMM: the value
-                   layout depends on the tuned ``k_blk``)
+                   impls are safe under ``jax.vmap``; the Pallas impls
+                   run native ``(H, ...)`` grids — one kernel launch
+                   for any head count.  Unflagged impls get an unrolled
+                   per-slice loop from the autodiff wrappers instead.
   load_balanced    the impl maps work onto uniform schedule segments
                    (block-parallel grids, DESIGN.md §11) instead of
                    ragged per-window loops — accepts ``schedule=`` /
@@ -98,9 +90,6 @@ class OpImpl:
     fn: Callable
     differentiable: bool = False
     batched: bool = False
-    tpu_only: bool = False
-    needs_canonical: bool = False
-    returns_format: bool = False
     load_balanced: bool = False
     multi_device: bool = False
     overlapped: bool = False
@@ -256,18 +245,15 @@ class FallbackWarning(UserWarning):
 # (ablation variants like pallas_staged/pallas_noncoalesced for SpMM)
 # enter at the plain single-device tier.  The sddmm ladder ends at
 # ``blocked`` — the ``coo`` impl returns edge values ``(NNZ,)``, a
-# different output contract than the blocked-layout rungs (and
-# ``returns_format`` impls like tuned SDDMM never degrade to bare-array
-# rungs for the same reason).
+# different output contract than the blocked-layout rungs.
 _LADDERS: Dict[str, Tuple[str, ...]] = {
-    "spmm": ("pallas_sharded_overlap", "pallas_sharded", "pallas_tuned",
-             "pallas_balanced", "pallas_batched", "pallas", "blocked",
-             "coo_segment"),
-    "sddmm": ("pallas_sharded_overlap", "pallas_sharded", "pallas_tuned",
-              "pallas_balanced", "pallas_batched", "pallas", "blocked"),
+    "spmm": ("pallas_sharded_overlap", "pallas_sharded", "pallas_balanced",
+             "pallas", "blocked", "coo_segment"),
+    "sddmm": ("pallas_sharded_overlap", "pallas_sharded", "pallas_balanced",
+              "pallas", "blocked"),
     "attention": ("pallas_sharded_overlap", "pallas_sharded",
-                  "pallas_fused_attn_tuned", "pallas_balanced",
-                  "pallas_fused_attn", "pallas_staged", "blocked"),
+                  "pallas_balanced", "pallas_fused_attn", "pallas_staged",
+                  "blocked"),
 }
 _DEFAULT_TIER = {"spmm": "pallas", "sddmm": "pallas",
                  "attention": "pallas_staged"}
@@ -292,39 +278,17 @@ def fallback_chain(op: str, impl: str) -> Tuple[str, ...]:
     return ladder
 
 
-def _static_compatible(entry: OpImpl, orig: OpImpl) -> bool:
-    return entry.returns_format == orig.returns_format
-
-
 def fallback_for(op: str, impl: str) -> Optional[str]:
-    """The first registered, contract-compatible rung below ``impl`` —
-    what the README impl matrix's ``fallback`` column shows."""
+    """The first registered rung below ``impl`` — what the README impl
+    matrix's ``fallback`` column shows."""
     try:
-        orig = get(op, impl)
+        get(op, impl)
     except ValueError:
         return None
     for name in fallback_chain(op, impl):
-        entry = _REGISTRY.get((op, name))
-        if entry is not None and _static_compatible(entry, orig):
+        if (op, name) in _REGISTRY:
             return name
     return None
-
-
-def _compatible(entry: OpImpl, orig: OpImpl, args) -> bool:
-    """Can this rung serve the original request's contract and inputs?"""
-    if not _static_compatible(entry, orig):
-        return False
-    if entry.tpu_only:
-        import jax
-
-        if jax.default_backend() != "tpu":
-            return False
-    if entry.needs_canonical and args:
-        from .format import BlockedMEBCRS
-
-        if isinstance(args[0], BlockedMEBCRS):
-            return False
-    return True
 
 
 _sig_cache: Dict[Tuple[str, str], Optional[frozenset]] = {}
@@ -380,13 +344,6 @@ def _adapt_kwargs(entry: OpImpl, kwargs: Dict) -> Dict:
     return kw
 
 
-def _extract_values(out):
-    """(container-or-None, value array) of an impl result."""
-    if hasattr(out, "vals") and hasattr(out, "win_ptr"):
-        return out, out.vals
-    return None, out
-
-
 def _guard_nonfinite(entry: OpImpl, args, kw: Dict, out):
     """Re-run a narrow (bf16/int8) forward at fp32 when it produced
     NaN/Inf (DESIGN.md §15).  The guarded output is returned in fp32 —
@@ -401,33 +358,27 @@ def _guard_nonfinite(entry: OpImpl, args, kw: Dict, out):
         return out
     if "fp32" not in entry.precisions:
         return out
-    container, arr = _extract_values(out)
-    if not jnp.issubdtype(arr.dtype, jnp.floating):
+    if not jnp.issubdtype(out.dtype, jnp.floating):
         return out
     kw32 = _adapt_kwargs(entry, {**kw, "precision": "fp32"})
 
     def rerun():
-        _, a32 = _extract_values(entry.fn(*args, **kw32))
-        return a32.astype(jnp.float32)
+        return entry.fn(*args, **kw32).astype(jnp.float32)
 
-    ok = jnp.all(jnp.isfinite(arr))
-    arr32 = arr.astype(jnp.float32)
+    ok = jnp.all(jnp.isfinite(out))
+    out32 = out.astype(jnp.float32)
     if isinstance(ok, jax.core.Tracer):
-        fixed = jax.lax.cond(ok, lambda: arr32, rerun)
-    elif bool(ok):
-        fixed = arr32
-    else:
-        warnings.warn(FallbackWarning(
-            entry.op, f"{entry.name}[{kw.get('precision')}]",
-            f"{entry.name}[fp32]",
-            [(entry.name, FloatingPointError("non-finite output"))]),
-            stacklevel=3)
-        _count("guard_nonfinite_rerun")
-        _log(entry.op, f"guard:{entry.name}:fp32-rerun")
-        fixed = rerun()
-    if container is not None:
-        return dataclasses.replace(container, vals=fixed, scales=None)
-    return fixed
+        return jax.lax.cond(ok, lambda: out32, rerun)
+    if bool(ok):
+        return out32
+    warnings.warn(FallbackWarning(
+        entry.op, f"{entry.name}[{kw.get('precision')}]",
+        f"{entry.name}[fp32]",
+        [(entry.name, FloatingPointError("non-finite output"))]),
+        stacklevel=3)
+    _count("guard_nonfinite_rerun")
+    _log(entry.op, f"guard:{entry.name}:fp32-rerun")
+    return rerun()
 
 
 def _count(name: str) -> None:
@@ -443,9 +394,8 @@ def robust_dispatch(op: str, impl: str, *args, strict: bool = False,
                     guard_nonfinite: bool = False, **kwargs):
     """Dispatch with graceful degradation down the capability ladder.
 
-    Tries ``impl`` first; on failure walks :func:`fallback_chain`, skipping
-    rungs whose output contract or input requirements differ, adapting
-    kwargs per rung via :func:`_adapt_kwargs`.  A recovery emits ONE
+    Tries ``impl`` first; on failure walks :func:`fallback_chain`,
+    adapting kwargs per rung via :func:`_adapt_kwargs`.  A recovery emits ONE
     structured :class:`FallbackWarning` plus a call-log record
     ``(op, "fallback:<requested>-><used>")``.  ``strict=True`` re-raises
     the requested impl's error instead of degrading.  Structural
@@ -459,13 +409,11 @@ def robust_dispatch(op: str, impl: str, *args, strict: bool = False,
     """
     from .validate import ValidationError
 
-    orig = get(op, impl)
+    get(op, impl)   # an unknown impl raises before any rung runs
     failures: List[Tuple[str, Exception]] = []
     for name in (impl,) + fallback_chain(op, impl):
         entry = _REGISTRY.get((op, name))
         if entry is None:
-            continue
-        if name != impl and not _compatible(entry, orig, args):
             continue
         kw = _adapt_kwargs(entry, kwargs)
         _log(op, name)
@@ -487,7 +435,7 @@ def robust_dispatch(op: str, impl: str, *args, strict: bool = False,
             _count("dispatch_fallback")
         return out
     err = RuntimeError(
-        f"op {op!r}: impl {impl!r} and every compatible fallback rung "
+        f"op {op!r}: impl {impl!r} and every fallback rung "
         f"failed: " + "; ".join(
             f"{n} ({type(e).__name__}: {str(e)[:200]})"
             for n, e in failures))

@@ -239,10 +239,10 @@ def build_schedule(blocked: BlockedMEBCRS, split_blk: int = 1,
     for empty windows (they keep one zero-length store-only segment).
 
     ``split_blk = 0`` disables splitting — one segment per window, the
-    window-parallel work assignment expressed in schedule form (useful as
-    the autotuner's degenerate candidate).  Host-side numpy, like
-    :func:`block_format`.  ``check`` audits both the input blocked view
-    and the built schedule (``None`` → ambient level, DESIGN.md §15).
+    window-parallel work assignment expressed in schedule form.  Host-side
+    numpy, like :func:`block_format`.  ``check`` audits both the input
+    blocked view and the built schedule (``None`` → ambient level,
+    DESIGN.md §15).
     """
     from . import validate as _validate
 
@@ -282,7 +282,7 @@ def build_schedule(blocked: BlockedMEBCRS, split_blk: int = 1,
 def window_skew(fmt) -> float:
     """p99 / mean of the per-window nonzero-vector counts (≥ 1.0).
 
-    The autotuner's bucket statistic (DESIGN.md §11): near 1 for uniform
+    The balanced-vs-plain statistic (DESIGN.md §11): near 1 for uniform
     matrices, large for power-law / hub-row matrices where a handful of
     windows own most K-blocks — the regime where the block-parallel
     schedule beats the window-parallel grid.  Accepts the canonical

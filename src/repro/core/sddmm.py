@@ -73,15 +73,11 @@ def sddmm(fmt, q: jax.Array, k: jax.Array, impl: str = "blocked",
     """SDDMM dispatch through the unified registry → blocked-layout values.
 
     ``impl`` names a registered implementation (``dispatch.impls("sddmm")``:
-    blocked / pallas / pallas_balanced / pallas_tuned / coo).
+    blocked / pallas / pallas_balanced / coo / the sharded impls).
     ``interpret=None`` auto-detects (compile on TPU, interpret elsewhere —
-    resolved in :mod:`repro.kernels.ops`).  ``pallas_tuned`` requires the
-    canonical :class:`MEBCRS` (the autotuner re-blocks per candidate
-    ``k_blk``) and — since the blocked layout depends on the tuned
-    ``k_blk`` — returns the :class:`BlockedMEBCRS` with the scores bound
-    as values instead of a bare value array (registry flag
-    ``returns_format``).  ``split_blk``/``schedule`` parameterize the
-    schedule-driven ``pallas_balanced`` grid (DESIGN.md §11).
+    resolved in :mod:`repro.kernels.ops`).  ``split_blk``/``schedule``
+    parameterize the schedule-driven ``pallas_balanced`` grid (DESIGN.md
+    §11).
 
     ``precision`` selects the mixed-precision path (DESIGN.md §13:
     ``"fp32"``/``"bf16"``; SDDMM has no int8 level) and is
@@ -142,7 +138,7 @@ def attention(fmt, q: jax.Array, k: jax.Array, v: jax.Array,
 
     ``impl`` names a registered implementation
     (``dispatch.impls("attention")``: blocked / pallas_fused_attn /
-    pallas_staged / pallas_balanced / pallas_fused_attn_tuned / ...);
+    pallas_staged / pallas_balanced / the sharded impls);
     ``"blocked"`` is the pure-XLA staged pipeline — the terminal rung of
     the fallback ladder.  The robustness knobs (DESIGN.md §15) mirror
     :func:`repro.core.spmm.spmm`: ``check`` audits ``fmt`` and guards

@@ -13,8 +13,6 @@ Three execution paths:
     zero-init and output cast fused into the epilogue (DESIGN.md §3).
     Validated in interpret mode on CPU; compiles to Mosaic on TPU
     (``interpret=None`` auto-detects).
-  * ``pallas_tuned``: same kernel behind the (k_blk, n_blk) autotuner
-    (kernels/autotune.py) with a persistent on-disk config cache.
   * ``coo_segment``: element-wise scatter-add SpMM — the "CUDA-core class"
     baseline (Sputnik / RoDe / cuSPARSE row algorithms reduce to this data
     flow on TPU); also serves as an independent oracle.
@@ -86,16 +84,14 @@ def spmm(fmt: MEBCRS, b: jax.Array, impl: str = "blocked", k_blk: int = 8,
     """SpMM dispatch through the unified registry (:mod:`repro.core.dispatch`).
 
     ``impl`` names a registered implementation (``dispatch.impls("spmm")``
-    lists them: blocked / pallas / pallas_balanced / pallas_tuned /
-    pallas_staged / pallas_noncoalesced / coo_segment).  ``interpret=None``
-    auto-detects: the Pallas paths compile to Mosaic on a TPU backend and
-    fall back to interpret mode elsewhere (resolved in
+    lists them: blocked / pallas / pallas_balanced / pallas_staged /
+    pallas_noncoalesced / coo_segment / the sharded impls).
+    ``interpret=None`` auto-detects: the Pallas paths compile to Mosaic on
+    a TPU backend and fall back to interpret mode elsewhere (resolved in
     :mod:`repro.kernels.ops`); pass ``True``/``False`` to force a mode.
-    ``pallas_tuned`` sweeps/caches ``(k_blk, n_blk, split_blk)`` via the
-    autotuner and requires the canonical :class:`MEBCRS` (it re-blocks per
-    candidate); an explicit ``n_blk`` overrides the column tile of the
-    non-tuned Pallas paths.  ``split_blk``/``schedule`` parameterize the
-    block-parallel ``pallas_balanced`` grid (DESIGN.md §11).
+    An explicit ``n_blk`` overrides the column tile of the Pallas paths.
+    ``split_blk``/``schedule`` parameterize the block-parallel
+    ``pallas_balanced`` grid (DESIGN.md §11).
     ``precision`` selects the mixed-precision path (DESIGN.md §13:
     ``"fp32"``/``"bf16"``/``"int8"``; ``None`` = operand dtypes as given)
     and is capability-checked against the impl's registry entry.

@@ -1,7 +1,7 @@
 """Pallas TPU kernels for FlashSparse SpMM / SDDMM — single-head and
 batched (H, ...) grids — plus the single-pass fused sparse-attention
-megakernel (+ jnp oracles, (k_blk, n_blk) autotuner)."""
+megakernel (+ jnp oracles)."""
 
-from . import autotune, ops, ref
+from . import ops, ref
 
-__all__ = ["autotune", "ops", "ref"]
+__all__ = ["ops", "ref"]

@@ -17,7 +17,7 @@ The adjacency arrives either as
     the differentiable path: every sparse op runs through the custom_vjp
     wrappers, so ``jax.grad`` of the loss executes the dispatched kernels
     backward too (transpose-SpMM on the cached Aᵀ, masked SDDMM), for any
-    registry impl including ``pallas``/``pallas_tuned``; or
+    registry impl including ``pallas``/``pallas_balanced``; or
   * a bare :class:`BlockedMEBCRS` — forward-only convenience: ops dispatch
     through the registry directly; training still works for the natively
     differentiable XLA ``blocked`` impl (plain tracing), which is the
@@ -63,7 +63,7 @@ class GNNConfig:
     num_classes: int = 16
     num_layers: int = 5             # paper: 5-layer GCN
     impl: str = "blocked"           # any registry impl: "blocked" | "pallas"
-                                    # | "pallas_tuned" | "pallas_sharded" ...
+                                    # | "pallas_balanced" | ...
     interpret: Any = None           # None = auto (compile on TPU)
     dtype: Any = jnp.float32
 

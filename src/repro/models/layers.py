@@ -230,8 +230,8 @@ def sparse_attention_staged(pattern, q: jax.Array, k: jax.Array,
 
     impl = impl or "blocked"
     if impl != "blocked":
-        # Pallas impls differentiate (and pallas_tuned re-blocks) only via
-        # the plan; fail here with the remedy, not inside grad tracing.
+        # Pallas impls differentiate only via the plan; fail here with
+        # the remedy, not inside grad tracing.
         raise ValueError(
             f"sparse_attention with a bare BlockedMEBCRS supports only "
             f"impl='blocked'; build an ADPlan (ad_plan(fmt, impl={impl!r})) "

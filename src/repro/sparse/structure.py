@@ -17,13 +17,11 @@ This module turns that observation into a small, deterministic taxonomy:
                               :data:`STRUCTURE_CLASSES` via documented
                               threshold rules
   :func:`classify_format`     the same, from an ME-BCRS format
-                              (memoized on the instance — the autotuner
-                              calls it per stats-key lookup)
+                              (memoized on the instance)
 
-The class feeds two consumers: the autotuner's stats-bucket key (cache
-schema v6 — matrices of different classes never share a tuned winner)
-and the ``--datasets`` benchmark records, which report the winning impl
-*per class* so the BENCH artifacts map the taxonomy onto impl choice.
+The class feeds the ``--datasets`` benchmark records, which report the
+winning impl *per class* so the BENCH artifacts map the taxonomy onto
+impl choice.
 
 All features are plain host-side numpy over the COO triplets — this is
 format-translation-time work, like :func:`repro.core.format.from_coo`.
@@ -88,7 +86,7 @@ def structure_stats(rows, cols, shape: Tuple[int, int],
       window_skew         p99/mean of nonzero-*vector* counts per
                           ``vector_size``-row window (≥ 1.0), the same
                           statistic :func:`repro.core.format.window_skew`
-                          computes on a built format — the autotuner's
+                          computes on a built format — the
                           balanced-vs-plain axis
       bandwidth           p95 of |i − j| in elements
       bandwidth_ratio     the same normalized by max(m, k): 0 for a
@@ -120,9 +118,9 @@ def structure_stats(rows, cols, shape: Tuple[int, int],
     mean_len = row_len.mean()
     stats["row_cv"] = float(row_len.std() / mean_len) if mean_len > 0 else 0.0
 
-    # nonzero vectors per window — the statistic that keys balanced-vs-
-    # plain in the autotuner; computed straight from COO so callers can
-    # classify before paying format translation
+    # nonzero vectors per window — the balanced-vs-plain statistic;
+    # computed straight from COO so callers can classify before paying
+    # format translation
     win = rows // vector_size
     uniq_vec = np.unique(win * k + cols)
     w = -(-m // vector_size)
@@ -177,8 +175,7 @@ def classify_structure(stats: Dict[str, float]) -> str:
 def classify_format(fmt) -> str:
     """Structure class of an ME-BCRS / blocked format (instance-memoized).
 
-    The autotuner calls this inside every ``matrix_stats_key`` build, so
-    the O(nnz) feature pass is paid once per format instance — the same
+    The O(nnz) feature pass is paid once per format instance — the same
     memoization contract as :meth:`repro.core.format.MEBCRS.transpose`.
     Requires concrete (non-tracer) arrays, like all host-side format
     work.

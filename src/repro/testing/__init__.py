@@ -1,10 +1,10 @@
 """Fault-injection tooling for the hardened sparse runtime (DESIGN.md §15).
 
-:mod:`repro.testing.faults` corrupts formats, caches, and kernel configs
+:mod:`repro.testing.faults` corrupts formats, inputs and kernel configs
 on purpose and asserts the runtime either *names the violated invariant*
 (:class:`repro.core.validate.ValidationError`) or *recovers* — falls back
-down the capability ladder to the oracle answer, salvages the cache, or
-counts the event.  Importable from tests and runnable as a CLI for CI::
+down the capability ladder to the oracle answer — or counts the event.
+Importable from tests and runnable as a CLI for CI::
 
     python -m repro.testing.faults --op spmm --impl blocked --strict
 
@@ -29,7 +29,6 @@ from .faults import (
     FAULTS,
     FaultNotDetected,
     corrupt_blocked,
-    corrupt_cache_file,
     run_fault,
     run_fault_suite,
 )
@@ -41,7 +40,6 @@ __all__ = [
     "FaultNotDetected",
     "conformance_self_test",
     "corrupt_blocked",
-    "corrupt_cache_file",
     "enumerate_cases",
     "format_report",
     "run_conformance",

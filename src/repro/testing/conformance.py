@@ -16,11 +16,9 @@ the synthetic generators the unit tests use.  For each loaded
   3. reports a structured pass/fail matrix (:class:`ConformanceRecord`
      rows; :func:`summarize` / :func:`format_report` for humans).
 
-Output contracts are normalized per impl flags: blocked-layout SDDMM
-values are scattered back through the format, ``returns_format`` impls
-(tuned SDDMM) are read via ``to_coo``, the edge-value ``coo`` impl is
-compared in ``to_coo`` order, and natively-batched ``*_batched`` impls
-are fed H=2 stacked operands against a stacked oracle.
+Output contracts are normalized per impl: blocked-layout SDDMM values
+are scattered back through the format, and the edge-value ``coo`` impl
+is compared in ``to_coo`` order.
 
 :func:`self_test` proves the harness can actually catch a wrong kernel:
 it registers a deliberately broken impl and raises
@@ -65,16 +63,15 @@ OPS = ("spmm", "sddmm", "attention")
 # Feature dims for the dense operands (small: the matrices carry the
 # structure, the operands only need to be wide enough to exercise tiling).
 N_FEAT = 16
-BATCH_H = 2
 
 
 @dataclasses.dataclass(frozen=True)
 class ConformanceCase:
     """One (op, impl, precision, variant) combination to execute.
 
-    ``variant``: ``"base"`` (plain call; ``*_batched`` impls get H=2
-    stacked operands), ``"split"`` (``split_blk=2`` on load-balanced
-    impls), ``"overlap"`` (``n_batches=2`` on overlapped impls).
+    ``variant``: ``"base"`` (plain call), ``"split"`` (``split_blk=2``
+    on load-balanced impls), ``"overlap"`` (``n_batches=2`` on overlapped
+    impls).
     Variants run at fp32 only — precision expansion happens on the base
     variant, variants probe scheduling/communication paths.
     """
@@ -195,8 +192,6 @@ def run_case(case: ConformanceCase, sample, operands) -> ConformanceRecord:
     """Execute one case on one sample; never raises (failures become
     ``status="fail"`` records — the CI contract is *zero unexplained
     failures*, so an exception is an explained failure, not a crash)."""
-    import jax.numpy as jnp
-
     from repro.core.format import to_coo
     from repro.core.sddmm import attention, sddmm
     from repro.core.spmm import spmm
@@ -211,17 +206,11 @@ def run_case(case: ConformanceCase, sample, operands) -> ConformanceRecord:
     entry = _dispatch.get(case.op, case.impl)
     if case.op == "attention" and not sample.is_square:
         return rec("skip", detail="attention needs a square pattern")
-    if entry.tpu_only:
-        import jax
-
-        if jax.default_backend() != "tpu":
-            return rec("skip", detail="tpu_only impl off-TPU")
 
     fmt = operands["fmt"]
     dense = operands["dense"]
     mask = operands["mask"]
     q, k, v, b = (operands[x] for x in ("q", "k", "v", "b"))
-    batched = entry.batched and case.impl.endswith("_batched")
 
     kw: Dict[str, object] = {"impl": case.impl}
     if case.precision != "fp32":
@@ -235,43 +224,22 @@ def run_case(case: ConformanceCase, sample, operands) -> ConformanceRecord:
 
     try:
         if case.op == "spmm":
-            rhs = jnp.stack([b, 2.0 * b]) if batched else b
-            out = np.asarray(spmm(fmt, rhs, **kw), np.float32)
-            ref = (np.stack([dense @ np.asarray(r) for r in rhs])
-                   if batched else dense @ np.asarray(b))
+            out = np.asarray(spmm(fmt, b, **kw), np.float32)
+            ref = dense @ np.asarray(b)
         elif case.op == "sddmm":
             dense_scores = (np.asarray(q) @ np.asarray(k).T) * mask
             if case.impl == "coo":  # edge values in to_coo(fmt) order
                 rows, cols, _ = to_coo(fmt)
                 out = np.asarray(sddmm(fmt, q, k, **kw), np.float32)
                 ref = dense_scores[rows, cols]
-            elif batched:
-                q3, k3 = jnp.stack([q, 2.0 * q]), jnp.stack([k, k])
-                raw = np.asarray(sddmm(fmt, q3, k3, **kw), np.float32)
+            else:  # blocked-layout (NNZP, V) for the entry's k_blk=8
                 from repro.core.format import block_format
 
+                raw = sddmm(fmt, q, k, **kw)
                 blocked = operands.setdefault(
                     "blocked", block_format(fmt, k_blk=8))
-                out = np.stack([_scatter_blocked(blocked, raw[h],
-                                                 sample.shape)
-                                for h in range(raw.shape[0])])
-                ref = np.stack([
-                    (np.asarray(q3[h]) @ np.asarray(k3[h]).T) * mask
-                    for h in range(raw.shape[0])])
-            else:
-                raw = sddmm(fmt, q, k, **kw)
-                if entry.returns_format:  # tuned: BlockedMEBCRS out
-                    rows, cols, vals = to_coo(raw)
-                    out = np.zeros(sample.shape, np.float32)
-                    out[rows, cols] = vals
-                else:  # blocked-layout (NNZP, V) for the entry's k_blk=8
-                    from repro.core.format import block_format
-
-                    blocked = operands.setdefault(
-                        "blocked", block_format(fmt, k_blk=8))
-                    out = _scatter_blocked(blocked,
-                                           np.asarray(raw, np.float32),
-                                           sample.shape)
+                out = _scatter_blocked(blocked, np.asarray(raw, np.float32),
+                                       sample.shape)
                 ref = dense_scores
         else:  # attention
             scale = 1.0 / np.sqrt(N_FEAT)

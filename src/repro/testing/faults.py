@@ -3,16 +3,15 @@
 Every corruption class below maps to one concrete failure a deployed
 sparse runtime meets — a bad converter writing out-of-bounds columns, a
 checkpoint truncating a leaf, NaNs leaking in from a diverged training
-run, a stale or torn autotune cache, an int8 scale that saturates, a tile
-config the kernel cannot launch.  For each class the harness asserts the
+run, an int8 scale that saturates, a tile config the kernel cannot
+launch.  For each class the harness asserts the
 hardened runtime (DESIGN.md §15) does exactly one of:
 
 * **raise** — ``check="full"`` validation rejects the object with a
   :class:`~repro.core.validate.ValidationError` naming the violated
   invariant (never a shape error from deep inside a kernel);
 * **recover** — the op degrades down the fallback ladder
-  (``strict=False``) and still matches the dense oracle, or the cache
-  layer salvages/rebuilds and later lookups behave;
+  (``strict=False``) and still matches the dense oracle;
 * **count** — the event is absorbed by design (int8 saturation clips)
   and surfaces in :func:`repro.core.metrics.counters`.
 
@@ -25,9 +24,6 @@ for CI::
 from __future__ import annotations
 
 import dataclasses
-import json
-import os
-import tempfile
 import warnings
 from typing import Dict, List, Optional, Tuple
 
@@ -48,7 +44,6 @@ __all__ = [
     "FAULTS",
     "FaultNotDetected",
     "corrupt_blocked",
-    "corrupt_cache_file",
     "run_fault",
     "run_fault_suite",
 ]
@@ -69,8 +64,6 @@ FAULTS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "oversized_block_config": ("config", ("block-config",)),
     "kernel_launch_failure": ("runtime", ()),
     "int8_saturation": ("counter", ()),
-    "stale_cache_schema": ("cache", ()),
-    "torn_cache_json": ("cache", ()),
 }
 
 
@@ -118,34 +111,6 @@ def corrupt_blocked(blocked, fault: str):
         return dataclasses.replace(
             blocked, win_ptr=jnp.asarray(wptr, jnp.float32))
     raise KeyError(f"not a format-level fault: {fault!r}")
-
-
-def corrupt_cache_file(path: str, fault: str) -> None:
-    """Write a corrupted autotune-cache file for ``fault`` at ``path``."""
-    from repro.kernels.autotune import SCHEMA_VERSION, TuneConfig
-
-    healthy = {
-        "schema": SCHEMA_VERSION,
-        "configs": {
-            "spmm|seed-entry|k8|nb128|s0|pfp32|o0":
-                TuneConfig(8, 128, 1.0).to_json(),
-            "spmm|other-entry|k8|nb64|s0|pfp32|o0":
-                TuneConfig(8, 64, 2.0).to_json(),
-        },
-    }
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    if fault == "stale_cache_schema":
-        healthy["schema"] = 1
-        with open(path, "w") as f:
-            json.dump(healthy, f, indent=2)
-        return
-    if fault == "torn_cache_json":
-        text = json.dumps({"schema": healthy["schema"],
-                           "configs": healthy["configs"]}, indent=2)
-        with open(path, "w") as f:
-            f.write(text[: int(len(text) * 0.7)])   # torn mid-entry
-        return
-    raise KeyError(f"not a cache-level fault: {fault!r}")
 
 
 def _call_op(op: str, impl: str, fmt, b, q, k, v, **kw):
@@ -300,30 +265,6 @@ def run_fault(fault: str, *, op: str = "spmm", impl: str = "blocked",
             raise FaultNotDetected(f"{fault}: quantize overflowed int8")
         del sc
         return _record(fault, op, impl, "counter", f"int8_clip={n_clip}")
-
-    if kind == "cache":
-        from repro.kernels.autotune import AutotuneCache, TuneConfig
-
-        with tempfile.TemporaryDirectory() as d:
-            path = os.path.join(d, "cache.json")
-            corrupt_cache_file(path, fault)
-            cache = AutotuneCache(path)
-            data = dict(cache._load())   # must not raise; snapshot (put
-                                         # below mutates the live dict)
-            if fault == "stale_cache_schema" and data:
-                raise FaultNotDetected(
-                    f"{fault}: stale-schema entries satisfied a lookup")
-            if fault == "torn_cache_json" and not data:
-                raise FaultNotDetected(
-                    f"{fault}: salvage recovered no entry from a file "
-                    f"torn past the first config")
-            # the cache must heal: a put round-trips through the salvage
-            cache.put("heal|k8|nb128|s0|pfp32|o0", TuneConfig(8, 128, 0.5))
-            reread = AutotuneCache(path)
-            if reread.get("heal|k8|nb128|s0|pfp32|o0") is None:
-                raise FaultNotDetected(f"{fault}: cache did not heal")
-            return _record(fault, op, impl, "recover",
-                           f"salvaged={len(data)}")
 
     raise KeyError(f"unknown fault {fault!r}")
 

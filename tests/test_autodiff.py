@@ -17,9 +17,8 @@ from _hypothesis_compat import given, settings, st  # degrades to skips
 from repro.core import dispatch, from_dense
 from repro.core.autodiff import ad_plan, sddmm_ad, spmm_ad
 from repro.core.format import BlockedMEBCRS
-from repro.kernels.autotune import AutotuneCache
 
-IMPLS = ["blocked", "pallas"]  # pallas_tuned covered separately (tuner sweep)
+IMPLS = ["blocked", "pallas"]
 
 
 def random_sparse(rng, m, k, density, empty_window=False):
@@ -115,30 +114,6 @@ def test_pallas_backward_runs_fused_kernels_not_dense():
     assert log.count(("spmm", "pallas")) == 2, log
     assert ("sddmm", "pallas") in log, log
     assert all(impl.startswith("pallas") for _, impl in log), log
-
-
-def test_pallas_tuned_plan_trains_and_logs_fused(tmp_path):
-    """pallas_tuned resolves the tuner at plan build; traced fwd+bwd run
-    the plain fused kernels with the tuned tiles."""
-    rng = np.random.default_rng(3)
-    a = random_sparse(rng, 32, 32, 0.3)
-    fmt = from_dense(a, vector_size=8)
-    cache = AutotuneCache(str(tmp_path / "tune.json"))
-    plan = ad_plan(fmt, impl="pallas_tuned", n_example=8, interpret=True,
-                   cache=cache)
-    assert ad_plan(fmt, impl="pallas_tuned", n_example=8, interpret=True,
-                   cache=cache) is plan  # memoized on the format instance
-    b = jnp.asarray(rng.standard_normal((32, 8)).astype(np.float32))
-    with dispatch.record_calls() as log:
-        gv, gb = jax.grad(
-            lambda v, bb: spmm_ad(plan, v, bb, interpret=True).sum(),
-            argnums=(0, 1))(plan.vals, b)
-    # the sweep picks window-parallel or balanced per direction (timing);
-    # either way every dispatch must be a fused Pallas kernel
-    assert all(impl in ("pallas", "pallas_balanced") for op, impl in log), log
-    np.testing.assert_allclose(
-        np.asarray(gb), a.T @ np.ones((32, 8), np.float32),
-        rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("impl", IMPLS)

@@ -93,7 +93,7 @@ def test_spmm_ad_batched_one_launch_fwd_and_grad(h):
 
     with dispatch.record_calls() as log:
         out = spmm_ad(plan, plan.vals, b3, interpret=True)
-    assert log == [("spmm", "pallas_batched")], log
+    assert log == [("spmm", "pallas")], log
 
     ref = jnp.stack([spmm_ad(plan, plan.vals, b3[i], interpret=True)
                      for i in range(h)])
@@ -103,8 +103,8 @@ def test_spmm_ad_batched_one_launch_fwd_and_grad(h):
         gb = jax.grad(lambda x: spmm_ad(plan, plan.vals, x,
                                         interpret=True).sum())(b3)
     # fwd spmm + bwd transpose-spmm + bwd sddmm: one batched launch each
-    assert log.count(("spmm", "pallas_batched")) == 2, log
-    assert log.count(("sddmm", "pallas_batched")) == 1, log
+    assert log.count(("spmm", "pallas")) == 2, log
+    assert log.count(("sddmm", "pallas")) == 1, log
     assert len(log) == 3, log
 
     gb_ref = jnp.stack([jax.grad(lambda x: spmm_ad(
@@ -122,7 +122,7 @@ def test_sddmm_ad_batched_one_launch_fwd_and_grad(h):
 
     with dispatch.record_calls() as log:
         out = sddmm_ad(plan, q3, k3, interpret=True)
-    assert log == [("sddmm", "pallas_batched")], log
+    assert log == [("sddmm", "pallas")], log
     ref = jnp.stack([sddmm_ad(plan, q3[i], k3[i], interpret=True)
                      for i in range(h)])
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
@@ -131,8 +131,8 @@ def test_sddmm_ad_batched_one_launch_fwd_and_grad(h):
         gq, gk = jax.grad(lambda qq, kk: sddmm_ad(
             plan, qq, kk, interpret=True).sum(), argnums=(0, 1))(q3, k3)
     # fwd sddmm + bwd dQ spmm + bwd dK transpose-spmm
-    assert log.count(("sddmm", "pallas_batched")) == 1, log
-    assert log.count(("spmm", "pallas_batched")) == 2, log
+    assert log.count(("sddmm", "pallas")) == 1, log
+    assert log.count(("spmm", "pallas")) == 2, log
     assert len(log) == 3, log
 
     g_ref = [jax.grad(lambda qq, kk: sddmm_ad(
@@ -162,9 +162,9 @@ def test_shared_operand_grad_matches_per_slice_sum():
 
 
 def test_batched_registry_flags():
-    assert dispatch.get("spmm", "pallas_batched").batched
-    assert dispatch.get("spmm", "pallas_batched").differentiable
-    assert dispatch.get("sddmm", "pallas_batched").batched
+    assert dispatch.get("spmm", "pallas").batched
+    assert dispatch.get("spmm", "pallas").differentiable
+    assert dispatch.get("sddmm", "pallas").batched
     assert dispatch.get("attention", "pallas_fused_attn").batched
     assert dispatch.get("attention", "pallas_fused_attn").differentiable
     assert not dispatch.get("attention", "pallas_staged").differentiable

@@ -44,15 +44,9 @@ def sample():
 
 
 @pytest.fixture(scope="module")
-def operands(sample, tmp_path_factory):
-    import os
-
+def operands(sample):
     from repro.testing.conformance import _operands_for
 
-    # tuned impls sweep through the autotune cache — isolate it so the
-    # suite never writes the user's real cache file
-    os.environ["REPRO_AUTOTUNE_CACHE"] = str(
-        tmp_path_factory.mktemp("autotune") / "cache.json")
     return _operands_for(sample, np.random.default_rng(0))
 
 

@@ -364,19 +364,12 @@ def test_classify_format_memoized():
 
 
 def test_stats_key_has_structure_class_bucket():
-    """Autotune cache schema v6: same coarse buckets, different structure
-    class → different tuning bucket."""
-    from repro.kernels.autotune import SCHEMA_VERSION, matrix_stats_key
-
-    assert SCHEMA_VERSION == 6
+    """``classify_format`` puts a banded and a uniform vendored matrix in
+    their own classes (the buckets ``class_winners`` reports by)."""
     samples = {s.name: s for s in load_vendored(["tridiag_64",
                                                  "uniform_80"])}
-    key_banded = matrix_stats_key(samples["tridiag_64"].to_format(), 64,
-                                  "spmm", interpret=True)
-    key_uniform = matrix_stats_key(samples["uniform_80"].to_format(), 64,
-                                   "spmm", interpret=True)
-    assert "clsbanded" in key_banded
-    assert "clsuniform" in key_uniform
+    assert classify_format(samples["tridiag_64"].to_format()) == "banded"
+    assert classify_format(samples["uniform_80"].to_format()) == "uniform"
 
 
 def test_matrix_sample_helpers():

@@ -1,6 +1,8 @@
 """Unified (op, impl) dispatch registry: resolution, flags, call log,
 and the sparse-op sharding helpers."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -20,10 +22,16 @@ def make_fmt(seed=0, m=40, k=36, density=0.25):
 def test_every_layer_resolves_the_same_table():
     """core.spmm/core.sddmm are thin shims over the registry: the impl
     lists match and unknown impls fail with the available set."""
-    assert {"blocked", "pallas", "pallas_tuned", "pallas_staged",
-            "pallas_noncoalesced", "coo_segment"} <= set(dispatch.impls("spmm"))
-    assert {"blocked", "pallas", "pallas_tuned", "coo"} <= \
-        set(dispatch.impls("sddmm"))
+    assert set(dispatch.impls("spmm")) == {
+        "blocked", "coo_segment", "pallas", "pallas_balanced",
+        "pallas_noncoalesced", "pallas_sharded", "pallas_sharded_overlap",
+        "pallas_staged"}
+    assert set(dispatch.impls("sddmm")) == {
+        "blocked", "coo", "pallas", "pallas_balanced", "pallas_sharded",
+        "pallas_sharded_overlap"}
+    assert set(dispatch.impls("attention")) == {
+        "blocked", "pallas_balanced", "pallas_fused_attn",
+        "pallas_sharded", "pallas_sharded_overlap", "pallas_staged"}
     with pytest.raises(ValueError, match="unknown impl .* available"):
         dispatch.get("spmm", "nope")
 
@@ -32,18 +40,20 @@ def test_capability_flags():
     assert dispatch.get("spmm", "blocked").differentiable
     assert dispatch.get("spmm", "blocked").batched
     assert dispatch.get("spmm", "pallas").differentiable
-    assert not dispatch.get("spmm", "pallas").batched  # per-slice loop path
-    assert dispatch.get("spmm", "pallas_tuned").needs_canonical
+    assert dispatch.get("spmm", "pallas").batched  # native (H, ...) grid
+    assert dispatch.get("sddmm", "pallas").batched
     assert not dispatch.get("spmm", "pallas_staged").differentiable
-    assert dispatch.get("sddmm", "pallas_tuned").returns_format
+    assert not dispatch.get("spmm", "pallas_staged").batched
+    assert [f.name for f in dataclasses.fields(dispatch.OpImpl)] == [
+        "op", "name", "fn", "differentiable", "batched", "load_balanced",
+        "multi_device", "overlapped", "precisions"]
     with pytest.raises(ValueError, match="not differentiable"):
         dispatch.require("spmm", "pallas_staged", differentiable=True)
     with pytest.raises(ValueError, match="no native batched"):
-        dispatch.require("spmm", "pallas", batched=True)
+        dispatch.require("spmm", "pallas_staged", batched=True)
 
 
-def test_all_spmm_impls_agree(tmp_path, monkeypatch):
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "t.json"))
+def test_all_spmm_impls_agree():
     a, fmt = make_fmt()
     b = jnp.asarray(np.random.default_rng(1).standard_normal(
         (36, 16)).astype(np.float32))

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 
 from repro.core import block_format, from_dense, spmm_blocked, sddmm_blocked
 from repro.kernels import ops
-from repro.kernels.autotune import AutotuneCache, tune_spmm
 
 
 def random_sparse(rng, m, k, density):
@@ -177,99 +176,6 @@ def test_win_ptr_all_empty_excludes_dummy_block():
     blocked = make_blocked(np.zeros((16, 16), np.float32))
     assert blocked.num_blocks == 1  # the dummy block keeps arrays non-empty
     assert int(np.asarray(blocked.win_ptr)[-1]) == 0  # ...but no window owns it
-
-
-# ------------------------------------------------------------- autotuner ----
-
-
-def test_autotune_cache_roundtrip(tmp_path):
-    rng = np.random.default_rng(10)
-    a = random_sparse(rng, 48, 48, 0.2)
-    fmt = from_dense(a, vector_size=8)
-    b = jnp.asarray(rng.standard_normal((48, 64)), dtype=jnp.float32)
-    cache = AutotuneCache(str(tmp_path / "tune.json"))
-    cfg = tune_spmm(fmt, b, k_blks=(8, 16), n_blks=(64,), interpret=True,
-                    reps=1, cache=cache)
-    assert cfg.k_blk in (8, 16) and cfg.n_blk == 64
-    # fresh cache object, same file → disk hit, no re-sweep
-    cfg2 = tune_spmm(fmt, b, k_blks=(8, 16), n_blks=(64,), interpret=True,
-                     reps=1, cache=AutotuneCache(str(tmp_path / "tune.json")))
-    assert cfg2 == cfg
-
-
-def test_autotune_v3_cache_discarded_with_one_warning(tmp_path, caplog):
-    """Schema-v4 migration: a v3 cache file (configs without ``precision``,
-    keys without the ``|p`` suffix) is discarded wholesale — its winners
-    must not satisfy v4 lookups — and the stale-schema warning fires once
-    per cache object, not once per lookup."""
-    import json
-    import logging
-
-    from repro.kernels.autotune import SCHEMA_VERSION, TuneConfig
-
-    path = tmp_path / "tune.json"
-    path.write_text(json.dumps({
-        "schema": 3,
-        "configs": {"spmm|v8|w3|vec2|sk1|n7|dtfloat32|b1|cpu|interp"
-                    "|k8,16|nb64|s0,1":
-                    {"k_blk": 16, "n_blk": 64, "median_ms": 0.1,
-                     "split_blk": 1}},
-    }))
-    cache = AutotuneCache(str(path))
-    with caplog.at_level(logging.WARNING, logger="repro.kernels.autotune"):
-        for _ in range(5):  # repeated lookups → memoized load, one warning
-            assert cache.get("anything") is None
-    stale = [r for r in caplog.records if "discarding autotune cache" in
-             r.getMessage()]
-    assert len(stale) == 1
-    assert "schema 3" in stale[0].getMessage()
-
-    # re-tuning through the stale file writes a clean v4 cache
-    rng = np.random.default_rng(13)
-    a = random_sparse(rng, 48, 48, 0.2)
-    fmt = from_dense(a, vector_size=8)
-    b = jnp.asarray(rng.standard_normal((48, 64)), dtype=jnp.float32)
-    cfg = tune_spmm(fmt, b, k_blks=(8,), n_blks=(64,), interpret=True,
-                    reps=1, cache=cache, precisions=("fp32", "bf16"))
-    assert cfg.precision in ("fp32", "bf16")
-    raw = json.loads(path.read_text())
-    assert raw["schema"] == SCHEMA_VERSION
-    (key,) = raw["configs"].keys()
-    assert "|pbf16,fp32" in key  # sorted precision-candidate suffix
-    assert TuneConfig.from_json(next(iter(raw["configs"].values()))) == cfg
-
-    # fresh cache object on the v4 file: disk hit, no warning, no re-sweep
-    caplog.clear()
-    cache2 = AutotuneCache(str(path))
-    with caplog.at_level(logging.WARNING, logger="repro.kernels.autotune"):
-        cfg2 = tune_spmm(fmt, b, k_blks=(8,), n_blks=(64,), interpret=True,
-                         reps=1, cache=cache2,
-                         precisions=("fp32", "bf16"))
-    assert cfg2 == cfg
-    assert not [r for r in caplog.records
-                if "discarding autotune cache" in r.getMessage()]
-
-
-def test_legacy_v1_layout_discarded(tmp_path):
-    """The schema-less v1 dict layout reads as empty, not as an error."""
-    import json
-
-    path = tmp_path / "tune.json"
-    path.write_text(json.dumps({"some|old|key": {"k_blk": 8, "n_blk": 64,
-                                                 "median_ms": 1.0}}))
-    assert AutotuneCache(str(path)).get("some|old|key") is None
-
-
-def test_tuned_spmm_matches_oracle(tmp_path):
-    rng = np.random.default_rng(11)
-    a = random_sparse(rng, 48, 48, 0.2)
-    fmt = from_dense(a, vector_size=8)
-    b = jnp.asarray(rng.standard_normal((48, 32)), dtype=jnp.float32)
-    cache = AutotuneCache(str(tmp_path / "tune.json"))
-    out = ops.spmm_tuned(fmt, b, interpret=True, cache=cache,
-                         k_blks=(8,), n_blks=(32, 64))
-    np.testing.assert_allclose(np.asarray(out), a @ np.asarray(b),
-                               rtol=2e-4, atol=2e-4)
 
 
 # ----------------------------------------------------------- HBM model ----

@@ -315,24 +315,6 @@ def test_dispatch_precision_gate():
         spmm(blocked, b, impl="coo_segment", precision="bf16")
 
 
-def test_tuned_precision_pins_level(tmp_path):
-    """spmm_tuned(precision=...) sweeps only that level and runs it."""
-    from repro.core import from_coo
-    from repro.kernels.autotune import AutotuneCache
-
-    rng = np.random.default_rng(9)
-    a = random_sparse(rng, 48, 48, 0.15)
-    fmt = from_dense(a, vector_size=8)
-    b = jnp.asarray(rng.standard_normal((48, 64)), jnp.float32)
-    cache = AutotuneCache(str(tmp_path / "tune.json"))
-    out = ops.spmm_tuned(fmt, b, interpret=True, k_blks=(8,), n_blks=(64,),
-                         cache=cache, precision="bf16")
-    assert out.dtype == jnp.bfloat16
-    base = np.asarray(ops.spmm(block_format(fmt, 8), b, interpret=True))
-    np.testing.assert_allclose(np.asarray(out, np.float32), base,
-                               rtol=2e-2, atol=2e-2 * np.abs(base).max())
-
-
 # ------------------------------------------------------------- sharded ----
 
 

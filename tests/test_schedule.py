@@ -1,6 +1,6 @@
 """Block-parallel scheduling (DESIGN.md §11): Schedule invariants, bitwise
 balanced-vs-window kernel parity (fwd + grad, batched, edge cases), the
-skew-aware autotuner, and the all-empty zero-block path."""
+window-skew statistic, and the all-empty zero-block path."""
 
 import numpy as np
 import pytest
@@ -21,13 +21,6 @@ from repro.core import (
     window_skew,
 )
 from repro.kernels import ops
-from repro.kernels.autotune import (
-    SCHEMA_VERSION,
-    AutotuneCache,
-    TuneConfig,
-    matrix_stats_key,
-    tune_spmm,
-)
 
 SPLITS = (1, 2, 8)
 
@@ -352,38 +345,14 @@ def test_attention_ad_balanced_fwd_and_grads(h):
     assert bwd and all(impl == "pallas_balanced" for _, impl in bwd), log2
 
 
-# ------------------------------------------------------------ autotuner ----
-
-
-def test_tuneconfig_roundtrip_and_stale_schema_discard(tmp_path):
-    import json
-
-    path = str(tmp_path / "tune.json")
-    # a v2-era file (no split_blk/precision, old schema tag) must be
-    # discarded wholesale — its buckets no longer mean the same thing
-    with open(path, "w") as f:
-        json.dump({"schema": 2, "configs": {"stale": {
-            "k_blk": 8, "n_blk": 64, "median_ms": 1.0}}}, f)
-    cache = AutotuneCache(path)
-    assert cache.get("stale") is None
-    assert SCHEMA_VERSION == 6
-
-    cfg = TuneConfig(k_blk=8, n_blk=64, median_ms=0.5, split_blk=2,
-                     precision="bf16", overlap_batches=2)
-    cache.put("k", cfg)
-    assert AutotuneCache(path).get("k") == cfg
-    with open(path) as f:
-        raw = json.load(f)
-    assert raw["schema"] == 6
-    assert raw["configs"]["k"]["split_blk"] == 2
-    assert raw["configs"]["k"]["precision"] == "bf16"
-    assert raw["configs"]["k"]["overlap_batches"] == 2
+# ---------------------------------------------------------- window skew ----
 
 
 def test_stats_key_has_skew_bucket():
-    """Hub-row and uniform matrices of the same size/density land in
-    different tuning buckets (exercised through the synthetic sparse
-    generators the skewed benchmarks are built on)."""
+    """``window_skew`` tells hub-row from uniform matrices of the same
+    size/density, on the canonical and the blocked format alike
+    (exercised through the synthetic sparse generators the skewed
+    benchmarks are built on)."""
     from repro.data.synthetic import (
         synthetic_sparse_coo,
         synthetic_sparse_format,
@@ -392,32 +361,15 @@ def test_stats_key_has_skew_bucket():
     uniform = synthetic_sparse_format(512, 8.0, kind="uniform", seed=0)
     skewed = synthetic_sparse_format(512, 8.0, kind="hub_row", skew=2.0,
                                      seed=0)
+    assert window_skew(uniform) >= 1.0
     assert window_skew(skewed) > 2 * window_skew(uniform)
-    ku = matrix_stats_key(uniform, 64, "spmm", interpret=True)
-    ks = matrix_stats_key(skewed, 64, "spmm", interpret=True)
-    assert "sk" in ku
-    assert ku.split("|") != ks.split("|"), "skewed and uniform matrices " \
-        "must not share a tuning bucket"
+    assert window_skew(block_format(skewed, 8)) > \
+        2 * window_skew(block_format(uniform, 8))
     # deterministic regeneration: pure function of (args, seed)
     r1 = synthetic_sparse_coo(256, 4.0, kind="hub_row", skew=1.5, seed=3)
     r2 = synthetic_sparse_coo(256, 4.0, kind="hub_row", skew=1.5, seed=3)
     for x, y in zip(r1[:3], r2[:3]):
         assert np.array_equal(x, y)
-
-
-def test_tune_spmm_sweeps_split_and_matches_oracle(tmp_path):
-    rng = np.random.default_rng(12)
-    a = skewed_sparse(rng, 48, 48)
-    fmt = from_dense(a, vector_size=8)
-    b = jnp.asarray(rng.standard_normal((48, 32)), dtype=jnp.float32)
-    cache = AutotuneCache(str(tmp_path / "tune.json"))
-    cfg = tune_spmm(fmt, b, k_blks=(8,), n_blks=(32,), split_blks=(0, 1, 8),
-                    interpret=True, reps=1, cache=cache)
-    assert cfg.split_blk in (0, 1, 8)
-    out = ops.spmm_tuned(fmt, b, interpret=True, cache=cache, k_blks=(8,),
-                         n_blks=(32,))
-    np.testing.assert_allclose(np.asarray(out), a @ np.asarray(b),
-                               rtol=2e-4, atol=2e-4)
 
 
 # ------------------------------------------------------------ HBM model ----

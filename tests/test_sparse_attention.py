@@ -169,7 +169,7 @@ def test_fused_backward_runs_batched_duality_kernels():
     assert log[0] == ("attention", "pallas_fused_attn"), log
     bwd = log[1:]
     assert bwd, "backward dispatched nothing"
-    assert all(impl in ("pallas_batched",) for _, impl in bwd), log
+    assert all(impl == "pallas" for _, impl in bwd), log
     assert {"spmm", "sddmm"} <= {op for op, _ in bwd}, log
 
 
@@ -188,30 +188,6 @@ def test_staged_blocked_impl_matches_pallas_paths():
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(out_b), np.asarray(out_raw),
                                rtol=1e-6, atol=1e-6)
-
-
-def test_tuned_attention_impl_sweeps_and_matches(tmp_path, monkeypatch):
-    """The forward-only autotuned megakernel (attention-specific k_blk
-    sweep): canonical-format-only, dispatchable, oracle parity."""
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "t.json"))
-    rng = np.random.default_rng(8)
-    m, d = 32, 8
-    pat = random_pattern(rng, m, 0.3)
-    fmt = from_dense(pat.astype(np.float32), vector_size=8)
-    q = jnp.asarray(rng.standard_normal((2, m, d)).astype(np.float32))
-
-    out = sparse_dispatch_call("pallas_fused_attn_tuned", fmt, q)
-    oracle = dense_oracle(pat, q, q, q, 1.0 / math.sqrt(d))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
-                               rtol=1e-4, atol=1e-4)
-    with pytest.raises(ValueError, match="pallas_fused_attn_tuned"):
-        sparse_dispatch_call("pallas_fused_attn_tuned",
-                             block_format(fmt, 8), q)
-
-
-def sparse_dispatch_call(impl, fmt, q):
-    return dispatch.dispatch("attention", impl, fmt, q, q, q,
-                             interpret=True)
 
 
 def test_attention_hbm_model_fused_strictly_below_staged():

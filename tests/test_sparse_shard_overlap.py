@@ -190,66 +190,6 @@ def test_ad_plan_rejects_overlap_batches_on_bulk_impl():
         ad_plan(from_dense(a), impl="pallas_balanced", overlap_batches=2)
 
 
-def test_autotune_v4_cache_discarded_with_one_warning(tmp_path, caplog):
-    """Schema-v5 migration: a v4 cache file (configs without
-    ``overlap_batches``, keys without the ``|o`` suffix) is discarded
-    wholesale — its winners must not satisfy v5 lookups — and the
-    stale-schema warning fires once per cache object."""
-    import json
-    import logging
-
-    import jax.numpy as jnp
-
-    from repro.kernels.autotune import (
-        SCHEMA_VERSION,
-        AutotuneCache,
-        TuneConfig,
-        tune_spmm,
-    )
-
-    path = tmp_path / "tune.json"
-    path.write_text(json.dumps({
-        "schema": 4,
-        "configs": {"spmm|v8|w3|vec2|sk1|n7|dtfloat32|b1|cpu|interp"
-                    "|k8,16|nb64|s0,1|pfp32":
-                    {"k_blk": 16, "n_blk": 64, "median_ms": 0.1,
-                     "split_blk": 1, "precision": "fp32"}},
-    }))
-    cache = AutotuneCache(str(path))
-    with caplog.at_level(logging.WARNING, logger="repro.kernels.autotune"):
-        for _ in range(5):
-            assert cache.get("anything") is None
-    stale = [r for r in caplog.records
-             if "discarding autotune cache" in r.getMessage()]
-    assert len(stale) == 1
-    assert "schema 4" in stale[0].getMessage()
-
-    # re-tuning through the stale file writes a clean v5 cache
-    rng = np.random.default_rng(13)
-    a = ((rng.random((48, 48)) < 0.2)
-         * rng.standard_normal((48, 48))).astype(np.float32)
-    fmt = from_dense(a, vector_size=8)
-    b = jnp.asarray(rng.standard_normal((48, 64)), dtype=jnp.float32)
-    cfg = tune_spmm(fmt, b, k_blks=(8,), n_blks=(64,), interpret=True,
-                    reps=1, cache=cache)
-    raw = json.loads(path.read_text())
-    assert raw["schema"] == SCHEMA_VERSION
-    (key,) = raw["configs"].keys()
-    assert "|o0" in key  # overlap-batch candidate suffix (bulk-only sweep)
-    assert next(iter(raw["configs"].values()))["overlap_batches"] == 0
-    assert TuneConfig.from_json(next(iter(raw["configs"].values()))) == cfg
-
-    # fresh cache object on the v5 file: disk hit, no warning
-    caplog.clear()
-    cache2 = AutotuneCache(str(path))
-    with caplog.at_level(logging.WARNING, logger="repro.kernels.autotune"):
-        cfg2 = tune_spmm(fmt, b, k_blks=(8,), n_blks=(64,), interpret=True,
-                         reps=1, cache=cache2)
-    assert cfg2 == cfg
-    assert not [r for r in caplog.records
-                if "discarding autotune cache" in r.getMessage()]
-
-
 # ---------------------------------------------------------------------------
 # Multi-device gradients and precision (child processes)
 # ---------------------------------------------------------------------------

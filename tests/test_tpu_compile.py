@@ -166,7 +166,7 @@ def test_value_relayout_gathers_one_value_per_vector(one_chip):
                   vec_row=idx(BWD["nnzp"], jnp.int8),
                   ovf_src=idx(OVERFLOW, jnp.int32),
                   ovf_dst=idx(OVERFLOW, jnp.int32),
-                  impl="pallas", n_blk=128, n_blk_t=128, f_blk=128)
+                  impl="pallas", n_blk=128, f_blk=128)
     text = _compile_text(
         lambda p, x: vectors_on_lanes(p.transpose_vals(x), K_BLK), plan,
         _sds((FWD["nnzp"], V), jnp.float32, one_chip))
